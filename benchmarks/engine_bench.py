@@ -414,7 +414,7 @@ def persistent_smoke(out_path: str | None = None):
     from repro.core.triplets import initial_frontier
     from repro.kernels.fused_round import (fused_round_pallas,
                                            persistent_round_pallas)
-    from repro.kernels.ops import _fused_tables
+    from repro.kernels.ops import _fused_tables, on_platform
 
     # -- dispatch contract: ⌈K/R⌉ pallas_calls on the unrolled superstep --
     n, edges = grid_graph(4, 4)
@@ -454,17 +454,16 @@ def persistent_smoke(out_path: str | None = None):
         tabs = _fused_tables(g, "bitword")
 
         def one(p, b, v1, l2, vl, cnt, bm, bc, *, tabs=tabs, delta=delta):
-            return fused_round_pallas(p, b, v1, l2, vl, cnt, bm, bc, tabs,
-                                      formulation="bitword", delta=delta,
-                                      store=False)
+            return on_platform(fused_round_pallas, p, b, v1, l2, vl, cnt,
+                               bm, bc, tabs, formulation="bitword",
+                               delta=delta, store=False)
 
         def pers(p, b, v1, l2, vl, cnt, bm, bc, *, tabs=tabs, delta=delta,
                  R=R):
-            return persistent_round_pallas(p, b, v1, l2, vl, cnt, bm, bc,
-                                           jnp.int32(R), tabs,
-                                           formulation="bitword",
-                                           delta=delta, store=False,
-                                           rounds=R)
+            return on_platform(persistent_round_pallas, p, b, v1, l2, vl,
+                               cnt, bm, bc, jnp.int32(R), tabs,
+                               formulation="bitword", delta=delta,
+                               store=False, rounds=R)
 
         jone, jpers = jax.jit(one), jax.jit(pers)
         args = (f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
@@ -659,20 +658,18 @@ print(json.dumps(rows))
 def dist_smoke(out_path: str | None = None):
     """Sharded-path A/B: per-round driver (K=1, one dispatch + one sync per
     round — the pre-superstep pattern) vs the sharded wave superstep (K=8)
-    on a 4-virtual-device mesh, equal cycle counts enforced. Runs in a
-    subprocess (the bench process must keep seeing 1 device), asserts the
-    >=2x dispatch/sync reduction, and writes
-    ``results/BENCH_dist_smoke.json``."""
-    import subprocess
-    import sys
-
-    from repro.launch.env import host_sim_env
-    env = host_sim_env(4, src_path=os.path.join(
+    on a 4-device mesh, equal cycle counts enforced. Runs on 4 chips in
+    process on a TPU, else in a subprocess simulating 4 CPU devices (the
+    bench process must keep seeing 1 device); asserts the >=2x
+    dispatch/sync reduction, and writes ``results/BENCH_dist_smoke.json``.
+    Returns None (skipped) on a TPU host with fewer than 4 chips."""
+    from repro.launch.env import run_multi_device
+    out = run_multi_device(_DIST_SMOKE_CODE, 4, src_path=os.path.join(
         os.path.dirname(__file__), "..", "src"))
-    out = subprocess.run([sys.executable, "-c", _DIST_SMOKE_CODE], env=env,
-                         capture_output=True, text=True, timeout=900)
-    assert out.returncode == 0, out.stderr[-3000:]
-    rows = json.loads(out.stdout.strip().splitlines()[-1])
+    if out is None:
+        print("dist smoke: skipped (needs 4 devices)")
+        return None
+    rows = json.loads(out.strip().splitlines()[-1])
     pr, ss = rows["per_round"], rows["superstep"]
     assert pr["n_cycles"] == ss["n_cycles"], rows
     doc = dict(benchmark="dist_smoke", graph="Grid_5x6", n_devices=4,

@@ -34,16 +34,15 @@ def run():
         rows.append((f"triplet_jnp_{name}",
                      _time(triplet_flags, g, d), f"grid={n}x{d}x{d}"))
         rows.append((f"triplet_pallas_{name}",
-                     _time(ops.triplet_flags, g, d), "interpret=True"))
+                     _time(ops.triplet_flags, g, d), "pallas"))
         rows.append((f"expand_slot_jnp_{name}",
                      _time(E.expand_flags_slot, g, f, d), f"cap={f.capacity}"))
         rows.append((f"expand_slot_pallas_{name}",
-                     _time(ops.expand_flags_slot, g, f, d),
-                     "interpret=True"))
+                     _time(ops.expand_flags_slot, g, f, d), "pallas"))
         rows.append((f"expand_bitword_jnp_{name}",
                      _time(E.expand_words_bitword, g, f), f"nw={g.n_words}"))
         rows.append((f"expand_bitword_pallas_{name}",
-                     _time(ops.expand_words_bitword, g, f), "interpret=True"))
+                     _time(ops.expand_words_bitword, g, f), "pallas"))
     rows += run_lanes()
     rows += run_fused()
     rows += run_persistent()
@@ -117,14 +116,15 @@ def run_persistent():
     args = (f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
             buf.masks, buf.count)
 
-    jone = jax.jit(lambda *a: fused_round_pallas(
-        *a, tabs, formulation="bitword", delta=d, store=False))
+    jone = jax.jit(lambda *a: ops.on_platform(
+        fused_round_pallas, *a, tabs, formulation="bitword", delta=d,
+        store=False))
 
     rows = []
     for R in (2, 4, 8):
-        jpers = jax.jit(lambda *a, R=R: persistent_round_pallas(
-            *a, jnp.int32(R), tabs, formulation="bitword", delta=d,
-            store=False, rounds=R))
+        jpers = jax.jit(lambda *a, R=R: ops.on_platform(
+            persistent_round_pallas, *a, jnp.int32(R), tabs,
+            formulation="bitword", delta=d, store=False, rounds=R))
 
         def loop_arm():
             p, b, v1, l2, vl, cnt, bm, bc = args
@@ -166,7 +166,7 @@ def run_lanes(B: int = 4):
     def loop_single(*args):
         return [ops.expand_words_bitword(g, f) for _ in range(B)]
 
-    us_lanes = _time(lambda: bitword_expand_lanes(*argsB))
+    us_lanes = _time(lambda: ops.on_platform(bitword_expand_lanes, *argsB))
     us_loop = _time(loop_single)
     return [
         (f"bitword_lanes_B{B}_grid5x8", us_lanes,

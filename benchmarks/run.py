@@ -211,6 +211,9 @@ def check() -> int:
 
 def main() -> None:
     full = "--full" in sys.argv
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     if "--check" in sys.argv:
         sys.exit(1 if check() else 0)
 

@@ -32,7 +32,10 @@ def _sub_jaxprs(v):
 
 def primitive_counts(closed_jaxpr) -> dict:
     """Histogram of primitive names reachable from ``closed_jaxpr``,
-    EXCLUDING everything inside pallas_call kernel bodies."""
+    EXCLUDING everything inside pallas_call kernel bodies. A platform-
+    dependent ``cond`` (``kernels.ops`` picks the compiled kernel on a TPU
+    and the interpreted one elsewhere) contributes its first branch only:
+    exactly one of its branches is ever lowered."""
     counts: dict[str, int] = {}
 
     def walk(jaxpr, inside_kernel):
@@ -41,7 +44,10 @@ def primitive_counts(closed_jaxpr) -> dict:
             if not inside_kernel:
                 counts[name] = counts.get(name, 0) + 1
             inner = inside_kernel or name == "pallas_call"
-            for v in eqn.params.values():
+            params = eqn.params
+            if name == "cond" and params.get("branches_platforms"):
+                params = dict(branches=params["branches"][:1])
+            for v in params.values():
                 for sub in _sub_jaxprs(v):
                     walk(sub, inner)
 

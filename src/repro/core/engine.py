@@ -20,7 +20,8 @@ Modes:
   * store=True  — returns every chordless cycle as a vertex bitmap (the
                   paper's solution matrix S).
   * store=False — count-only (the paper's Grid 8×10 footnote mode).
-Backends: 'jnp' (pure JAX) or 'pallas' (kernels/; interpret=True on CPU).
+Backends: 'jnp' (pure JAX) or 'pallas' (kernels/: compiled on a TPU,
+interpreted elsewhere).
 Formulations: 'slot' (paper-faithful) or 'bitword' (TPU-native).
 
 Layering (DESIGN.md §"Service layer"): this module holds the device
@@ -192,6 +193,23 @@ class EngineConfig:
 
     def bucket(self, c: int) -> int:
         return _bucket(c, growth_bits=self.growth_bits)
+
+
+def round_path(cfg: EngineConfig, *, cap: int, nw: int, n: int,
+               n_neighbors: int, cyc_cap: int) -> str:
+    """Which path a superstep's rounds take at this bucket: 'fused' (each
+    round inside a fused pallas kernel — one per round, or one persistent
+    launch per ``rounds_per_launch`` rounds) or 'split' (flag kernel plus
+    XLA compaction). The static-shape rule ``core.expand`` applies while
+    tracing: a launch too large for the persistent kernel loops over
+    single-round fused kernels, so the single-round budget decides."""
+    from ..kernels.fused_round import fits_vmem
+    if not (cfg.fused_round and cfg.backend == "pallas"):
+        return "split"
+    fits = fits_vmem(cap=cap, nw=nw, n=n, n_neighbors=n_neighbors,
+                     cyc_cap=cyc_cap, formulation=cfg.formulation,
+                     store=cfg.store, persistent=False)
+    return "fused" if fits else "split"
 
 
 @dataclasses.dataclass

@@ -511,6 +511,21 @@ def expand_op(formulation: str, backend: str) -> ExpandOp:
 # Fused wave round (DESIGN.md §6.4)
 # ---------------------------------------------------------------------------
 
+def fused_kernel_fits(formulation: str, g: BitsetGraph, f: Frontier,
+                      buf: CycleBuffer, *, store: bool,
+                      persistent: bool) -> bool:
+    """The fused-bucket rule for one lane's traced operands: a round
+    (``persistent``: a multi-round launch) runs as one fused pallas kernel
+    only if the VMEM its blocks need, counted from static shapes with lane
+    padding, fits the kernels' budget (``kernels.fused_round.fits_vmem``).
+    Buckets past it take the split path."""
+    from ..kernels.fused_round import fits_vmem
+    return fits_vmem(cap=f.capacity, nw=f.n_words, n=g.adj_bits.shape[-2],
+                     n_neighbors=g.neighbors.shape[-1], cyc_cap=buf.capacity,
+                     formulation=formulation, store=store,
+                     persistent=persistent)
+
+
 def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
                          delta: int, store: bool,
                          formulation: str = "slot", backend: str = "jnp",
@@ -528,15 +543,18 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
     ``fused`` selects the one-pass round (DESIGN.md §6.8) when the op
     supports it: pallas ops with a fused kernel collapse the whole guarded
     round into ONE device dispatch (two-phase scatter, guard evaluated in
-    kernel); jnp ops swap the scatter compaction for the gather formulation
-    (one frontier pass instead of two). Output is bit-identical either way;
-    ops without fused support fall back to the split path silently.
+    kernel) while the bucket fits the kernel's VMEM budget
+    (``fused_kernel_fits``), and take the split path past it; jnp ops swap
+    the scatter compaction for the gather formulation (one frontier pass
+    instead of two). Output is bit-identical either way; ops without fused
+    support fall back to the split path silently.
 
     Returns (f2, buf2, n_cyc, n_new, ok_frontier, ok_cycles).
     """
     if op is None:
         op = expand_op(formulation, backend)
-    if fused and op.fused_kernel:
+    if fused and op.fused_kernel and fused_kernel_fits(
+            op.formulation, g, f, buf, store=store, persistent=False):
         return op.fused_round(g, f, buf, delta, store)
     flags, n_cyc, n_new = op.flags(g, f, delta)
     ok_frontier = n_new <= f.capacity
@@ -564,7 +582,8 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
     """Up to ``rounds`` complete guarded expansion rounds as ONE traced
     unit — the persistent superstep's loop body (DESIGN.md §6.11).
 
-    On pallas ops with a fused kernel (``fused=True``) this is the
+    On pallas ops with a fused kernel (``fused=True``) whose bucket fits
+    the VMEM budget this is the
     persistent wave kernel: one ``pallas_call`` with a leading round axis
     whose scratch carries the frontier between rounds, so HBM sees one
     frontier read + one write per LAUNCH instead of per round. Every other
@@ -590,7 +609,8 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
     rounds = int(rounds)
     if rlimit is None:
         rlimit = jnp.int32(rounds)
-    if fused and op.fused_kernel:
+    if fused and op.fused_kernel and fused_kernel_fits(
+            op.formulation, g, f, buf, store=store, persistent=True):
         return op.persistent_round(g, f, buf, delta, store, rounds, rlimit)
 
     zeros = jnp.zeros((rounds,), jnp.int32)

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from .bitset_graph import BitsetGraph
 from . import triplets as T
 from .engine import (STATUS_NAMES, EngineConfig, EnumerationResult, _DONE,
-                     _DRAIN, _GROW, _RUN, _SHRINK, _enumerate_host)
+                     _DRAIN, _GROW, _RUN, _SHRINK, _enumerate_host,
+                     round_path)
 from .frontier import (empty_cycle_buffer, empty_frontier, with_capacity,
                        with_capacity_batched)
 from .plan import (PlanKey, ProgramCache, RecyclePlan, WavePlan,
@@ -433,7 +434,10 @@ class CycleService:
                 plan_key=str(plan.key),
                 rounds_per_launch=cfg.rounds_per_launch,
                 lane_rids=(rid,) if rid else (),
-                lane_rounds=(it + int(r_h),) if rid else ())
+                lane_rounds=(it + int(r_h),) if rid else (),
+                round_path=round_path(
+                    cfg, cap=cap_in, nw=nw, n=g.n,
+                    n_neighbors=g.neighbors.shape[0], cyc_cap=cyc_cap))
 
             for i in range(int(r_h)):
                 n_cycles += int(ch_h[i])
@@ -608,7 +612,10 @@ class CycleService:
                 lane_rids=(rid,) * B if rid else (),
                 lane_rounds=tuple(
                     int(v) for v in its + np.asarray(r_h, np.int64))
-                if rid else ())
+                if rid else (),
+                round_path=round_path(
+                    cfg, cap=cap_in, nw=nw, n=n_pad,
+                    n_neighbors=gbat.neighbors.shape[-1], cyc_cap=cyc_cap))
 
             for i in range(B):
                 for j in range(int(r_h[i])):

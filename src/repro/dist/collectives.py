@@ -39,7 +39,7 @@ def ef_psum_tree(grads, errs, axis: str):
     (n·127 can't overflow), ONE dequantize at the end. Returns
     ``(mean_tree, new_err_tree)``.
     """
-    n = jax.lax.psum(1, axis)  # lax.axis_size is not in this jax version
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         local_scale = jnp.max(jnp.abs(g + e)) / _QMAX

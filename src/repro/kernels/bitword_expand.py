@@ -19,10 +19,19 @@ when they are counted.
 
 Batch is a first-class axis (DESIGN.md §6.7): the kernel runs on a
 ``grid=(B, capp//tp)`` LANE GRID — grid dim 0 walks graph lanes, dim 1 walks
-frontier row tiles within a lane; every BlockSpec carries a leading
-lane-block of 1 and each lane pins its own graph tables in VMEM. The
-single-graph entry point is the B=1 special case of the same kernel, so one
-compiled shape family serves both ``enumerate`` and ``enumerate_batch``.
+frontier row tiles within a lane; each lane pins its own graph tables in
+VMEM. The single-graph entry point is the B=1 special case of the same
+kernel, so one compiled shape family serves both ``enumerate`` and
+``enumerate_batch``.
+
+Planar layout: this kernel serves the split path, so it sees the largest
+buckets (millions of rows). A (cap, nw) or (cap, 1) operand would be tiled
+(8, 128) in HBM — 64-128× padding — so the wrapper stacks the frontier
+fields as planes, ``(B, 2·nw + 3, cap/128, 128)`` int32 (path words,
+blocked words, v₁, ℓ(v₂), v_last), and every field of a row tile is one
+dense (S, 128) slab. Mosaic has no vector gather, so the three table
+lookups (``Adj[v_last]``, ``Adj[v₁]``, ``labelgt[ℓ(v₂)]``) are one-hot
+selects over the n table rows, whose words are read as scalars.
 """
 from __future__ import annotations
 
@@ -32,40 +41,51 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_LANES = 128
 
-def _bitword_kernel(path_ref, blocked_ref, v1_ref, l2_ref, vlast_ref,
-                    adj_ref, labelgt_ref,
-                    close_ref, ext_ref, ncyc_ref, next_ref):
-    # every ref carries a leading lane-block dim of 1 (the lane grid axis)
-    path = path_ref[0]
-    blocked = blocked_ref[0]
-    v1 = v1_ref[0][:, 0]
-    l2 = l2_ref[0][:, 0]
-    vlast = vlast_ref[0][:, 0]
-    adj = adj_ref[0]            # this lane's graph, whole, VMEM-pinned
-    labelgt = labelgt_ref[0]
-    n = adj.shape[0]
 
-    adj_last = jnp.take(adj, jnp.clip(vlast, 0, n - 1), axis=0)
-    adj_v1 = jnp.take(adj, jnp.clip(v1, 0, n - 1), axis=0)
-    gt = jnp.take(labelgt, jnp.clip(l2, 0, n - 1), axis=0)
+def _bitword_kernel(x_ref, adj_ref, labelgt_ref, y_ref, *, nw: int):
+    # x: (1, 2nw+3, S, 128) int32 planes; y: (1, 2nw+2, S, 128) int32
+    n = adj_ref.shape[1]
+    u32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.uint32)
+    path = [u32(x_ref[0, w]) for w in range(nw)]
+    blocked = [u32(x_ref[0, nw + w]) for w in range(nw)]
+    clip = lambda a: jnp.clip(a, 0, n - 1)
+    v1, l2, vlast = (clip(x_ref[0, 2 * nw + k]) for k in range(3))
+    zero = jnp.zeros(v1.shape, jnp.uint32)
 
-    cand = adj_last & ~path & ~blocked & gt
-    close = cand & adj_v1
-    ext = cand & ~adj_v1
-    close_ref[0] = close
-    ext_ref[0] = ext
-    # fused popcount reductions — words are still register/VMEM-resident
-    ncyc_ref[0] = jax.lax.population_count(close).astype(jnp.int32).sum(
-        axis=1, keepdims=True)
-    next_ref[0] = jax.lax.population_count(ext).astype(jnp.int32).sum(
-        axis=1, keepdims=True)
+    def body(j, acc):
+        last, first, gt = acc
+        hit_last, hit_v1, hit_l2 = vlast == j, v1 == j, l2 == j
+        return (tuple(jnp.where(hit_last, adj_ref[0, j, w], a)
+                      for w, a in enumerate(last)),
+                tuple(jnp.where(hit_v1, adj_ref[0, j, w], a)
+                      for w, a in enumerate(first)),
+                tuple(jnp.where(hit_l2, labelgt_ref[0, j, w], a)
+                      for w, a in enumerate(gt)))
+
+    init = ((zero,) * nw,) * 3
+    adj_last, adj_v1, gt = jax.lax.fori_loop(0, n, body, init)
+
+    ncyc = jnp.zeros(v1.shape, jnp.int32)
+    next_ = jnp.zeros(v1.shape, jnp.int32)
+    for w in range(nw):
+        cand = adj_last[w] & ~path[w] & ~blocked[w] & gt[w]
+        close = cand & adj_v1[w]
+        ext = cand & ~adj_v1[w]
+        y_ref[0, w] = jax.lax.bitcast_convert_type(close, jnp.int32)
+        y_ref[0, nw + w] = jax.lax.bitcast_convert_type(ext, jnp.int32)
+        # fused popcount reductions — the words are still in vregs
+        ncyc = ncyc + jax.lax.population_count(close).astype(jnp.int32)
+        next_ = next_ + jax.lax.population_count(ext).astype(jnp.int32)
+    y_ref[0, 2 * nw] = ncyc
+    y_ref[0, 2 * nw + 1] = next_
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def bitword_expand_lanes(path, blocked, v1, l2, vlast, count,
                          adj_bits, labelgt_bits,
-                         *, tile: int = 128, interpret: bool = True):
+                         *, interpret: bool, tile: int = 2048):
     """Lane-gridded bitword expansion: ONE ``pallas_call`` advances every
     lane of a graph batch.
 
@@ -75,49 +95,47 @@ def bitword_expand_lanes(path, blocked, v1, l2, vlast, count,
     with the leading lane axis (dead rows zeroed per lane).
     """
     B, cap, nw = path.shape
-    tp = min(tile, max(8, cap))
-    pad = (-cap) % tp
-    padded = lambda a: jnp.pad(
-        a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-    col = lambda a: padded(a[..., None])
-    capp = cap + pad
+    tp = min(tile, -(-cap // 1024) * 1024)      # rows per grid step
+    capp = -(-cap // tp) * tp
+    i32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.int32)
+    planes = jnp.concatenate(
+        [i32(jnp.swapaxes(path, 1, 2)), i32(jnp.swapaxes(blocked, 1, 2)),
+         jnp.stack([v1, l2, vlast], axis=1).astype(jnp.int32)], axis=1)
+    planes = jnp.pad(planes, ((0, 0), (0, 0), (0, capp - cap)))
+    planes = planes.reshape(B, 2 * nw + 3, capp // _LANES, _LANES)
+    s = tp // _LANES
     lane_whole = lambda a: pl.BlockSpec(
         (1,) + a.shape[1:], lambda b, i: (b,) + (0,) * (a.ndim - 1))
 
-    close, ext, ncyc, next_ = pl.pallas_call(
-        _bitword_kernel,
+    y = pl.pallas_call(
+        functools.partial(_bitword_kernel, nw=nw),
         grid=(B, capp // tp),
-        in_specs=[
-            pl.BlockSpec((1, tp, nw), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, nw), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, 1), lambda b, i: (b, i, 0)),
-            lane_whole(adj_bits), lane_whole(labelgt_bits),
-        ],
-        out_specs=[pl.BlockSpec((1, tp, nw), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, tp, nw), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, tp, 1), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, tp, 1), lambda b, i: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, capp, nw), jnp.uint32),
-                   jax.ShapeDtypeStruct((B, capp, nw), jnp.uint32),
-                   jax.ShapeDtypeStruct((B, capp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((B, capp, 1), jnp.int32)],
+        in_specs=[pl.BlockSpec((1, 2 * nw + 3, s, _LANES),
+                               lambda b, i: (b, 0, i, 0)),
+                  lane_whole(adj_bits), lane_whole(labelgt_bits)],
+        out_specs=pl.BlockSpec((1, 2 * nw + 2, s, _LANES),
+                               lambda b, i: (b, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (B, 2 * nw + 2, capp // _LANES, _LANES), jnp.int32),
         interpret=interpret,
-    )(padded(path), padded(blocked), col(v1), col(l2), col(vlast),
-      adj_bits, labelgt_bits)
+        name="bitword_expand",
+    )(planes, adj_bits, labelgt_bits)
 
+    y = y.reshape(B, 2 * nw + 2, capp)[:, :, :cap]
+    words = lambda k: jnp.swapaxes(
+        jax.lax.bitcast_convert_type(y[:, k * nw:(k + 1) * nw], jnp.uint32),
+        1, 2)
     live = (jnp.arange(cap, dtype=jnp.int32)[None, :] < count[:, None])
     z = jnp.uint32(0)
-    return (jnp.where(live[..., None], close[:, :cap], z),
-            jnp.where(live[..., None], ext[:, :cap], z),
-            jnp.where(live, ncyc[:, :cap, 0], 0),
-            jnp.where(live, next_[:, :cap, 0], 0))
+    return (jnp.where(live[..., None], words(0), z),
+            jnp.where(live[..., None], words(1), z),
+            jnp.where(live, y[:, 2 * nw], 0),
+            jnp.where(live, y[:, 2 * nw + 1], 0))
 
 
 def bitword_expand_pallas(path, blocked, v1, l2, vlast, count,
                           adj_bits, labelgt_bits,
-                          *, tile: int = 128, interpret: bool = True):
+                          *, interpret: bool, tile: int = 2048):
     """Single-graph entry point — the B=1 lane of ``bitword_expand_lanes``.
     Returns (close_words, ext_words, n_cycles_per_row, n_ext_per_row)
     for live rows (dead rows are zeroed)."""

@@ -37,45 +37,51 @@ from jax.experimental import pallas as pl
 def _expand_kernel(path_ref, blocked_ref, v1_ref, l2_ref, vlast_ref,
                    offsets_ref, neighbors_ref, labels_ref, adj_ref,
                    cand_ref, cycle_ref, ext_ref, *, delta_p: int):
-    # every ref carries a leading lane-block dim of 1 (the lane grid axis)
-    path = path_ref[0]            # (TP, nw) uint32
-    blocked = blocked_ref[0]      # (TP, nw) uint32
-    v1 = v1_ref[0][:, 0]          # (TP,)
-    l2 = l2_ref[0][:, 0]
-    vlast = vlast_ref[0][:, 0]
-    offsets = offsets_ref[0][:, 0]      # (n+1,)
-    neighbors = neighbors_ref[0][:, 0]  # (2m_pad,)
-    labels = labels_ref[0][:, 0]        # (n,)
-    adj = adj_ref[0]                    # (n, nw)
+    # every ref carries a leading lane-block dim of 1 (the lane grid axis);
+    # Mosaic has no vector gather, so each path row reads its CSR slots as
+    # scalars and spreads them over the row's Δp slot lanes
+    tp = path_ref.shape[1]
+    n, nw = adj_ref.shape[1], adj_ref.shape[2]
+    last = neighbors_ref.shape[1] - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, delta_p), 1)
+    widx = jax.lax.broadcasted_iota(jnp.int32, (1, nw), 1)
 
-    tp = path.shape[0]
-    j = jax.lax.broadcasted_iota(jnp.int32, (tp, delta_p), 1)
-    k1 = offsets[jnp.clip(vlast, 0, offsets.shape[0] - 2)][:, None]
-    k2 = offsets[jnp.clip(vlast, 0, offsets.shape[0] - 2) + 1][:, None]
-    slot_ok = j < (k2 - k1)                                     # j < deg(v_t)
-    v = jnp.take(neighbors, jnp.clip(k1 + j, 0, neighbors.shape[0] - 1))
-    vi = jnp.clip(v, 0, labels.shape[0] - 1)
+    def row(r, carry):
+        rows = pl.ds(r, 1)
+        path = path_ref[0, rows, :]            # (1, nw) uint32
+        blocked = blocked_ref[0, rows, :]
+        l2 = l2_ref[0, r, 0]
+        vc = jnp.clip(vlast_ref[0, r, 0], 0, n - 1)
+        k1 = offsets_ref[0, vc, 0]
+        deg = offsets_ref[0, vc + 1, 0] - k1
+        adj_v1 = adj_ref[0, pl.ds(jnp.clip(v1_ref[0, r, 0], 0, n - 1), 1), :]
 
-    lab_ok = jnp.take(labels, vi) > l2[:, None]                 # ℓ(v) > ℓ(v₂)
+        def slot(j, acc):
+            cand, cyc, ext = acc
+            v = neighbors_ref[0, jnp.clip(k1 + j, 0, last), 0]
+            vi = jnp.clip(v, 0, n - 1)
+            sh = jnp.full((1, nw), vi % 32, jnp.int32).astype(jnp.uint32)
+            bit = jnp.where(widx == vi // 32, jnp.uint32(1) << sh,
+                            jnp.uint32(0))
+            hit = lambda words: jnp.max(
+                jnp.where((words & bit) != 0, 1, 0)) > 0
+            valid = ((j < deg) & (labels_ref[0, vi, 0] > l2)    # ℓ(v) > ℓ(v₂)
+                     & ~hit(path) & ~hit(blocked))            # chord check
+            closes = hit(adj_v1)                               # v ∈ Adj(v₁)
+            at = lane == j
+            return (jnp.where(at, v, cand),
+                    jnp.where(at & valid & closes, 1, cyc),
+                    jnp.where(at & valid & ~closes, 1, ext))
 
-    word = (vi // 32).astype(jnp.int32)
-    bit = (jnp.uint32(1) << (vi % 32).astype(jnp.uint32))
+        zero = jnp.zeros((1, delta_p), jnp.int32)
+        cand, cyc, ext = jax.lax.fori_loop(0, delta_p, slot,
+                                           (zero, zero, zero))
+        cand_ref[0, rows, :] = cand
+        cycle_ref[0, rows, :] = cyc
+        ext_ref[0, rows, :] = ext
+        return carry
 
-    def probe(mask_rows):  # (TP, nw) -> bit of v per slot (TP, Δp)
-        w = jnp.take_along_axis(
-            mask_rows[:, None, :].repeat(delta_p, axis=1),
-            word[..., None], axis=2)[..., 0]
-        return (w & bit) != 0
-
-    in_path = probe(path)
-    in_blocked = probe(blocked)
-    adj_v1 = jnp.take(adj, jnp.clip(v1, 0, adj.shape[0] - 1), axis=0)
-    closes = probe(adj_v1)
-
-    valid = slot_ok & lab_ok & ~in_path & ~in_blocked
-    cand_ref[0] = v.astype(jnp.int32)
-    cycle_ref[0] = valid & closes
-    ext_ref[0] = valid & ~closes
+    jax.lax.fori_loop(0, tp, row, 0)
 
 
 def _pad_to(x, mult, axis=1, fill=0):
@@ -91,8 +97,8 @@ def _pad_to(x, mult, axis=1, fill=0):
                    static_argnames=("delta", "tile", "interpret"))
 def frontier_expand_lanes(path, blocked, v1, l2, vlast, count,
                           offsets, neighbors, labels, adj_bits,
-                          *, delta: int, tile: int = 128,
-                          interpret: bool = True):
+                          *, delta: int, interpret: bool,
+                          tile: int = 128):
     """Lane-gridded slot expansion: ONE ``pallas_call`` advances every lane.
 
     Shapes: ``path``/``blocked`` (B, cap, nw); ``v1``/``l2``/``vlast``
@@ -136,24 +142,25 @@ def frontier_expand_lanes(path, blocked, v1, l2, vlast, count,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, capp, delta_p), jnp.int32),
-            jax.ShapeDtypeStruct((B, capp, delta_p), jnp.bool_),
-            jax.ShapeDtypeStruct((B, capp, delta_p), jnp.bool_),
+            jax.ShapeDtypeStruct((B, capp, delta_p), jnp.int32),
+            jax.ShapeDtypeStruct((B, capp, delta_p), jnp.int32),
         ],
         interpret=interpret,
+        name="frontier_expand",
     )(path_p, blocked_p, v1_p, l2_p, vl_p, offs, nbr, labs, adj_bits)
 
     live = (jnp.arange(cap, dtype=jnp.int32)[None, :]
             < count[:, None])[..., None]
     cand = cand[:, :cap, :delta]
-    cyc = cyc[:, :cap, :delta] & live
-    ext = ext[:, :cap, :delta] & live
+    cyc = (cyc[:, :cap, :delta] != 0) & live
+    ext = (ext[:, :cap, :delta] != 0) & live
     return cand, cyc, ext
 
 
 def frontier_expand_pallas(path, blocked, v1, l2, vlast, count,
                            offsets, neighbors, labels, adj_bits,
-                           *, delta: int, tile: int = 128,
-                           interpret: bool = True):
+                           *, delta: int, interpret: bool,
+                           tile: int = 128):
     """Single-graph entry point — the B=1 lane of ``frontier_expand_lanes``.
     Returns (cand_v, is_cycle, is_ext), each (cap, Δ)."""
     cand, cyc, ext = frontier_expand_lanes(

@@ -1,6 +1,8 @@
 """Jit'd wrappers exposing the Pallas kernels with engine-compatible
-signatures. On CPU (this container) kernels run under interpret=True; on a
-real TPU backend set ``REPRO_PALLAS_INTERPRET=0``.
+signatures. Every kernel call goes through ``on_platform``: the program
+carries the compiled kernel for a TPU and the interpreted one for every
+other platform, and the lowering keeps only the branch of the platform it
+compiles for. Nothing here asks for a backend when the module is imported.
 
 Batch transparency (DESIGN.md §6.7): every wrapper carries a
 ``jax.custom_batching.custom_vmap`` rule that maps ``jax.vmap`` onto the
@@ -14,7 +16,6 @@ same kernels, so both paths share one compiled shape family.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +28,15 @@ from .bitword_expand import bitword_expand_lanes, bitword_expand_pallas
 from .fused_round import (fused_round_lanes, fused_round_pallas,
                           persistent_round_lanes, persistent_round_pallas)
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0" or \
-    jax.default_backend() != "tpu"
+
+def on_platform(kernel, *args, **static):
+    """Call ``kernel(*args, **static)`` compiled on a TPU and interpreted
+    (``interpret=True``) on any other platform, decided when the program
+    lowers for the device it runs on."""
+    return jax.lax.platform_dependent(
+        *args, tpu=functools.partial(kernel, interpret=False, **static),
+        default=functools.partial(kernel, interpret=True, **static))
+
 
 # Trace-time observability for the fused round (DESIGN.md §6.8): each entry
 # counts how many times a fused-round pallas_call was TRACED into a program
@@ -56,19 +64,19 @@ def _broadcast_unbatched(tree, tree_batched, axis_size):
 def _slot_flags_op(delta: int):
     @jax.custom_batching.custom_vmap
     def flags(g: BitsetGraph, f: Frontier):
-        return frontier_expand_pallas(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            g.offsets, g.neighbors, g.labels, g.adj_bits,
-            delta=delta, interpret=INTERPRET)
+        return on_platform(
+            frontier_expand_pallas, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, g.offsets, g.neighbors, g.labels, g.adj_bits,
+            delta=delta)
 
     @flags.def_vmap
     def _rule(axis_size, in_batched, g, f):
         g = _broadcast_unbatched(g, in_batched[0], axis_size)
         f = _broadcast_unbatched(f, in_batched[1], axis_size)
-        out = frontier_expand_lanes(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            g.offsets, g.neighbors, g.labels, g.adj_bits,
-            delta=delta, interpret=INTERPRET)
+        out = on_platform(
+            frontier_expand_lanes, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, g.offsets, g.neighbors, g.labels, g.adj_bits,
+            delta=delta)
         return out, (True, True, True)
 
     return flags
@@ -88,16 +96,14 @@ def expand_flags_slot(g: BitsetGraph, f: Frontier, delta: int):
 def _triplet_flags_op(delta: int):
     @jax.custom_batching.custom_vmap
     def flags(g: BitsetGraph):
-        return triplet_init_pallas(g.offsets, g.neighbors, g.labels,
-                                   g.adj_bits, delta=delta,
-                                   interpret=INTERPRET)
+        return on_platform(triplet_init_pallas, g.offsets, g.neighbors,
+                           g.labels, g.adj_bits, delta=delta)
 
     @flags.def_vmap
     def _rule(axis_size, in_batched, g):
         g = _broadcast_unbatched(g, in_batched[0], axis_size)
-        out = triplet_init_lanes(g.offsets, g.neighbors, g.labels,
-                                 g.adj_bits, delta=delta,
-                                 interpret=INTERPRET)
+        out = on_platform(triplet_init_lanes, g.offsets, g.neighbors,
+                          g.labels, g.adj_bits, delta=delta)
         return out, (True, True)
 
     return flags
@@ -116,18 +122,18 @@ def triplet_flags(g: BitsetGraph, delta: int):
 @jax.custom_batching.custom_vmap
 def _bitword_rows(g: BitsetGraph, f: Frontier):
     """(close_words, ext_words, per-row cycle counts, per-row ext counts)."""
-    return bitword_expand_pallas(
-        f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-        g.adj_bits, g.labelgt_bits, interpret=INTERPRET)
+    return on_platform(
+        bitword_expand_pallas, f.path, f.blocked, f.v1, f.l2, f.vlast,
+        f.count, g.adj_bits, g.labelgt_bits)
 
 
 @_bitword_rows.def_vmap
 def _bitword_rows_vmap(axis_size, in_batched, g, f):
     g = _broadcast_unbatched(g, in_batched[0], axis_size)
     f = _broadcast_unbatched(f, in_batched[1], axis_size)
-    out = bitword_expand_lanes(
-        f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-        g.adj_bits, g.labelgt_bits, interpret=INTERPRET)
+    out = on_platform(
+        bitword_expand_lanes, f.path, f.blocked, f.v1, f.l2, f.vlast,
+        f.count, g.adj_bits, g.labelgt_bits)
     return out, (True, True, True, True)
 
 
@@ -172,11 +178,10 @@ def _fused_round_op(formulation: str, delta: int, store: bool):
     @jax.custom_batching.custom_vmap
     def fused(g: BitsetGraph, f: Frontier, buf: CycleBuffer):
         FUSED_KERNEL_BUILDS["single"] += 1
-        return fused_round_pallas(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            buf.masks, buf.count, _fused_tables(g, formulation),
-            formulation=formulation, delta=delta, store=store,
-            interpret=INTERPRET)
+        return on_platform(
+            fused_round_pallas, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, buf.masks, buf.count, _fused_tables(g, formulation),
+            formulation=formulation, delta=delta, store=store)
 
     @fused.def_vmap
     def _rule(axis_size, in_batched, g, f, buf):
@@ -184,11 +189,10 @@ def _fused_round_op(formulation: str, delta: int, store: bool):
         g = _broadcast_unbatched(g, in_batched[0], axis_size)
         f = _broadcast_unbatched(f, in_batched[1], axis_size)
         buf = _broadcast_unbatched(buf, in_batched[2], axis_size)
-        out = fused_round_lanes(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            buf.masks, buf.count, _fused_tables(g, formulation),
-            formulation=formulation, delta=delta, store=store,
-            interpret=INTERPRET)
+        out = on_platform(
+            fused_round_lanes, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, buf.masks, buf.count, _fused_tables(g, formulation),
+            formulation=formulation, delta=delta, store=store)
         return out, (True,) * len(out)
 
     return fused
@@ -238,11 +242,11 @@ def _persistent_round_op(formulation: str, delta: int, store: bool,
     @jax.custom_batching.custom_vmap
     def persistent(g: BitsetGraph, f: Frontier, buf: CycleBuffer, rlimit):
         FUSED_KERNEL_BUILDS["persistent_single"] += 1
-        return persistent_round_pallas(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            buf.masks, buf.count, rlimit, _fused_tables(g, formulation),
-            formulation=formulation, delta=delta, store=store,
-            rounds=rounds, interpret=INTERPRET)
+        return on_platform(
+            persistent_round_pallas, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, buf.masks, buf.count, rlimit,
+            _fused_tables(g, formulation), formulation=formulation,
+            delta=delta, store=store, rounds=rounds)
 
     @persistent.def_vmap
     def _rule(axis_size, in_batched, g, f, buf, rlimit):
@@ -251,11 +255,11 @@ def _persistent_round_op(formulation: str, delta: int, store: bool,
         f = _broadcast_unbatched(f, in_batched[1], axis_size)
         buf = _broadcast_unbatched(buf, in_batched[2], axis_size)
         rlimit = _broadcast_unbatched(rlimit, in_batched[3], axis_size)
-        out = persistent_round_lanes(
-            f.path, f.blocked, f.v1, f.l2, f.vlast, f.count,
-            buf.masks, buf.count, rlimit, _fused_tables(g, formulation),
-            formulation=formulation, delta=delta, store=store,
-            rounds=rounds, interpret=INTERPRET)
+        out = on_platform(
+            persistent_round_lanes, f.path, f.blocked, f.v1, f.l2, f.vlast,
+            f.count, buf.masks, buf.count, rlimit,
+            _fused_tables(g, formulation), formulation=formulation,
+            delta=delta, store=store, rounds=rounds)
         return out, (True,) * len(out)
 
     return persistent

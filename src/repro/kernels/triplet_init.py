@@ -3,11 +3,16 @@
 Paper Algorithm 2: thread j decodes (i_u, i_x, i_y) from its global id and
 tests ℓ(u) < ℓ(x) < ℓ(y) plus (x,y) ∈ E.  Here the |V|·Δ² thread grid becomes
 a lane-gridded Pallas grid ``(B, np//tu)`` over (graph lane × vertex tile)
-pairs (DESIGN.md §6.7); each grid step evaluates a (TU, Δ·Δ) flag tile with
-the same index algebra (Eqs. 1–3 of the paper) computed from a 2-D iota.
-The (x,y) ∈ E binary search (O(log Δ)) is replaced by an O(1)
-adjacency-bitmap probe held in VMEM.  The single-graph entry point is the
-B=1 special case — one dispatch seeds every lane of a graph batch.
+pairs (DESIGN.md §6.7); each grid step fills a (TU, Δ·Δ) flag tile, one
+vertex row at a time, with the same index algebra (Eqs. 1–3 of the paper)
+computed from a lane iota. The (x,y) ∈ E binary search (O(log Δ)) is
+replaced by an O(1) adjacency-bitmap probe held in VMEM.  The single-graph
+entry point is the B=1 special case — one dispatch seeds every lane of a
+graph batch.
+
+Mosaic has no vector gather: the CSR tables are read as scalars (u's
+offsets, its Δ neighbors and their labels) and spread over the row's slot
+lanes with selects.
 """
 from __future__ import annotations
 
@@ -21,54 +26,70 @@ from jax.experimental import pallas as pl
 def _triplet_kernel(offsets_ref, neighbors_ref, labels_ref, adj_ref,
                     tri_ref, trip_ref, *, delta: int, tu: int):
     # every ref carries a leading lane-block dim of 1 (the lane grid axis)
-    offsets = offsets_ref[0][:, 0]
-    neighbors = neighbors_ref[0][:, 0]
-    labels = labels_ref[0][:, 0]
-    adj = adj_ref[0]
-    n = labels.shape[0]
-
-    step = pl.program_id(1)     # vertex tile within this lane
-    u = step * tu + jax.lax.broadcasted_iota(jnp.int32, (tu, delta * delta), 0)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (tu, delta * delta), 1)
+    n = labels_ref.shape[1]
+    nw = adj_ref.shape[2]
+    last = neighbors_ref.shape[1] - 1
+    dd = delta * delta
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, dd), 1)
     ix = slot // delta     # Eq. 2 (relative index of x)
     iy = slot % delta      # Eq. 3 (relative index of y)
+    step = pl.program_id(1)     # vertex tile within this lane
 
-    uc = jnp.clip(u, 0, n - 1)
-    k1 = jnp.take(offsets, uc)
-    k2 = jnp.take(offsets, uc + 1)
-    u_ok = u < n
-    slot_ok = (ix < (k2 - k1)) & (iy < (k2 - k1)) & (ix != iy) & u_ok
+    def vertex(uu, carry):
+        u = step * tu + uu
+        uc = jnp.minimum(u, n - 1)
+        k1 = offsets_ref[0, uc, 0]
+        deg = offsets_ref[0, uc + 1, 0] - k1
+        lu = labels_ref[0, uc, 0]
 
-    last = neighbors.shape[0] - 1
-    x = jnp.take(neighbors, jnp.clip(k1 + ix, 0, last))
-    y = jnp.take(neighbors, jnp.clip(k1 + iy, 0, last))
-    lu = jnp.take(labels, uc)
-    lx = jnp.take(labels, jnp.clip(x, 0, n - 1))
-    ly = jnp.take(labels, jnp.clip(y, 0, n - 1))
-    label_ok = (lu < lx) & (lx < ly)
+        def nbr(j):
+            x = neighbors_ref[0, jnp.clip(k1 + j, 0, last), 0]
+            return jnp.clip(x, 0, n - 1)
 
-    # (x, y) ∈ E via bitmap probe
-    adj_x = jnp.take(adj, jnp.clip(x, 0, n - 1), axis=0)  # (tu, ΔΔ, nw)
-    word = (jnp.clip(y, 0, n - 1) // 32).astype(jnp.int32)
-    bit = jnp.uint32(1) << (jnp.clip(y, 0, n - 1) % 32).astype(jnp.uint32)
-    w = jnp.take_along_axis(adj_x, word[..., None], axis=2)[..., 0]
-    adj_xy = (w & bit) != 0
+        # slot lanes of x = N(u)[ix] and y = N(u)[iy]: labels, and y's id
+        def spread(j, acc):
+            lx, ly, y = acc
+            x = nbr(j)
+            lab = labels_ref[0, x, 0]
+            return (jnp.where(ix == j, lab, lx), jnp.where(iy == j, lab, ly),
+                    jnp.where(iy == j, x, y))
 
-    base = slot_ok & label_ok
-    tri_ref[0] = base & adj_xy
-    trip_ref[0] = base & ~adj_xy
+        zero = jnp.zeros((1, dd), jnp.int32)
+        lx, ly, y = jax.lax.fori_loop(0, deg, spread, (zero, zero, zero))
+
+        # (x, y) ∈ E: the word of Adj[x] holding y's bit, per slot lane
+        yw = y // 32
+
+        def probe(j, acc):
+            x = nbr(j)
+            for w in range(nw):
+                acc = jnp.where((ix == j) & (yw == w), adj_ref[0, x, w], acc)
+            return acc
+
+        words = jax.lax.fori_loop(0, deg, probe,
+                                  jnp.zeros((1, dd), jnp.uint32))
+        adj_xy = ((words >> (y % 32).astype(jnp.uint32))
+                  & jnp.uint32(1)) != 0
+
+        slot_ok = (ix < deg) & (iy < deg) & (ix != iy) & (u < n)
+        base = slot_ok & (lu < lx) & (lx < ly)
+        row = pl.ds(uu, 1)
+        tri_ref[0, row, :] = (base & adj_xy).astype(jnp.int32)
+        trip_ref[0, row, :] = (base & ~adj_xy).astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, tu, vertex, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("delta", "tile", "interpret"))
 def triplet_init_lanes(offsets, neighbors, labels, adj_bits,
-                       *, delta: int, tile: int = 8, interpret: bool = True):
+                       *, delta: int, interpret: bool, tile: int = 8):
     """Lane-gridded stage 1: ONE ``pallas_call`` flags every lane's
     (n, Δ, Δ) triplet grid.  Graph tables carry a leading lane axis
     ((B, n+1), (B, 2m), (B, n), (B, n, nw)); returns (is_triangle,
     is_triplet) of shape (B, n, Δ, Δ)."""
     B, n = labels.shape
-    nw = adj_bits.shape[2]
-    tu = min(tile, max(1, n))
+    tu = tile
     np_ = -(-n // tu) * tu
     dd = delta * delta
 
@@ -88,16 +109,17 @@ def triplet_init_lanes(offsets, neighbors, labels, adj_bits,
                   lane_whole(adj_bits)],
         out_specs=[pl.BlockSpec((1, tu, dd), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, tu, dd), lambda b, i: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, np_, dd), jnp.bool_),
-                   jax.ShapeDtypeStruct((B, np_, dd), jnp.bool_)],
+        out_shape=[jax.ShapeDtypeStruct((B, np_, dd), jnp.int32),
+                   jax.ShapeDtypeStruct((B, np_, dd), jnp.int32)],
         interpret=interpret,
+        name="triplet_init",
     )(offs, nbr, labs, adj_bits)
-    return (tri[:, :n].reshape(B, n, delta, delta),
-            trip[:, :n].reshape(B, n, delta, delta))
+    return (tri[:, :n].reshape(B, n, delta, delta) != 0,
+            trip[:, :n].reshape(B, n, delta, delta) != 0)
 
 
 def triplet_init_pallas(offsets, neighbors, labels, adj_bits,
-                        *, delta: int, tile: int = 8, interpret: bool = True):
+                        *, delta: int, interpret: bool, tile: int = 8):
     """Single-graph entry point — the B=1 lane of ``triplet_init_lanes``.
     Returns (is_triangle, is_triplet) of shape (n, Δ, Δ)."""
     tri, trip = triplet_init_lanes(

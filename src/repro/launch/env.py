@@ -16,6 +16,15 @@ instead of each test/benchmark hand-rolling its own ``XLA_FLAGS`` string:
 - ``configure(...)`` — compose both into ``os.environ`` for a process
   that has NOT yet initialized jax (flags are read at first init; calling
   after is a silent no-op, so this raises instead).
+- ``run_multi_device(code, n)`` — run a multi-device benchmark snippet:
+  in this process on ``jax.devices()`` when they are TPUs (a chip belongs
+  to one process, so a child could never reach it), in a child simulating
+  ``n`` CPU devices otherwise.
+- ``enable_compile_cache()`` — JAX's persistent compilation cache for the
+  entry points (``chip_smoke.py``, ``launch.serve``, ``benchmarks.run``):
+  ``JAX_COMPILATION_CACHE_DIR`` when it is set, else one fixed directory
+  of the checkout (``.jax_cache``, git-ignored). The path is part of the
+  cache key, so it never comes from a temporary name, a pid or the time.
 """
 from __future__ import annotations
 
@@ -23,6 +32,11 @@ import os
 import sys
 
 HOST_SIM_FLAG = "--xla_force_host_platform_device_count"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: src/repro/launch/env.py is three levels down
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 # XLA:GPU flags that keep the sharded superstep's collectives off the
 # critical path (async collectives + latency-hiding scheduler) and enable
@@ -77,6 +91,36 @@ def host_sim_env(n_devices: int, *, src_path: str | None = None,
     return env
 
 
+def run_multi_device(code: str, n_devices: int, *, src_path: str,
+                     timeout: int = 900) -> str | None:
+    """Run ``code`` (a script that prints its result) on ``n_devices``
+    devices and return its stdout.
+
+    On a TPU the script runs in this process on the first ``n_devices``
+    chips, and ``None`` comes back when fewer are attached; elsewhere it
+    runs in a child process that simulates ``n_devices`` CPU devices
+    (the flag must precede the child's first jax init)."""
+    import contextlib
+    import io
+    import subprocess
+
+    import jax
+    if jax.devices()[0].platform == "tpu":
+        if len(jax.devices()) < n_devices:
+            return None
+        out = io.StringIO()
+        with contextlib.redirect_stdout(out):
+            exec(compile(code, "<multi-device section>", "exec"),
+                 {"__name__": "__multi_device__"})
+        return out.getvalue()
+    done = subprocess.run([sys.executable, "-c", code],
+                          env=host_sim_env(n_devices, src_path=src_path),
+                          capture_output=True, text=True, timeout=timeout)
+    if done.returncode != 0:
+        raise RuntimeError(done.stderr[-3000:])
+    return done.stdout
+
+
 def configure(n_devices: int = 0, *, gpu_comm: bool = False) -> str:
     """Set ``XLA_FLAGS`` for THIS process, before jax initializes.
 
@@ -98,3 +142,19 @@ def configure(n_devices: int = 0, *, gpu_comm: bool = False) -> str:
     flags = xla_flags(n_devices, gpu_comm=gpu_comm)
     os.environ["XLA_FLAGS"] = flags
     return flags
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets no other; otherwise the cache goes to ``REPO_CACHE_DIR``.
+    Every compile is cached, however short: kernels compile in well under
+    JAX's default one-second threshold."""
+    import jax
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = REPO_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path

@@ -33,6 +33,11 @@ import argparse
 import time
 
 
+# device counters both serving paths sum over their runs
+_DEVICE_COUNTERS = ("n_dispatches", "n_host_syncs", "fused_rounds",
+                    "split_rounds")
+
+
 def build_request_queue(n_requests: int, seed: int):
     """Mixed multi-tenant traffic: small grids + G(n, p) instances."""
     import numpy as np
@@ -96,11 +101,16 @@ def serve(service, queue, *, slots: int = 4, verbose: bool = True) -> dict:
     latency (every request "arrives" when serve() starts, so queue wait is
     time spent behind earlier waves) and ``mean_lane_occupancy`` (per wave:
     lane-rounds lived / lane-rounds dispatched — the dead-lane drag of
-    wave-at-a-time scheduling shows up here as occupancy < 1).
+    wave-at-a-time scheduling shows up here as occupancy < 1). Both paths
+    also report ``cycles_by_request`` (in queue order) and the device
+    counters summed over their runs: dispatches, host syncs, and rounds
+    by path (fused kernel or split).
     """
     queue = list(queue)
+    order = list(range(len(queue)))
     stats = dict(requests=0, waves=0, coalesced_lanes=0, solo_requests=0,
-                 n_cycles=0, classes={})
+                 n_cycles=0, classes={}, cycles_by_request=[0] * len(queue),
+                 **{k: 0 for k in _DEVICE_COUNTERS})
     # registry mirrors (DESIGN.md §6.10): the returned dict stays the
     # legacy view, every count double-writes into the service's registry
     m = service.metrics
@@ -116,6 +126,9 @@ def serve(service, queue, *, slots: int = 4, verbose: bool = True) -> dict:
     t_start = time.perf_counter()
     while queue:
         batch, idx, cls = _pop_class_batch(queue, slots)
+        ids = [order[i] for i in idx]
+        for i in reversed(idx):
+            order.pop(i)
 
         t1 = time.perf_counter()
         results = (service.enumerate_batch(batch) if len(batch) > 1
@@ -147,6 +160,10 @@ def serve(service, queue, *, slots: int = 4, verbose: bool = True) -> dict:
             mc["solo_requests"].inc()
         total = sum(r.n_cycles for r in results)
         stats["n_cycles"] += total
+        for rid, r in zip(ids, results):
+            stats["cycles_by_request"][rid] = r.n_cycles
+        for k in _DEVICE_COUNTERS:   # a batch's lanes share one run's
+            stats[k] += results[0].stats[k]
         if verbose:
             print(f"wave {stats['waves']}: [{cls}] {len(batch)} lane(s), "
                   f"{total} cycles, {dt * 1e3 / len(batch):.1f} ms/graph")
@@ -168,10 +185,12 @@ def serve_recycled(service, queue, *, slots=None, arrivals=None,
     dict shape ``serve`` produces, from the session's own stats."""
     n_done = 0
     n_cycles = 0
+    by_request = [0] * len(queue)
     for ridx, res in service.serve_stream(queue, slots=slots,
                                           arrivals=arrivals):
         n_done += 1
         n_cycles += res.n_cycles
+        by_request[ridx] = res.n_cycles
         if verbose:
             print(f"done {n_done}/{len(queue)}: request {ridx}, "
                   f"{res.n_cycles} cycles, "
@@ -185,7 +204,9 @@ def serve_recycled(service, queue, *, slots=None, arrivals=None,
                  pools=sess.stats["pools"],
                  classes=dict(sess.stats["classes"]),
                  queue_wait_ms=list(sess.stats["queue_wait_ms"]),
-                 e2e_ms=list(sess.stats["e2e_ms"]))
+                 e2e_ms=list(sess.stats["e2e_ms"]),
+                 cycles_by_request=by_request,
+                 **{k: sess.stats[k] for k in _DEVICE_COUNTERS})
     stats.update(sess.latency_summary())
     return stats
 
@@ -220,8 +241,10 @@ def main():
     args = ap.parse_args()
 
     from ..core import CycleService, EngineConfig
+    from ..launch.env import enable_compile_cache
     from ..obs import FlightRecorder
 
+    enable_compile_cache()
     recorder = (FlightRecorder(dump_dir=args.flight_dir)
                 if args.flight_dir else None)
     service = CycleService(EngineConfig(store=args.store,

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from ..core import triplets as T
 from ..core.bitset_graph import BitsetGraph, n_words_for
 from ..core.engine import (STATUS_NAMES, EngineConfig, EnumerationResult,
-                           _DONE, _DRAIN, _GROW, _RUN, _SHRINK)
+                           _DONE, _DRAIN, _GROW, _RUN, _SHRINK, round_path)
 from ..core.frontier import empty_cycle_buffer, with_capacity_batched
 from ..core.plan import pad_graph
 from ..obs.spans import new_request_id
@@ -88,7 +88,8 @@ class ContinuousScheduler:
             requests=0, completed=0, supersteps=0, boundaries=0,
             admissions=0, retirements=0, pools=0, classes={},
             occupancy_sum=0.0, n_cycles=0, boundary_ms=0.0,
-            queue_wait_ms=[], e2e_ms=[])
+            queue_wait_ms=[], e2e_ms=[], n_dispatches=0, n_host_syncs=0,
+            fused_rounds=0, split_rounds=0)
         # registry mirrors (DESIGN.md §6.10): the legacy stats dict above
         # stays the session-local view, every count double-writes into the
         # service's shared MetricsRegistry via _bump (dict == registry is
@@ -273,6 +274,11 @@ class ContinuousScheduler:
         scheduler's own ``slots`` knob (``replay_sched``)."""
         if self.pool is None:
             return
+        tr = self._trace
+        self.stats["n_dispatches"] += tr.n_dispatches
+        self.stats["n_host_syncs"] += tr.n_host_syncs
+        self.stats["fused_rounds"] += tr.rounds_by_path["fused"]
+        self.stats["split_rounds"] += tr.rounds_by_path["split"]
         if self._observe and self._tkey is not None and self._done:
             from ..tune import WaveProfile
             n_pad, m_pad, d_pad = self._shape
@@ -518,7 +524,11 @@ class ContinuousScheduler:
             lane_rids=tuple(r.rid if r is not None else ""
                             for r in pool.req),
             lane_rounds=tuple(int(pool.its[i]) + int(r_h[i])
-                              for i in range(B)))
+                              for i in range(B)),
+            round_path=round_path(
+                cfg, cap=cap_in, nw=self._nw, n=n_pad,
+                n_neighbors=self._gbat.neighbors.shape[-1],
+                cyc_cap=self._cyc_cap))
         if self._spans.enabled:
             t_end = self._spans.now_ms()
             for i in occ:

@@ -162,7 +162,8 @@ class WaveTrace:
 
     __slots__ = ("enabled", "events", "n_dispatches", "n_host_syncs",
                  "n_bucket_transitions", "n_drains", "n_kernel_launches",
-                 "by_cause", "_t0", "_origin", "_ticked", "observer")
+                 "by_cause", "rounds_by_path", "_t0", "_origin", "_ticked",
+                 "observer")
 
     def __init__(self, enabled: bool = True, origin: float | None = None,
                  observer=None):
@@ -180,6 +181,9 @@ class WaveTrace:
         self.n_drains = 0
         self.n_kernel_launches = 0
         self.by_cause: dict[str, int] = {}
+        # rounds (aborted attempts included) per round path: 'fused' — one
+        # fused pallas kernel per round or launch — or 'split'
+        self.rounds_by_path = {"fused": 0, "split": 0}
         self._t0 = 0.0
         self._origin = time.perf_counter() if origin is None else origin
         self._ticked = False
@@ -226,11 +230,14 @@ class WaveTrace:
                  lanes: int = 0, live_lanes: int = 0, retired: int = 0,
                  admitted: int = 0, wall_ms: float = 0.0, lane_rids=(),
                  lane_rounds=(), rounds_per_launch: int = 1,
-                 t_start_ms: float | None = None) -> None:
+                 t_start_ms: float | None = None,
+                 round_path: str = "") -> None:
         self.n_dispatches += launches
         if kind in ("superstep", "batch", "dist"):
             att = rounds + (1 if status in ("GROW", "DRAIN") else 0)
             self.n_kernel_launches += -(-att // max(rounds_per_launch, 1))
+            if round_path:
+                self.rounds_by_path[round_path] += att
         self.by_cause[status] = self.by_cause.get(status, 0) + 1
         if not self.enabled and self.observer is None:
             self._ticked = False
@@ -285,6 +292,8 @@ class WaveTrace:
                    n_drains=self.n_drains,
                    rounds=rounds,
                    n_kernel_launches=self.n_kernel_launches,
+                   fused_rounds=self.rounds_by_path["fused"],
+                   split_rounds=self.rounds_by_path["split"],
                    rounds_per_dispatch=rounds / max(self.n_dispatches, 1),
                    syncs_per_round=self.n_host_syncs / max(rounds, 1))
         if self.by_cause:

@@ -1,6 +1,6 @@
 """Pallas kernels vs pure-jnp oracles (ref.py) across shape/density sweeps.
 
-All kernels run under interpret=True on CPU; outputs are exact-integer /
+All kernels run in the Pallas interpreter on CPU; outputs are exact-integer /
 boolean so comparisons are exact (np.array_equal), which is stronger than
 allclose."""
 import numpy as np
@@ -134,3 +134,17 @@ def test_kernel_dead_rows_masked():
     c = int(half.count)
     assert not np.asarray(cyc)[c:].any()
     assert not np.asarray(ext)[c:].any()
+
+
+def test_importing_ops_initialises_no_backend():
+    """The kernels choose compiled or interpreted when a program lowers,
+    so importing them must not pick a backend (a chip belongs to whoever
+    initialises it first)."""
+    import os
+    import subprocess
+    import sys
+    code = ("import repro.kernels.ops\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env=dict(os.environ, JAX_PLATFORMS="cpu"))
