@@ -195,23 +195,6 @@ class EngineConfig:
         return _bucket(c, growth_bits=self.growth_bits)
 
 
-def round_path(cfg: EngineConfig, *, cap: int, nw: int, n: int,
-               n_neighbors: int, cyc_cap: int) -> str:
-    """Which path a superstep's rounds take at this bucket: 'fused' (each
-    round inside a fused pallas kernel — one per round, or one persistent
-    launch per ``rounds_per_launch`` rounds) or 'split' (flag kernel plus
-    XLA compaction). The static-shape rule ``core.expand`` applies while
-    tracing: a launch too large for the persistent kernel loops over
-    single-round fused kernels, so the single-round budget decides."""
-    from ..kernels.fused_round import fits_vmem
-    if not (cfg.fused_round and cfg.backend == "pallas"):
-        return "split"
-    fits = fits_vmem(cap=cap, nw=nw, n=n, n_neighbors=n_neighbors,
-                     cyc_cap=cyc_cap, formulation=cfg.formulation,
-                     store=cfg.store, persistent=False)
-    return "fused" if fits else "split"
-
-
 @dataclasses.dataclass
 class EnumerationResult:
     n_cycles: int                 # all chordless cycles (incl. triangles)

@@ -25,9 +25,18 @@ legacy host engine, sharded step) programs against. Every op is
 batch-transparent: it traces identically with or without a leading lane
 axis, so ``jax.vmap`` of the superstep works on every backend (the pallas
 ops route vmap onto lane-gridded kernels via ``custom_vmap``).
+
+Device stages carry ``jax.named_scope`` names, which land in every
+operation's name stack (the ``tf_op`` of a profiler trace) and change
+nothing else in the program: ``repro.round.fused`` (a round or launch in
+one fused kernel), ``repro.round.flags`` (the split path's flags),
+``repro.round.compact`` (its frontier compaction) and
+``repro.round.cycles`` (its cycle-ring append). They name the stage, not
+the implementation, so a trace reads the same whichever op runs it.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
@@ -391,15 +400,20 @@ class _SlotApply:
     def apply(self, g, f, buf, flags, delta, store):
         cand_v, is_cyc, is_ext = flags
         if store:
-            buf = gather_cycles_into(f, cand_v, is_cyc, buf)
-        f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
+            with jax.named_scope("repro.round.cycles"):
+                buf = gather_cycles_into(f, cand_v, is_cyc, buf)
+        with jax.named_scope("repro.round.compact"):
+            f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
         return f2, buf
 
     def apply_fused(self, g, f, buf, flags, delta, store):
         cand_v, is_cyc, is_ext = flags
         if store:
-            buf = gather_cycles_into(f, cand_v, is_cyc, buf)
-        f2, _ = compact_extensions_gather(g, f, cand_v, is_ext, f.capacity)
+            with jax.named_scope("repro.round.cycles"):
+                buf = gather_cycles_into(f, cand_v, is_cyc, buf)
+        with jax.named_scope("repro.round.compact"):
+            f2, _ = compact_extensions_gather(g, f, cand_v, is_ext,
+                                              f.capacity)
         return f2, buf
 
 
@@ -410,12 +424,15 @@ class _BitwordApply:
 
     def apply(self, g, f, buf, flags, delta, store):
         close_w, ext_w = flags
-        cand_v = bitword_to_slots(ext_w, delta)
-        is_ext = cand_v >= 0
+        with jax.named_scope("repro.round.compact"):
+            cand_v = bitword_to_slots(ext_w, delta)
+            is_ext = cand_v >= 0
         if store:
-            ccand = bitword_to_slots(close_w, delta)
-            buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
-        f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
+            with jax.named_scope("repro.round.cycles"):
+                ccand = bitword_to_slots(close_w, delta)
+                buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
+        with jax.named_scope("repro.round.compact"):
+            f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
         return f2, buf
 
     def apply_fused(self, g, f, buf, flags, delta, store):
@@ -423,9 +440,11 @@ class _BitwordApply:
         # extraction, no cap·Δ row materialization (DESIGN.md §6.8)
         close_w, ext_w = flags
         if store:
-            ccand = bitword_to_slots(close_w, delta)
-            buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
-        f2, _ = bitword_compact_gather(g, f, ext_w, f.capacity)
+            with jax.named_scope("repro.round.cycles"):
+                ccand = bitword_to_slots(close_w, delta)
+                buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
+        with jax.named_scope("repro.round.compact"):
+            f2, _ = bitword_compact_gather(g, f, ext_w, f.capacity)
         return f2, buf
 
 
@@ -511,6 +530,29 @@ def expand_op(formulation: str, backend: str) -> ExpandOp:
 # Fused wave round (DESIGN.md §6.4)
 # ---------------------------------------------------------------------------
 
+# Round paths taken while tracing, one list per open ``record_round_paths``
+# (``core.plan.WavePlan`` opens one around each trace of its superstep).
+_ROUND_PATH_LOGS: list[list[str]] = []
+
+
+@contextlib.contextmanager
+def record_round_paths():
+    """Collect the path ('fused' or 'split') of every round traced inside
+    the block: which one a program runs is a static-shape choice made
+    here while tracing, so the program knows it without re-deriving it."""
+    log: list[str] = []
+    _ROUND_PATH_LOGS.append(log)
+    try:
+        yield log
+    finally:
+        _ROUND_PATH_LOGS.pop()
+
+
+def _took(path: str) -> None:
+    if _ROUND_PATH_LOGS:
+        _ROUND_PATH_LOGS[-1].append(path)
+
+
 def fused_kernel_fits(formulation: str, g: BitsetGraph, f: Frontier,
                       buf: CycleBuffer, *, store: bool,
                       persistent: bool) -> bool:
@@ -555,8 +597,12 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
         op = expand_op(formulation, backend)
     if fused and op.fused_kernel and fused_kernel_fits(
             op.formulation, g, f, buf, store=store, persistent=False):
-        return op.fused_round(g, f, buf, delta, store)
-    flags, n_cyc, n_new = op.flags(g, f, delta)
+        _took("fused")
+        with jax.named_scope("repro.round.fused"):
+            return op.fused_round(g, f, buf, delta, store)
+    _took("split")
+    with jax.named_scope("repro.round.flags"):
+        flags, n_cyc, n_new = op.flags(g, f, delta)
     ok_frontier = n_new <= f.capacity
     if store:
         ok_cycles = (buf.count + n_cyc) <= buf.capacity
@@ -611,7 +657,10 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
         rlimit = jnp.int32(rounds)
     if fused and op.fused_kernel and fused_kernel_fits(
             op.formulation, g, f, buf, store=store, persistent=True):
-        return op.persistent_round(g, f, buf, delta, store, rounds, rlimit)
+        _took("fused")
+        with jax.named_scope("repro.round.fused"):
+            return op.persistent_round(g, f, buf, delta, store, rounds,
+                                       rlimit)
 
     zeros = jnp.zeros((rounds,), jnp.int32)
 

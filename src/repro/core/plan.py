@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from .bitset_graph import BitsetGraph, n_words_for, pack_bits
 from . import engine as _engine
+from . import expand as _expand
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +86,9 @@ class WavePlan:
 
     Calling the plan executes it; ``n_traces`` counts how many times jax
     actually (re)traced the wrapped function — the zero-retrace assertion
-    of the warm path. ``lower(*args)`` exposes the jit lowering so tests
+    of the warm path. ``round_path`` is the path ('fused' or 'split') the
+    traced rounds took, recorded while tracing ("" before the first trace).
+    ``lower(*args)`` exposes the jit lowering so tests
     can assert the donation aliasing made it into the program
     (an ``XLA_FLAGS=--log-donation``-style check without log scraping).
     """
@@ -96,6 +99,7 @@ class WavePlan:
         self.n_traces = 0
         self.n_calls = 0
         self.donated = donate
+        self.round_path = ""
 
         statics = dict(delta=key.delta, store=key.store,
                        formulation=key.formulation, backend=key.backend,
@@ -105,7 +109,12 @@ class WavePlan:
         def _traced(g, f, buf, rounds_limit):
             # runs once per TRACE (not per call): the retrace observer
             self.n_traces += 1
-            return _engine.wave_superstep(g, f, buf, rounds_limit, **statics)
+            with _expand.record_round_paths() as paths:
+                out = _engine.wave_superstep(g, f, buf, rounds_limit,
+                                             **statics)
+            # the loop body is traced once, so its rounds take one path
+            (self.round_path,) = set(paths)
+            return out
 
         fn = _traced
         if key.batch:

@@ -43,8 +43,7 @@ import jax.numpy as jnp
 from .bitset_graph import BitsetGraph
 from . import triplets as T
 from .engine import (STATUS_NAMES, EngineConfig, EnumerationResult, _DONE,
-                     _DRAIN, _GROW, _RUN, _SHRINK, _enumerate_host,
-                     round_path)
+                     _DRAIN, _GROW, _RUN, _SHRINK, _enumerate_host)
 from .frontier import (empty_cycle_buffer, empty_frontier, with_capacity,
                        with_capacity_batched)
 from .plan import (PlanKey, ProgramCache, RecyclePlan, WavePlan,
@@ -216,16 +215,19 @@ class CycleService:
         self._tuner.observe(tune_key, cfg, res.history, n=g.n,
                             nw=g.adj_bits.shape[1], traces=(trace,))
 
-    def _request_spans(self, rid: str, t_req: float,
-                       trace: WaveTrace) -> None:
+    def _request_spans(self, rid: str, t_req: float, trace: WaveTrace,
+                       dispatches: bool = True) -> None:
         """Decompose one finished run into request spans (DESIGN.md §6.10):
-        a root ``request`` slice covering the whole call plus one child per
-        recorded dispatch, all on the shared service clock. Only runs when
-        spans are enabled AND the run recorded events — the disabled path
-        constructs no Span objects at all (overhead contract)."""
+        a root ``request`` slice covering the whole call plus, where the
+        run's host phases did not record them already (``dispatches``), one
+        child per recorded dispatch, all on the shared service clock. Only
+        runs when spans are enabled AND the run recorded events — the
+        disabled path constructs no Span objects at all (overhead
+        contract)."""
         if not rid or not self.spans.enabled:
             return
-        for wave, ev in enumerate(getattr(trace, "events", ())):
+        for wave, ev in enumerate(getattr(trace, "events", ())
+                                  if dispatches else ()):
             self.spans.add(ev.kind, rid, ev.t_start_ms,
                            max(ev.wall_ms, ev.t_ms), wave=wave,
                            status=ev.status, rounds=ev.rounds,
@@ -303,35 +305,37 @@ class CycleService:
         self._m["graphs"].inc()
         rid = new_request_id() if self.spans.enabled else ""
         t_req = self.spans.now_ms() if rid else 0.0
-        cfg, tkey, observe = self._resolve_config(
-            g.n, g.m, max(g.max_degree, 1), cfg, explicit=config is not None)
-        trace = self._new_trace(observe)
-        if cfg.mesh is not None:
-            from .distributed import enumerate_sharded
-            res = enumerate_sharded(g, cfg, cache=self._cache, trace=trace,
-                                    progress=progress, metrics=self.metrics)
+        with self.spans.phase("enumerate", rid):
+            cfg, tkey, observe = self._resolve_config(
+                g.n, g.m, max(g.max_degree, 1), cfg,
+                explicit=config is not None)
+            trace = self._new_trace(observe)
+            wave = cfg.mesh is None and cfg.engine != "host"
+            if cfg.mesh is not None:
+                from .distributed import enumerate_sharded
+                res = enumerate_sharded(g, cfg, cache=self._cache,
+                                        trace=trace, progress=progress,
+                                        metrics=self.metrics)
+            elif not wave:
+                res = _enumerate_host(g, cfg, progress, trace=trace)
+            else:
+                gen = self._wave_events(g, cfg, progress, trace, rid=rid)
+                chunks: list[np.ndarray] = []
+                while True:
+                    try:
+                        chunks.append(next(gen))
+                    except StopIteration as stop:
+                        res = stop.value
+                        break
+                if cfg.store:
+                    nw = g.adj_bits.shape[1]
+                    with self.spans.phase("drain", rid):
+                        res.cycle_masks = (
+                            np.concatenate(chunks, axis=0) if chunks
+                            else np.zeros((0, nw), np.uint32))
             self._after_run(g, cfg, tkey, observe, trace, res)
-            self._request_spans(rid, t_req, trace)
-            return res
-        if cfg.engine == "host":
-            res = _enumerate_host(g, cfg, progress, trace=trace)
-            self._after_run(g, cfg, tkey, observe, trace, res)
-            self._request_spans(rid, t_req, trace)
-            return res
-        gen = self._wave_events(g, cfg, progress, trace, rid=rid)
-        chunks: list[np.ndarray] = []
-        while True:
-            try:
-                chunks.append(next(gen))
-            except StopIteration as stop:
-                res = stop.value
-                break
-        if cfg.store:
-            nw = g.adj_bits.shape[1]
-            res.cycle_masks = (np.concatenate(chunks, axis=0) if chunks
-                               else np.zeros((0, nw), np.uint32))
-        self._after_run(g, cfg, tkey, observe, trace, res)
-        self._request_spans(rid, t_req, trace)
+        # the wave driver's host phases already recorded its dispatches
+        self._request_spans(rid, t_req, trace, dispatches=not wave)
         return res
 
     def stream(self, g: BitsetGraph, *,
@@ -385,23 +389,27 @@ class CycleService:
         """The wave driver loop as an event generator: yields drained mask
         chunks (store mode), returns the EnumerationResult (masks unset).
         Port of the PR-1 ``_enumerate_wave`` with the superstep dispatch
-        replaced by a ProgramCache lookup."""
+        replaced by a ProgramCache lookup. Every host statement between
+        two supersteps runs inside one host phase (``SpanLog.phase``), so
+        a profiler trace names what the host did while the device idled."""
+        phase = self.spans.phase
         delta = max(g.max_degree, 1)
         nw = g.adj_bits.shape[1]
-        frontier, tri_masks, n_tri = T.initial_frontier_device(
-            g, bucket=cfg.bucket, backend=cfg.backend)
-
         trace = trace if trace is not None else disabled_trace()
+        with phase("seed", rid):
+            frontier, tri_masks, n_tri = T.initial_frontier_device(
+                g, bucket=cfg.bucket, backend=cfg.backend, trace=trace)
+            cyc_cap = (cfg.bucket(max(cfg.cycle_buffer_rows, 16))
+                       if cfg.store else 1)
+            buf = empty_cycle_buffer(cyc_cap, nw)
+        with phase("readback", rid):
+            cnt = int(jax.device_get(frontier.count))
+            trace.sync()
+            trace.d2h()
         n_cycles = n_tri
-        cnt = int(frontier.count)
-        trace.sync()
         history = [dict(step=0, T=cnt, C=n_tri)]
         limit = (cfg.max_iters if cfg.max_iters is not None
                  else max(g.n - 3, 0))
-
-        cyc_cap = (cfg.bucket(max(cfg.cycle_buffer_rows, 16))
-                   if cfg.store else 1)
-        buf = empty_cycle_buffer(cyc_cap, nw)
         if cfg.store:
             yield tri_masks
 
@@ -412,78 +420,94 @@ class CycleService:
             if relaunches > 4 * limit + 16:
                 raise RuntimeError(
                     "wave engine: no progress across relaunches")
-            k = min(cfg.superstep_rounds, limit - it)
-            cap_in, cnt_in = frontier.capacity, cnt
-            plan = self._wave_plan(g.n, g.m, frontier.capacity, cyc_cap, nw,
-                                   delta, cfg)
-            fresh = plan.n_calls == 0
-            trace.tic()
-            frontier, buf, r, status, th, ch, pn, pc = plan(
-                g, frontier, buf, jnp.int32(k))
-            (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
-             bc_h) = jax.device_get(
-                (status, r, th, ch, pn, pc, frontier.count, buf.count))
-            trace.sync()
-            trace.dispatch(
-                kind="superstep", bucket=cap_in, cyc_cap=cyc_cap, budget=k,
-                rounds=int(r_h), status=STATUS_NAMES[int(status_h)],
-                t_sizes=th_h[:int(r_h)], c_counts=ch_h[:int(r_h)],
-                enter_count=cnt_in, exit_count=int(cnt_h),
-                pending_new=int(pn_h), pending_cyc=int(pc_h),
-                cyc_fill=int(bc_h), t_ms=trace.toc_ms(), fresh=fresh,
-                plan_key=str(plan.key),
-                rounds_per_launch=cfg.rounds_per_launch,
-                lane_rids=(rid,) if rid else (),
-                lane_rounds=(it + int(r_h),) if rid else (),
-                round_path=round_path(
-                    cfg, cap=cap_in, nw=nw, n=g.n,
-                    n_neighbors=g.neighbors.shape[0], cyc_cap=cyc_cap))
-
-            for i in range(int(r_h)):
-                n_cycles += int(ch_h[i])
-                rec = dict(step=it + i + 1, T=int(th_h[i]), C=n_cycles)
-                history.append(rec)
-                if progress:
-                    progress(rec)
-            it += int(r_h)
-            cnt = int(cnt_h)
-            status_h = int(status_h)
+            with phase("superstep", rid):
+                k = min(cfg.superstep_rounds, limit - it)
+                cap_in, cnt_in = frontier.capacity, cnt
+                plan = self._wave_plan(g.n, g.m, frontier.capacity, cyc_cap,
+                                       nw, delta, cfg)
+                fresh = plan.n_calls == 0
+                trace.tic()
+                frontier, buf, r, status, th, ch, pn, pc = plan(
+                    g, frontier, buf, jnp.int32(k))
+            with phase("readback", rid):
+                fetched = (status, r, th, ch, pn, pc, frontier.count,
+                           buf.count)
+                (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
+                 bc_h) = jax.device_get(fetched)
+                trace.sync()
+                trace.d2h(len(fetched))
+                trace.dispatch(
+                    kind="superstep", bucket=cap_in, cyc_cap=cyc_cap,
+                    budget=k, rounds=int(r_h),
+                    status=STATUS_NAMES[int(status_h)],
+                    t_sizes=th_h[:int(r_h)], c_counts=ch_h[:int(r_h)],
+                    enter_count=cnt_in, exit_count=int(cnt_h),
+                    pending_new=int(pn_h), pending_cyc=int(pc_h),
+                    cyc_fill=int(bc_h), t_ms=trace.toc_ms(), fresh=fresh,
+                    plan_key=str(plan.key),
+                    rounds_per_launch=cfg.rounds_per_launch,
+                    lane_rids=(rid,) if rid else (),
+                    lane_rounds=(it + int(r_h),) if rid else (),
+                    round_path=plan.round_path)
+                for i in range(int(r_h)):
+                    n_cycles += int(ch_h[i])
+                    rec = dict(step=it + i + 1, T=int(th_h[i]), C=n_cycles)
+                    history.append(rec)
+                    if progress:
+                        progress(rec)
+                it += int(r_h)
+                cnt = int(cnt_h)
+                status_h = int(status_h)
 
             if status_h == _DRAIN:
                 # cycle buffer full: drain to host, regrow if one round
                 # alone exceeds the current buffer.
-                if int(bc_h):
-                    yield np.asarray(buf.masks[:int(bc_h)])
-                    trace.sync()
-                    trace.drain()
-                cyc_cap = max(cyc_cap, cfg.bucket(max(int(pc_h), 1)))
-                buf = empty_cycle_buffer(cyc_cap, nw)
+                chunk = None
+                with phase("drain", rid):
+                    if int(bc_h):
+                        chunk = np.asarray(buf.masks[:int(bc_h)])
+                        trace.sync()
+                        trace.d2h()
+                        trace.drain()
+                    cyc_cap = max(cyc_cap, cfg.bucket(max(int(pc_h), 1)))
+                    buf = empty_cycle_buffer(cyc_cap, nw)
+                if chunk is not None:
+                    yield chunk
             elif status_h == _GROW:
                 # re-bucket the headroom'd size so the shape stays inside
                 # the growth_bits bucket family (off-family shapes would
                 # churn recompiles against the SHRINK path).
-                new_cap = cfg.bucket(
-                    cfg.bucket(max(int(pn_h), 1))
-                    << max(cfg.grow_headroom, 0))
-                frontier = with_capacity(frontier, new_cap)
-                trace.transition()
+                with phase("rebucket", rid):
+                    new_cap = cfg.bucket(
+                        cfg.bucket(max(int(pn_h), 1))
+                        << max(cfg.grow_headroom, 0))
+                    frontier = with_capacity(frontier, new_cap)
+                    trace.transition()
             elif status_h in (_RUN, _SHRINK) and cnt > 0:
                 # round budget exhausted / wave decayed below the bucket:
                 # shrink as the wave dies down (bounds dead-row work, like
                 # the host loop does every round).
                 new_cap = cfg.bucket(max(cnt, 1))
                 if new_cap < frontier.capacity:
-                    frontier = with_capacity(frontier, new_cap)
-                    trace.transition()
+                    with phase("rebucket", rid):
+                        frontier = with_capacity(frontier, new_cap)
+                        trace.transition()
             elif status_h == _DONE:
                 break
 
         if cfg.store:
-            bc = int(jax.device_get(buf.count))
-            if bc:
-                yield np.asarray(buf.masks[:bc])
-                trace.drain()
-            trace.sync()
+            chunk = None
+            with phase("readback", rid):
+                bc = int(jax.device_get(buf.count))
+                trace.sync()
+                trace.d2h()
+            with phase("drain", rid):
+                if bc:
+                    chunk = np.asarray(buf.masks[:bc])
+                    trace.d2h()
+                    trace.drain()
+            if chunk is not None:
+                yield chunk
 
         return EnumerationResult(
             n_cycles=n_cycles, n_triangles=n_tri, cycle_masks=None,
@@ -539,42 +563,49 @@ class CycleService:
         gbat = batch_graphs(graphs)
         nw = gbat.adj_bits.shape[-1]
 
+        # host phases are annotated only: the batch's request spans come
+        # from its dispatch events (_request_spans)
+        phase = self.spans.phase
+
         # stage 1 device-side: one counts dispatch + ONE seeding dispatch
         # scatter every lane's triplets (and triangle bitmaps) in place —
         # no host nonzero, no per-lane H2D (DESIGN.md §6.7). wall_ms spans
         # the whole boundary (staging included), not just the device time.
-        wall_t0 = time.perf_counter()
-        trace.tic()
-        fbat, tri_bat, ntris, cnts = T.initial_frontier_batched(
-            gbat, delta=delta, bucket=cfg.bucket, backend=cfg.backend)
-        cap = fbat.path.shape[1]
-        trace.sync()
-        seed_wall_ms = (time.perf_counter() - wall_t0) * 1e3
-        self._m_boundary.inc(seed_wall_ms)
-        trace.dispatch(
-            kind="seed", bucket=cap, cyc_cap=0, budget=0, rounds=0,
-            status="RUN", enter_count=int(cnts.sum()),
-            exit_count=int(cnts.sum()), t_ms=trace.toc_ms(), launches=2,
-            wall_ms=seed_wall_ms,
-            lane_rids=(rid,) * B if rid else ())
+        with phase("seed"):
+            wall_t0 = time.perf_counter()
+            trace.tic()
+            fbat, tri_bat, ntris, cnts = T.initial_frontier_batched(
+                gbat, delta=delta, bucket=cfg.bucket, backend=cfg.backend,
+                trace=trace)
+            cap = fbat.path.shape[1]
+            trace.sync()
+            seed_wall_ms = (time.perf_counter() - wall_t0) * 1e3
+            self._m_boundary.inc(seed_wall_ms)
+            trace.dispatch(
+                kind="seed", bucket=cap, cyc_cap=0, budget=0, rounds=0,
+                status="RUN", enter_count=int(cnts.sum()),
+                exit_count=int(cnts.sum()), t_ms=trace.toc_ms(), launches=2,
+                wall_ms=seed_wall_ms,
+                lane_rids=(rid,) * B if rid else ())
 
-        cyc_cap = (cfg.bucket(max(cfg.cycle_buffer_rows, 16))
-                   if cfg.store else 1)
-        bufbat = empty_cycle_buffer(cyc_cap, nw, batch=B)
+            cyc_cap = (cfg.bucket(max(cfg.cycle_buffer_rows, 16))
+                       if cfg.store else 1)
+            bufbat = empty_cycle_buffer(cyc_cap, nw, batch=B)
 
-        limits = np.array([max(g.n - 3, 0) for g in graphs], np.int64)
-        if cfg.max_iters is not None:
-            limits = np.minimum(limits, cfg.max_iters)
-        its = np.zeros(B, np.int64)
-        n_cycles = [int(t) for t in ntris]
-        histories = [[dict(step=0, T=int(cnts[i]), C=int(ntris[i]))]
-                     for i in range(B)]
-        if cfg.store:
-            tri_h = np.asarray(tri_bat)
-            chunks: list[list[np.ndarray]] = [
-                [tri_h[i, :int(ntris[i])].copy()] for i in range(B)]
-        else:
-            chunks = [[] for _ in range(B)]
+            limits = np.array([max(g.n - 3, 0) for g in graphs], np.int64)
+            if cfg.max_iters is not None:
+                limits = np.minimum(limits, cfg.max_iters)
+            its = np.zeros(B, np.int64)
+            n_cycles = [int(t) for t in ntris]
+            histories = [[dict(step=0, T=int(cnts[i]), C=int(ntris[i]))]
+                         for i in range(B)]
+            if cfg.store:
+                tri_h = np.asarray(tri_bat)
+                trace.d2h()
+                chunks: list[list[np.ndarray]] = [
+                    [tri_h[i, :int(ntris[i])].copy()] for i in range(B)]
+            else:
+                chunks = [[] for _ in range(B)]
 
         K = cfg.superstep_rounds
         relaunches = 0
@@ -584,67 +615,72 @@ class CycleService:
             if relaunches > 4 * int(limits.max()) + 16:
                 raise RuntimeError(
                     "batched wave engine: no progress across relaunches")
-            k_i = np.where(active, np.minimum(K, limits - its), 0)
-            cap_in, live_in = cap, int(cnts.sum())
-            plan = self._wave_plan(n_pad, m_pad, cap, cyc_cap, nw, delta,
-                                   cfg, batch=B)
-            fresh = plan.n_calls == 0
-            trace.tic()
-            fbat, bufbat, r, status, th, ch, pn, pc = plan(
-                gbat, fbat, bufbat, jnp.asarray(k_i, jnp.int32))
-            (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
-             bc_h) = jax.device_get(
-                (status, r, th, ch, pn, pc, fbat.count, bufbat.count))
-            trace.sync()
-            lane_statuses = {int(s) for s in np.asarray(status_h)}
-            agg = next(s for s in (_DRAIN, _GROW, _SHRINK, _RUN, _DONE)
-                       if s in lane_statuses)
-            trace.dispatch(
-                kind="batch", bucket=cap_in, cyc_cap=cyc_cap,
-                budget=int(k_i.max()), rounds=int(np.asarray(r_h).max()),
-                status=STATUS_NAMES[agg],
-                enter_count=live_in,
-                exit_count=int(np.asarray(cnt_h).sum()),
-                cyc_fill=int(np.asarray(bc_h).sum()),
-                t_ms=trace.toc_ms(), fresh=fresh,
-                plan_key=str(plan.key),
-                rounds_per_launch=cfg.rounds_per_launch,
-                lane_rids=(rid,) * B if rid else (),
-                lane_rounds=tuple(
-                    int(v) for v in its + np.asarray(r_h, np.int64))
-                if rid else (),
-                round_path=round_path(
-                    cfg, cap=cap_in, nw=nw, n=n_pad,
-                    n_neighbors=gbat.neighbors.shape[-1], cyc_cap=cyc_cap))
+            with phase("superstep"):
+                k_i = np.where(active, np.minimum(K, limits - its), 0)
+                cap_in, live_in = cap, int(cnts.sum())
+                plan = self._wave_plan(n_pad, m_pad, cap, cyc_cap, nw,
+                                       delta, cfg, batch=B)
+                fresh = plan.n_calls == 0
+                trace.tic()
+                fbat, bufbat, r, status, th, ch, pn, pc = plan(
+                    gbat, fbat, bufbat, jnp.asarray(k_i, jnp.int32))
+            with phase("readback"):
+                fetched = (status, r, th, ch, pn, pc, fbat.count,
+                           bufbat.count)
+                (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
+                 bc_h) = jax.device_get(fetched)
+                trace.sync()
+                trace.d2h(len(fetched))
+                lane_statuses = {int(s) for s in np.asarray(status_h)}
+                agg = next(s for s in (_DRAIN, _GROW, _SHRINK, _RUN, _DONE)
+                           if s in lane_statuses)
+                trace.dispatch(
+                    kind="batch", bucket=cap_in, cyc_cap=cyc_cap,
+                    budget=int(k_i.max()),
+                    rounds=int(np.asarray(r_h).max()),
+                    status=STATUS_NAMES[agg],
+                    enter_count=live_in,
+                    exit_count=int(np.asarray(cnt_h).sum()),
+                    cyc_fill=int(np.asarray(bc_h).sum()),
+                    t_ms=trace.toc_ms(), fresh=fresh,
+                    plan_key=str(plan.key),
+                    rounds_per_launch=cfg.rounds_per_launch,
+                    lane_rids=(rid,) * B if rid else (),
+                    lane_rounds=tuple(
+                        int(v) for v in its + np.asarray(r_h, np.int64))
+                    if rid else (),
+                    round_path=plan.round_path)
 
-            for i in range(B):
-                for j in range(int(r_h[i])):
-                    n_cycles[i] += int(ch_h[i, j])
-                    histories[i].append(dict(step=int(its[i]) + j + 1,
-                                             T=int(th_h[i, j]),
-                                             C=n_cycles[i]))
-            its += np.asarray(r_h, np.int64)
-            cnts = np.asarray(cnt_h, np.int64)
-            status_h = np.asarray(status_h)
+                for i in range(B):
+                    for j in range(int(r_h[i])):
+                        n_cycles[i] += int(ch_h[i, j])
+                        histories[i].append(dict(step=int(its[i]) + j + 1,
+                                                 T=int(th_h[i, j]),
+                                                 C=n_cycles[i]))
+                its += np.asarray(r_h, np.int64)
+                cnts = np.asarray(cnt_h, np.int64)
+                status_h = np.asarray(status_h)
 
             drains = status_h == _DRAIN
             grows = status_h == _GROW
             if drains.any():
                 # drain EVERY lane with pending masks in one host copy;
                 # per-lane chunk order stays discovery order.
-                masks_h = np.asarray(bufbat.masks)
-                for i in range(B):
-                    bc = int(bc_h[i])
-                    if bc:
-                        chunks[i].append(masks_h[i, :bc].copy())
-                        trace.drain()
-                trace.sync()
-                # regrow only from the lanes that actually overflowed —
-                # a simultaneous GROW lane's pending_cyc is an aborted
-                # round's size, not a drain signal.
-                cyc_cap = max(cyc_cap,
-                              cfg.bucket(max(int(pc_h[drains].max()), 1)))
-                bufbat = empty_cycle_buffer(cyc_cap, nw, batch=B)
+                with phase("drain"):
+                    masks_h = np.asarray(bufbat.masks)
+                    trace.d2h()
+                    for i in range(B):
+                        bc = int(bc_h[i])
+                        if bc:
+                            chunks[i].append(masks_h[i, :bc].copy())
+                            trace.drain()
+                    trace.sync()
+                    # regrow only from the lanes that actually overflowed —
+                    # a simultaneous GROW lane's pending_cyc is an aborted
+                    # round's size, not a drain signal.
+                    cyc_cap = max(cyc_cap, cfg.bucket(
+                        max(int(pc_h[drains].max()), 1)))
+                    bufbat = empty_cycle_buffer(cyc_cap, nw, batch=B)
             if grows.any():
                 # shared bucket must cover the largest pending lane (a
                 # growing lane's need always exceeds the current bucket,
@@ -653,9 +689,10 @@ class CycleService:
                 new_cap = cfg.bucket(
                     cfg.bucket(max(need, 1)) << max(cfg.grow_headroom, 0))
                 if new_cap != cap:
-                    fbat = with_capacity_batched(fbat, new_cap)
-                    cap = new_cap
-                    trace.transition()
+                    with phase("rebucket"):
+                        fbat = with_capacity_batched(fbat, new_cap)
+                        cap = new_cap
+                        trace.transition()
             elif not drains.any() and cnts.max() > 0:
                 # no transition forced a relaunch size-up: shrink to the
                 # largest live lane as the waves die down (skip on the
@@ -663,20 +700,26 @@ class CycleService:
                 # guard).
                 new_cap = cfg.bucket(max(int(cnts.max()), 1))
                 if new_cap < cap:
-                    fbat = with_capacity_batched(fbat, new_cap)
-                    cap = new_cap
-                    trace.transition()
+                    with phase("rebucket"):
+                        fbat = with_capacity_batched(fbat, new_cap)
+                        cap = new_cap
+                        trace.transition()
             active = (its < limits) & (cnts > 0)
 
         if cfg.store:
-            bc_h = np.asarray(jax.device_get(bufbat.count))
-            if bc_h.any():
-                masks_h = np.asarray(bufbat.masks)
-                for i in range(B):
-                    if int(bc_h[i]):
-                        chunks[i].append(masks_h[i, :int(bc_h[i])].copy())
-                        trace.drain()
-            trace.sync()
+            with phase("readback"):
+                bc_h = np.asarray(jax.device_get(bufbat.count))
+                trace.d2h()
+            with phase("drain"):
+                if bc_h.any():
+                    masks_h = np.asarray(bufbat.masks)
+                    trace.d2h()
+                    for i in range(B):
+                        if int(bc_h[i]):
+                            chunks[i].append(
+                                masks_h[i, :int(bc_h[i])].copy())
+                            trace.drain()
+                trace.sync()
 
         if observe and tkey is not None:
             # first visit of this (shape × batch-size) class: profile the

@@ -24,6 +24,9 @@ Two compaction paths (DESIGN.md §2, §6.7):
 
 Both produce bit-identical frontiers: cumsum order over the flat (n·Δ·Δ)
 grid IS ascending-index order, the exact order ``np.flatnonzero`` walks.
+The device-side programs run under the name scope ``repro.seed`` (the
+stage's name in a profiler trace; ``jax.named_call`` keeps each program's
+own name, so its compiled form is unchanged).
 """
 from __future__ import annotations
 
@@ -186,7 +189,8 @@ def _seed_from_flags(g: BitsetGraph, tri, trip, capacity: int,
 
 @functools.lru_cache(maxsize=None)
 def _flags_counts_program(delta: int, backend: str, batched: bool):
-    fn = lambda g: _flags_counts(g, delta, backend)
+    fn = jax.named_call(lambda g: _flags_counts(g, delta, backend),
+                        name="repro.seed")
     if batched:
         fn = jax.vmap(fn)
     return jax.jit(fn)
@@ -195,8 +199,10 @@ def _flags_counts_program(delta: int, backend: str, batched: bool):
 @functools.lru_cache(maxsize=None)
 def _seed_program(delta: int, capacity: int, tri_capacity: int,
                   batched: bool):
-    fn = lambda g, tri, trip: _seed_from_flags(g, tri, trip, capacity,
-                                               tri_capacity)
+    fn = jax.named_call(
+        lambda g, tri, trip: _seed_from_flags(g, tri, trip, capacity,
+                                              tri_capacity),
+        name="repro.seed")
     if batched:
         fn = jax.vmap(fn)
     return jax.jit(fn)
@@ -204,12 +210,13 @@ def _seed_program(delta: int, capacity: int, tri_capacity: int,
 
 def initial_frontier_device(g: BitsetGraph, *,
                             bucket=lambda c: max(1, int(c)),
-                            backend: str = "jnp"):
+                            backend: str = "jnp", trace=None):
     """Device-side stage 1 for one graph: a flags+counts dispatch sizes the
     bucket (flag grids stay device-resident), then ONE seeding dispatch
     scatters every triplet and triangle in place (no host nonzero).
     Drop-in for ``initial_frontier`` — returns (frontier, triangle_masks
-    (t, nw) uint32 np.ndarray, n_triangles), row-for-row identical."""
+    (t, nw) uint32 np.ndarray, n_triangles), row-for-row identical.
+    ``trace`` (a ``WaveTrace``) counts the arrays read to the host."""
     nw = g.adj_bits.shape[1]
     if g.m == 0:
         from .frontier import empty_frontier
@@ -219,6 +226,8 @@ def initial_frontier_device(g: BitsetGraph, *,
         delta, backend, False)(g)
     n_tri, n_trip = (int(x) for x in jax.device_get((ntri_j, ntrip_j)))
     cap = bucket(max(n_trip, 1))
+    if trace is not None:
+        trace.d2h(3)    # the two counts, then the triangle masks
     # bucket the triangle capacity too: the fused seed program is one jit
     # shape for BOTH scatters, so an exact tcap would recompile it for
     # every distinct triangle count (callers slice to n_tri anyway)
@@ -231,7 +240,7 @@ def initial_frontier_device(g: BitsetGraph, *,
 def initial_frontier_batched(gbat: BitsetGraph, *, delta: int, bucket,
                              backend: str = "jnp",
                              capacity: int | None = None,
-                             tri_capacity: int | None = None):
+                             tri_capacity: int | None = None, trace=None):
     """Device-side stage 1 for a stacked graph batch: ONE flags+counts
     dispatch for every lane, then ONE seeding dispatch that cumsum-scatters
     all B frontiers (and triangle bitmaps) — no host nonzero, no per-lane
@@ -248,11 +257,14 @@ def initial_frontier_batched(gbat: BitsetGraph, *, delta: int, bucket,
     (rows stay identical — a larger capacity only grows the zero padding;
     cumsum order over the flat grid does not depend on it). A lane whose
     need exceeds the floor still wins: the floor is a max, never a trim.
+    ``trace`` (a ``WaveTrace``) counts the arrays read to the host.
     """
     tri, trip, ntri_j, ntrip_j = _flags_counts_program(
         delta, backend, True)(gbat)
     n_tri, n_trip = (np.asarray(jax.device_get(x), np.int64)
                      for x in (ntri_j, ntrip_j))
+    if trace is not None:
+        trace.d2h(2)
     cap = bucket(max(int(n_trip.max()), 1))
     if capacity is not None:
         cap = max(cap, int(capacity))

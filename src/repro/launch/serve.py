@@ -306,7 +306,9 @@ def main():
                            write_json)
         doc = to_perfetto(collect_events(service), service.spans.spans,
                           meta=dict(recycle=args.recycle,
-                                    requests=args.requests))
+                                    requests=args.requests,
+                                    origin_unix_ns=service.spans
+                                    .origin_unix_ns))
         errs = validate_perfetto(doc)
         write_json(args.trace_out, doc)
         print(f"perfetto trace -> {args.trace_out} "

@@ -10,7 +10,9 @@ Three parts, threaded through every layer:
                  stats-dict shapes are preserved as views over it.
 * ``spans``    — request-ids minted at every service entry point, each
                  request decomposed into queue_wait → seed → superstep
-                 slices → recycle/retire → drain on one shared clock.
+                 slices → recycle/retire → drain on one shared clock;
+                 ``SpanLog.phase`` opens each host phase under a
+                 ``repro.*`` profiler annotation as well.
 * ``export``   — Chrome/Perfetto ``trace_event`` rendering of the
                  TraceEvent stream + span set (per-lane tracks, counter
                  tracks, guard-trip instants), the schema validators the
@@ -18,14 +20,14 @@ Three parts, threaded through every layer:
 """
 from .metrics import (DEFAULT_MS_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, validate_metrics)
-from .spans import Span, SpanLog, new_request_id, reset_request_ids
+from .spans import SPAN_NAMES, Span, SpanLog, new_request_id
 from .export import (FlightRecorder, collect_events, to_perfetto,
                      validate_perfetto, write_json)
 
 __all__ = [
     "DEFAULT_MS_BUCKETS", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "validate_metrics",
-    "Span", "SpanLog", "new_request_id", "reset_request_ids",
+    "SPAN_NAMES", "Span", "SpanLog", "new_request_id",
     "FlightRecorder", "collect_events", "to_perfetto", "validate_perfetto",
     "write_json",
 ]

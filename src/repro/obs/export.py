@@ -20,6 +20,11 @@ span set (``obs.spans``) as a Chrome ``trace_event`` JSON document that
 
 Timestamps are microseconds on the shared service clock (spans and events
 carry the same origin), so slices and spans line up without reconciliation.
+A document whose ``otherData`` carries ``origin_unix_ns`` (the span log's
+origin on the profiler's host clock, as ``launch/serve.py --trace-out``
+writes it) lines up with a JAX profiler trace by one subtraction: a slice
+at ``ts`` µs sits at ``origin_unix_ns + 1e3·ts - profile_start_time`` ns
+of the trace.
 
 ``validate_perfetto`` is the schema gate (required keys, per-track
 monotonic timestamps, span nesting) that ``benchmarks/run.py --check``

@@ -14,6 +14,19 @@ into a tree of named slices on one shared clock:
       recycle                     (admission-merge boundary it rode in on)
       drain / retire              (CycleBuffer flush, lane retirement)
 
+``SpanLog.phase`` is the one way the host driver opens a phase: it always
+opens a ``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` (a no-op
+unless the profiler runs), and on an enabled log it also records the
+interval as a ``Span`` of its request. The driver's phases are
+``enumerate`` (a whole single-graph request), ``seed`` (stage 1),
+``superstep`` (plan lookup, argument staging and the program call),
+``readback`` (device-to-host reads of status, counts and histories),
+``drain`` (cycle-ring flush), ``rebucket`` (frontier capacity change) and
+the scheduler's ``recycle`` / ``retire`` boundaries. Host phases and the
+device operations of a profiler trace share one clock: ``origin_unix_ns``
+is the log's origin on the profiler's host clock (Unix nanoseconds), so
+a span starts at ``origin_unix_ns + t_start_ms * 1e6`` there.
+
 This is the substrate the ROADMAP's deadline/priority admission control
 will schedule against: "where did this request's milliseconds go" is
 answerable from the span log alone, without re-running anything.
@@ -25,13 +38,18 @@ telemetry overhead contract, tested in ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
 
-# Span names, in the order a recycled request walks them.
-SPAN_NAMES = ("request", "queue_wait", "seed", "superstep", "recycle",
-              "retire", "drain")
+from jax.profiler import TraceAnnotation
+
+# Span names, in the order a request walks them.
+SPAN_NAMES = ("request", "enumerate", "queue_wait", "seed", "superstep",
+              "readback", "rebucket", "recycle", "retire", "drain")
+# the profiler annotation of each phase (an unknown name is a KeyError)
+_ANNOTATIONS = {name: "repro." + name for name in SPAN_NAMES}
 
 _REQ_IDS = itertools.count(1)
 
@@ -40,13 +58,6 @@ def new_request_id(prefix: str = "r") -> str:
     """Process-unique request id (``r000001``, ...). Monotone so sorted
     request ids are arrival-ordered within one process."""
     return f"{prefix}{next(_REQ_IDS):06d}"
-
-
-def reset_request_ids() -> None:
-    """Restart the id sequence (tests only — ids must stay unique within
-    any one exported trace)."""
-    global _REQ_IDS
-    _REQ_IDS = itertools.count(1)
 
 
 @dataclasses.dataclass
@@ -66,18 +77,6 @@ class Span:
     def t_end_ms(self) -> float:
         return self.t_start_ms + self.dur_ms
 
-    def to_dict(self) -> dict:
-        out = dict(rid=self.rid, name=self.name,
-                   t_start_ms=round(self.t_start_ms, 4),
-                   dur_ms=round(self.dur_ms, 4))
-        if self.lane >= 0:
-            out["lane"] = self.lane
-        if self.wave >= 0:
-            out["wave"] = self.wave
-        if self.attrs:
-            out["attrs"] = self.attrs
-        return out
-
 
 class SpanLog:
     """Bounded recorder of request spans on one clock.
@@ -85,26 +84,42 @@ class SpanLog:
     ``origin`` is the perf_counter epoch all ``t_start_ms`` values are
     relative to — the service passes the SAME origin to its ``WaveTrace``
     recorders, so spans and TraceEvents land on one timeline and the
-    Perfetto export needs no clock reconciliation.
+    Perfetto export needs no clock reconciliation. ``origin_unix_ns`` is
+    that epoch on the profiler's host clock.
     """
 
     def __init__(self, enabled: bool = True, origin: float | None = None,
                  maxlen: int = 262_144):
         self.enabled = bool(enabled)
-        self._origin = time.perf_counter() if origin is None else origin
+        now = time.perf_counter()
+        unix_ns = time.time_ns()
+        self._origin = now if origin is None else origin
+        self.origin_unix_ns = unix_ns - round((now - self._origin) * 1e9)
         self.maxlen = int(maxlen)
         self.spans: list[Span] = []
-        self.dropped = 0
 
     def now_ms(self) -> float:
         return (time.perf_counter() - self._origin) * 1e3
 
+    @contextlib.contextmanager
+    def phase(self, name: str, rid: str = "", **attrs):
+        """Run the body as host phase ``name``: under the profiler
+        annotation ``repro.<name>`` always, and as a ``Span`` of request
+        ``rid`` when the log is enabled. A phase without a request id (the
+        scheduler's, which serve a whole pool) is annotated only."""
+        with TraceAnnotation(_ANNOTATIONS[name]):
+            if not (self.enabled and rid):
+                yield
+                return
+            t0 = self.now_ms()
+            try:
+                yield
+            finally:
+                self.add(name, rid, t0, self.now_ms() - t0, **attrs)
+
     def add(self, name: str, rid: str, t_start_ms: float, dur_ms: float, *,
             lane: int = -1, wave: int = -1, **attrs) -> None:
-        if not self.enabled:
-            return
-        if len(self.spans) >= self.maxlen:
-            self.dropped += 1
+        if not self.enabled or len(self.spans) >= self.maxlen:
             return
         self.spans.append(Span(rid=rid, name=name,
                                t_start_ms=float(t_start_ms),
@@ -114,15 +129,8 @@ class SpanLog:
 
     def clear(self) -> None:
         self.spans.clear()
-        self.dropped = 0
 
     # -- queries -----------------------------------------------------------
-
-    def by_request(self) -> dict[str, list[Span]]:
-        out: dict[str, list[Span]] = {}
-        for sp in self.spans:
-            out.setdefault(sp.rid, []).append(sp)
-        return out
 
     def roots(self) -> dict[str, Span]:
         """The ``request`` root span per rid (last one wins — there should

@@ -26,6 +26,13 @@ Free lanes between boundaries ride along with a zero round budget (the
 vmapped superstep's while-cond masks them — same mechanism
 ``enumerate_batch`` uses for finished lanes), so the dispatch cadence never
 waits for admission.
+
+Every boundary runs under a host phase of the service's ``SpanLog``
+(``recycle`` for admissions, ``retire`` for retirements, with ``seed``,
+``superstep``, ``readback``, ``drain`` and ``rebucket`` around the work
+inside), so a profiler trace names what the host did while the device
+idled. The phases serve a whole pool, so they are annotated only; each
+request's spans are added per lane below.
 """
 from __future__ import annotations
 
@@ -38,7 +45,7 @@ import jax.numpy as jnp
 from ..core import triplets as T
 from ..core.bitset_graph import BitsetGraph, n_words_for
 from ..core.engine import (STATUS_NAMES, EngineConfig, EnumerationResult,
-                           _DONE, _DRAIN, _GROW, _RUN, _SHRINK, round_path)
+                           _DONE, _DRAIN, _GROW, _RUN, _SHRINK)
 from ..core.frontier import empty_cycle_buffer, with_capacity_batched
 from ..core.plan import pad_graph
 from ..obs.spans import new_request_id
@@ -89,7 +96,7 @@ class ContinuousScheduler:
             admissions=0, retirements=0, pools=0, classes={},
             occupancy_sum=0.0, n_cycles=0, boundary_ms=0.0,
             queue_wait_ms=[], e2e_ms=[], n_dispatches=0, n_host_syncs=0,
-            fused_rounds=0, split_rounds=0)
+            n_d2h_arrays=0, fused_rounds=0, split_rounds=0)
         # registry mirrors (DESIGN.md §6.10): the legacy stats dict above
         # stays the session-local view, every count double-writes into the
         # service's shared MetricsRegistry via _bump (dict == registry is
@@ -163,10 +170,12 @@ class ContinuousScheduler:
                 if not pending:
                     break
                 now = self._sleep_until(pending[0].t_arrival)
-                self._close_pool()
-                self._open_pool(pending, now)
+                with self._spans.phase("recycle"):
+                    self._close_pool()
+                    self._open_pool(pending, now)
             else:
-                self._admit(pending, now)
+                with self._spans.phase("recycle"):
+                    self._admit(pending, now)
             if not self.pool.occupied_lanes():
                 # every admitted lane was dead on arrival (empty graphs);
                 # retire them without burning a dispatch
@@ -277,6 +286,7 @@ class ContinuousScheduler:
         tr = self._trace
         self.stats["n_dispatches"] += tr.n_dispatches
         self.stats["n_host_syncs"] += tr.n_host_syncs
+        self.stats["n_d2h_arrays"] += tr.n_d2h_arrays
         self.stats["fused_rounds"] += tr.rounds_by_path["fused"]
         self.stats["split_rounds"] += tr.rounds_by_path["split"]
         if self._observe and self._tkey is not None and self._done:
@@ -339,28 +349,34 @@ class ContinuousScheduler:
         boundary event covers the whole seed (staging included), not just
         the device time, and accumulates into ``boundary_ms_total``."""
         cfg, trace = self._cfg, self._trace
-        wall_t0 = time.perf_counter()
-        trace.tic()
-        fbat, tri_bat, ntris, ntrips = T.initial_frontier_batched(
-            gbat, delta=self._shape[2], bucket=cfg.bucket,
-            backend=cfg.backend, capacity=self._cap,
-            tri_capacity=self._tcap)
-        self._tcap = tri_bat.shape[1]
-        trace.sync()
-        wall_ms = (time.perf_counter() - wall_t0) * 1e3
-        self.stats["boundary_ms"] += wall_ms
-        self._m_boundary.inc(wall_ms)
-        trace.dispatch(
-            kind="seed", bucket=fbat.path.shape[1], cyc_cap=0, budget=0,
-            rounds=0, status="RUN", enter_count=int(ntrips.sum()),
-            exit_count=int(ntrips.sum()), t_ms=trace.toc_ms(), launches=2,
-            lanes=self.pool.slots, live_lanes=live, admitted=admitted,
-            wall_ms=wall_ms, lane_rids=tuple(r.rid for r in reqs))
-        if self._spans.enabled and reqs:
-            t_end = self._spans.now_ms()
-            for r in reqs:
-                self._spans.add("seed", r.rid, t_end - wall_ms, wall_ms)
-        tri_h = np.asarray(tri_bat) if cfg.store else None
+        with self._spans.phase("seed"):
+            wall_t0 = time.perf_counter()
+            trace.tic()
+            fbat, tri_bat, ntris, ntrips = T.initial_frontier_batched(
+                gbat, delta=self._shape[2], bucket=cfg.bucket,
+                backend=cfg.backend, capacity=self._cap,
+                tri_capacity=self._tcap, trace=trace)
+            self._tcap = tri_bat.shape[1]
+            trace.sync()
+            wall_ms = (time.perf_counter() - wall_t0) * 1e3
+            self.stats["boundary_ms"] += wall_ms
+            self._m_boundary.inc(wall_ms)
+            trace.dispatch(
+                kind="seed", bucket=fbat.path.shape[1], cyc_cap=0,
+                budget=0, rounds=0, status="RUN",
+                enter_count=int(ntrips.sum()),
+                exit_count=int(ntrips.sum()), t_ms=trace.toc_ms(),
+                launches=2, lanes=self.pool.slots, live_lanes=live,
+                admitted=admitted, wall_ms=wall_ms,
+                lane_rids=tuple(r.rid for r in reqs))
+            if self._spans.enabled and reqs:
+                t_end = self._spans.now_ms()
+                for r in reqs:
+                    self._spans.add("seed", r.rid, t_end - wall_ms, wall_ms)
+            tri_h = None
+            if cfg.store:
+                tri_h = np.asarray(tri_bat)
+                trace.d2h()
         return fbat, ntris, ntrips, tri_h
 
     def _seat(self, lane: int, req: LaneRequest, n0: int, n_tri: int,
@@ -420,9 +436,10 @@ class ContinuousScheduler:
             # running frontier so the merge (and next superstep) run at
             # the larger shape — a bucket transition, not a retrace for
             # warm shapes
-            self._fbat = with_capacity_batched(self._fbat, new_cap)
-            self._cap = new_cap
-            self._trace.transition()
+            with self._spans.phase("rebucket"):
+                self._fbat = with_capacity_batched(self._fbat, new_cap)
+                self._cap = new_cap
+                self._trace.transition()
 
         admit = np.zeros(B, bool)
         admit[lanes] = True
@@ -493,93 +510,98 @@ class ContinuousScheduler:
         self.stats["occupancy_sum"] += len(occ) / B
         self._g_live.set(len(occ))
 
-        n_pad, m_pad, d_pad = self._shape
-        plan = self.service._wave_plan(n_pad, m_pad, self._cap,
-                                       self._cyc_cap, self._nw, d_pad, cfg,
-                                       batch=B)
-        fresh = plan.n_calls == 0
-        cap_in, live_in = self._cap, int(pool.cnts[occ].sum())
-        trace.tic()
-        self._fbat, self._bufbat, r, status, th, ch, pn, pc = plan(
-            self._gbat, self._fbat, self._bufbat,
-            jnp.asarray(k_i, jnp.int32))
-        (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
-         bc_h) = jax.device_get(
-            (status, r, th, ch, pn, pc, self._fbat.count,
-             self._bufbat.count))
-        trace.sync()
-        status_h = np.asarray(status_h)
-        lane_statuses = {int(status_h[i]) for i in occ}
-        agg = next((s for s in (_DRAIN, _GROW, _SHRINK, _RUN, _DONE)
-                    if s in lane_statuses), _RUN)
-        step_ms = trace.toc_ms()
-        trace.dispatch(
-            kind="batch", bucket=cap_in, cyc_cap=self._cyc_cap,
-            budget=int(k_i.max()), rounds=int(np.asarray(r_h).max()),
-            status=STATUS_NAMES[agg], enter_count=live_in,
-            exit_count=int(sum(int(cnt_h[i]) for i in occ)),
-            cyc_fill=int(sum(int(bc_h[i]) for i in occ)),
-            t_ms=step_ms, fresh=fresh, plan_key=str(plan.key),
-            lanes=B, live_lanes=len(occ),
-            lane_rids=tuple(r.rid if r is not None else ""
-                            for r in pool.req),
-            lane_rounds=tuple(int(pool.its[i]) + int(r_h[i])
-                              for i in range(B)),
-            round_path=round_path(
-                cfg, cap=cap_in, nw=self._nw, n=n_pad,
-                n_neighbors=self._gbat.neighbors.shape[-1],
-                cyc_cap=self._cyc_cap))
-        if self._spans.enabled:
-            t_end = self._spans.now_ms()
-            for i in occ:
-                self._spans.add(
-                    "superstep", pool.req[i].rid, t_end - step_ms, step_ms,
-                    lane=i, wave=int(pool.its[i]) + int(r_h[i]),
-                    rounds=int(r_h[i]))
+        phase = self._spans.phase
+        with phase("superstep"):
+            n_pad, m_pad, d_pad = self._shape
+            plan = self.service._wave_plan(n_pad, m_pad, self._cap,
+                                           self._cyc_cap, self._nw, d_pad,
+                                           cfg, batch=B)
+            fresh = plan.n_calls == 0
+            cap_in, live_in = self._cap, int(pool.cnts[occ].sum())
+            trace.tic()
+            self._fbat, self._bufbat, r, status, th, ch, pn, pc = plan(
+                self._gbat, self._fbat, self._bufbat,
+                jnp.asarray(k_i, jnp.int32))
+        with phase("readback"):
+            fetched = (status, r, th, ch, pn, pc, self._fbat.count,
+                       self._bufbat.count)
+            (status_h, r_h, th_h, ch_h, pn_h, pc_h, cnt_h,
+             bc_h) = jax.device_get(fetched)
+            trace.sync()
+            trace.d2h(len(fetched))
+            status_h = np.asarray(status_h)
+            lane_statuses = {int(status_h[i]) for i in occ}
+            agg = next((s for s in (_DRAIN, _GROW, _SHRINK, _RUN, _DONE)
+                        if s in lane_statuses), _RUN)
+            step_ms = trace.toc_ms()
+            trace.dispatch(
+                kind="batch", bucket=cap_in, cyc_cap=self._cyc_cap,
+                budget=int(k_i.max()), rounds=int(np.asarray(r_h).max()),
+                status=STATUS_NAMES[agg], enter_count=live_in,
+                exit_count=int(sum(int(cnt_h[i]) for i in occ)),
+                cyc_fill=int(sum(int(bc_h[i]) for i in occ)),
+                t_ms=step_ms, fresh=fresh, plan_key=str(plan.key),
+                lanes=B, live_lanes=len(occ),
+                lane_rids=tuple(r.rid if r is not None else ""
+                                for r in pool.req),
+                lane_rounds=tuple(int(pool.its[i]) + int(r_h[i])
+                                  for i in range(B)),
+                round_path=plan.round_path)
+            if self._spans.enabled:
+                t_end = self._spans.now_ms()
+                for i in occ:
+                    self._spans.add(
+                        "superstep", pool.req[i].rid, t_end - step_ms,
+                        step_ms, lane=i, wave=int(pool.its[i]) + int(r_h[i]),
+                        rounds=int(r_h[i]))
 
-        for i in occ:
-            for j in range(int(r_h[i])):
-                pool.n_cycles[i] += int(ch_h[i, j])
-                pool.histories[i].append(dict(step=int(pool.its[i]) + j + 1,
-                                              T=int(th_h[i, j]),
-                                              C=pool.n_cycles[i]))
-            pool.its[i] += int(r_h[i])
-            pool.cnts[i] = int(cnt_h[i])
-        self._bc_h = np.asarray(bc_h, np.int64)
+            for i in occ:
+                for j in range(int(r_h[i])):
+                    pool.n_cycles[i] += int(ch_h[i, j])
+                    pool.histories[i].append(
+                        dict(step=int(pool.its[i]) + j + 1,
+                             T=int(th_h[i, j]), C=pool.n_cycles[i]))
+                pool.its[i] += int(r_h[i])
+                pool.cnts[i] = int(cnt_h[i])
+            self._bc_h = np.asarray(bc_h, np.int64)
 
         drains = [i for i in occ if int(status_h[i]) == _DRAIN]
         grows = [i for i in occ if int(status_h[i]) == _GROW]
         if drains:
             # drain EVERY occupied lane with pending masks in one host
             # copy (free lanes' stale rows are dropped by the reset)
-            masks_h = np.asarray(self._bufbat.masks)
-            for i in occ:
-                bc = int(bc_h[i])
-                if bc:
-                    pool.chunks[i].append(masks_h[i, :bc].copy())
-                    trace.drain()
-            trace.sync()
-            self._cyc_cap = max(
-                self._cyc_cap,
-                cfg.bucket(max(max(int(pc_h[i]) for i in drains), 1)))
-            self._bufbat = empty_cycle_buffer(self._cyc_cap, self._nw,
-                                              batch=B)
-            self._bc_h[:] = 0
+            with phase("drain"):
+                masks_h = np.asarray(self._bufbat.masks)
+                trace.d2h()
+                for i in occ:
+                    bc = int(bc_h[i])
+                    if bc:
+                        pool.chunks[i].append(masks_h[i, :bc].copy())
+                        trace.drain()
+                trace.sync()
+                self._cyc_cap = max(
+                    self._cyc_cap,
+                    cfg.bucket(max(max(int(pc_h[i]) for i in drains), 1)))
+                self._bufbat = empty_cycle_buffer(self._cyc_cap, self._nw,
+                                                  batch=B)
+                self._bc_h[:] = 0
         if grows:
             need = max(int(pn_h[i]) for i in grows)
             new_cap = cfg.bucket(cfg.bucket(max(need, 1))
                                  << max(cfg.grow_headroom, 0))
             if new_cap != self._cap:
-                self._fbat = with_capacity_batched(self._fbat, new_cap)
-                self._cap = new_cap
-                trace.transition()
+                with phase("rebucket"):
+                    self._fbat = with_capacity_batched(self._fbat, new_cap)
+                    self._cap = new_cap
+                    trace.transition()
         elif (not drains and not getattr(self, "_hold_shrink", False)
               and pool.cnts[occ].max(initial=0) > 0):
             new_cap = cfg.bucket(max(int(pool.cnts[occ].max()), 1))
             if new_cap < self._cap:
-                self._fbat = with_capacity_batched(self._fbat, new_cap)
-                self._cap = new_cap
-                trace.transition()
+                with phase("rebucket"):
+                    self._fbat = with_capacity_batched(self._fbat, new_cap)
+                    self._cap = new_cap
+                    trace.transition()
 
     # -- retirement --------------------------------------------------------
 
@@ -587,16 +609,26 @@ class ContinuousScheduler:
         """Superstep-boundary drain: flush each finished lane's pending
         CycleBuffer rows and yield its completed result. The lane is FREE
         afterwards; its stale device rows are inert (zero budget) until the
-        next admission merges over them."""
+        next admission merges over them. Results go out after the
+        ``retire`` phase closes: the caller's work between them is not the
+        scheduler's."""
+        with self._spans.phase("retire"):
+            done = self._retire()
+        yield from done
+
+    def _retire(self) -> list:
         pool, cfg = self.pool, self._cfg
         finished = pool.finished_lanes()
+        done = []
         if not finished:
-            return
+            return done
         masks_h = None
         drain_t0 = self._spans.now_ms() if self._spans.enabled else 0.0
         if cfg.store and any(self._bc_h[i] for i in finished):
-            masks_h = np.asarray(self._bufbat.masks)
-            self._trace.sync()
+            with self._spans.phase("drain"):
+                masks_h = np.asarray(self._bufbat.masks)
+                self._trace.sync()
+                self._trace.d2h()
         now = self._now()
         for i in finished:
             drained = False
@@ -630,7 +662,8 @@ class ContinuousScheduler:
                 self._spans.add("request", req.rid,
                                 self._span_ms(req.t_arrival), e2e, lane=i,
                                 idx=req.idx, cls=req.cls)
-            yield req.idx, self._render(req, state)
+            done.append((req.idx, self._render(req, state)))
+        return done
 
     def _render(self, req: LaneRequest, state: dict) -> EnumerationResult:
         masks = None

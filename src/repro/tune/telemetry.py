@@ -13,7 +13,8 @@ recordable stream:
                     RUN), pending sizes of an aborted round, cycle-buffer
                     fill, and host wall time.
 * ``WaveTrace``   — the recorder. Aggregate counters (dispatches, syncs,
-                    transitions-by-cause, drains) are ALWAYS maintained —
+                    arrays read to the host, transitions-by-cause, drains,
+                    rounds by path) are ALWAYS maintained —
                     they are a handful of int adds and back the legacy
                     ``EnumerationResult.stats`` dict — but per-dispatch
                     ``TraceEvent`` objects are retained only when the trace
@@ -161,7 +162,8 @@ class WaveTrace:
     """
 
     __slots__ = ("enabled", "events", "n_dispatches", "n_host_syncs",
-                 "n_bucket_transitions", "n_drains", "n_kernel_launches",
+                 "n_d2h_arrays", "n_bucket_transitions", "n_drains",
+                 "n_kernel_launches",
                  "by_cause", "rounds_by_path", "_t0", "_origin", "_ticked",
                  "observer")
 
@@ -177,12 +179,17 @@ class WaveTrace:
         self.events: list[TraceEvent] = []
         self.n_dispatches = 0
         self.n_host_syncs = 0
+        # arrays copied device-to-host: a device_get of a tuple reads each
+        # array on its own, so this counts reads where n_host_syncs counts
+        # the driver's waits
+        self.n_d2h_arrays = 0
         self.n_bucket_transitions = 0
         self.n_drains = 0
         self.n_kernel_launches = 0
         self.by_cause: dict[str, int] = {}
         # rounds (aborted attempts included) per round path: 'fused' — one
-        # fused pallas kernel per round or launch — or 'split'
+        # fused pallas kernel per round or launch — or 'split'; the path
+        # is the one the dispatched program took when it was traced
         self.rounds_by_path = {"fused": 0, "split": 0}
         self._t0 = 0.0
         self._origin = time.perf_counter() if origin is None else origin
@@ -204,6 +211,10 @@ class WaveTrace:
 
     def sync(self, n: int = 1) -> None:
         self.n_host_syncs += n
+
+    def d2h(self, n: int = 1) -> None:
+        """Count ``n`` arrays copied device-to-host by one read."""
+        self.n_d2h_arrays += n
 
     def launch(self, n: int = 1) -> None:
         """Count device-program launches that are part of the CURRENT
@@ -274,10 +285,6 @@ class WaveTrace:
 
     # -- summaries -------------------------------------------------------
 
-    @property
-    def rounds(self) -> int:
-        return sum(e.rounds for e in self.events)
-
     def row_work(self, n_words: int) -> int:
         return sum(e.row_work(n_words) for e in self.events)
 
@@ -288,6 +295,7 @@ class WaveTrace:
         """Legacy ``EnumerationResult.stats`` dict + transition causes."""
         out = dict(n_dispatches=self.n_dispatches,
                    n_host_syncs=self.n_host_syncs,
+                   n_d2h_arrays=self.n_d2h_arrays,
                    n_bucket_transitions=self.n_bucket_transitions,
                    n_drains=self.n_drains,
                    rounds=rounds,

@@ -17,6 +17,13 @@ Pins the subsystem's contracts:
 * the overhead contract — observability disabled retains NO TraceEvent /
   Span objects per dispatch while aggregate counters match an enabled run
   exactly;
+* host phases — ``SpanLog.phase`` annotations nest inside
+  ``repro.enumerate`` in a profiler trace and cover it, every device read
+  of the wave driver falls in ``readback`` or ``drain``, and a span agrees
+  with its annotation on the profiler's host clock;
+* device stages and counters — lowered plans carry their
+  ``repro.round.*`` scope, the round path a plan records is the VMEM
+  rule's, and ``n_d2h_arrays`` matches a hand count;
 * boundary accounting — seed/recycle events carry ``wall_ms`` and
   ``boundary_ms_total`` accumulates them;
 * FlightRecorder — bounded ring, guard-storm / warm-retrace /
@@ -28,8 +35,8 @@ import pytest
 from repro.core import CycleService, EngineConfig, build_graph
 from repro.core.graphs import grid_graph, random_gnp
 from repro.obs import (FlightRecorder, MetricsRegistry, SpanLog,
-                       collect_events, new_request_id, reset_request_ids,
-                       to_perfetto, validate_metrics, validate_perfetto)
+                       collect_events, new_request_id, to_perfetto,
+                       validate_metrics, validate_perfetto)
 from repro.sched.traffic import imbalanced_queue
 from repro.tune.telemetry import TraceEvent
 
@@ -185,7 +192,6 @@ def test_serve_wave_scheduler_mirrors_registry():
 # ---------------------------------------------------------------------------
 
 def test_request_ids_are_unique_and_monotone():
-    reset_request_ids()
     a, b = new_request_id(), new_request_id()
     assert a != b and a < b and a.startswith("r")
 
@@ -319,12 +325,21 @@ def test_disabled_path_retains_nothing_but_counts_match():
     svc_on = CycleService(cfg, trace=True)
     res_off = dict(svc_off.serve_stream(queue, slots=2))
     res_on = dict(svc_on.serve_stream(queue, slots=2))
+    # the single-graph driver opens its host phases on the same log
+    g = build_graph(*grid_graph(4, 5))
+    one_off, one_on = svc_off.enumerate(g), svc_on.enumerate(g)
 
     # nothing retained per dispatch on the disabled path
     assert list(svc_off.trace_log) == []
     assert svc_off.spans.spans == []
     assert svc_off.last_trace is None
     assert not svc_off.spans.enabled
+    assert one_off.trace is None
+    assert {sp.name for sp in svc_on.spans.spans} >= {
+        "enumerate", "seed", "superstep", "readback", "drain"}
+    for name in ("n_host_syncs", "n_d2h_arrays", "n_dispatches",
+                 "fused_rounds", "split_rounds"):
+        assert one_off.stats[name] == one_on.stats[name], name
 
     # identical results and aggregate accounting either way
     for i in res_off:
@@ -334,7 +349,8 @@ def test_disabled_path_retains_nothing_but_counts_match():
         b = np.asarray(res_on[i].cycle_masks)
         assert a.shape == b.shape and (a == b).all()
     for name in ("requests", "completed", "supersteps", "boundaries",
-                 "admissions", "retirements", "pools"):
+                 "admissions", "retirements", "pools", "n_host_syncs",
+                 "n_d2h_arrays", "split_rounds"):
         assert (svc_off.last_session.stats[name]
                 == svc_on.last_session.stats[name]), name
     for name in ("sched_requests_total", "sched_supersteps_total",
@@ -430,3 +446,232 @@ def test_flight_recorder_rides_disabled_service():
     assert fr.n_seen > 0                 # observer saw events…
     assert list(svc.trace_log) == []     # …but nothing was retained
     assert svc.spans.spans == []
+
+
+# ---------------------------------------------------------------------------
+# Host phases on the profiler's clock, device-stage scopes, d2h counts
+# ---------------------------------------------------------------------------
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler (host annotations only) and return
+    its ProfileData."""
+    import glob
+    import os
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "**",
+                                         "*.xplane.pb"), recursive=True),
+                  key=os.path.getmtime)[-1]
+    with open(path, "rb") as f:
+        return jax.profiler.ProfileData.from_serialized_xspace(f.read())
+
+
+def _repro_events(pd):
+    """(start ns, end ns, name) of every ``repro.*`` host event."""
+    return sorted((e.start_ns, e.end_ns, e.name)
+                  for p in pd.planes if p.name == "/host:CPU"
+                  for line in p.lines for e in line.events
+                  if e.name.startswith("repro."))
+
+
+def _store_service():
+    # a 16-row ring and 2-round supersteps on Grid_4x5: the run GROWs,
+    # SHRINKs and drains the ring mid-wave
+    return CycleService(EngineConfig(store=True, cycle_buffer_rows=16,
+                                     superstep_rounds=2))
+
+
+def test_phases_nest_inside_enumerate_and_cover_it(tmp_path):
+    svc = _store_service()
+    g = build_graph(*grid_graph(4, 5))
+    svc.enumerate(g)                             # compile outside the trace
+    res = {}
+    pd = _profile(tmp_path, lambda: res.update(r=svc.enumerate(g)))
+    stats = res["r"].stats
+    assert stats["exit_causes"].get("GROW") and stats["n_drains"] >= 2
+    assert stats["n_bucket_transitions"] > stats["exit_causes"]["GROW"]
+
+    evs = _repro_events(pd)
+    (root,) = [e for e in evs if e[2] == "repro.enumerate"]
+    kids = [e for e in evs if e is not root]
+    names = {e[2] for e in kids}
+    assert {"repro.seed", "repro.superstep", "repro.readback",
+            "repro.drain", "repro.rebucket"} <= names
+    # every phase nests inside the request, and phases never overlap
+    assert all(root[0] <= s and e <= root[1] for s, e, _ in kids)
+    assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
+    covered = sum(e - s for s, e, _ in kids)
+    assert covered >= 0.95 * (root[1] - root[0])
+
+
+def test_wave_driver_reads_the_device_only_in_readback_or_drain(
+        monkeypatch):
+    import sys
+    import jax
+    svc = CycleService(EngineConfig(store=True, cycle_buffer_rows=16,
+                                    superstep_rounds=2), trace=True)
+    g = build_graph(*grid_graph(4, 5))
+    svc.enumerate(g)
+    reads = []
+    real_get, real_asarray = jax.device_get, np.asarray
+
+    def note():
+        if sys._getframe(2).f_code.co_name == "_wave_events":
+            reads.append(svc.spans.now_ms())
+
+    def device_get(x):
+        note()
+        return real_get(x)
+
+    def asarray(x, *a, **k):
+        if isinstance(x, jax.Array):
+            note()
+        return real_asarray(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(np, "asarray", asarray)
+    svc.spans.clear()
+    res = svc.enumerate(g)
+    monkeypatch.undo()
+    assert len(reads) >= res.stats["n_drains"] + 2
+    phases = [sp for sp in svc.spans.spans
+              if sp.name not in ("request", "enumerate")]
+    for t in reads:
+        inner = min((sp for sp in phases
+                     if sp.t_start_ms <= t <= sp.t_end_ms),
+                    key=lambda sp: sp.dur_ms)
+        assert inner.name in ("readback", "drain"), inner
+
+
+def test_span_and_its_annotation_agree_on_the_host_clock(tmp_path):
+    import time
+    for log in (SpanLog(), SpanLog(origin=time.perf_counter() - 3.0)):
+        def run():
+            with log.phase("readback", "r1"):
+                time.sleep(0.005)
+        pd = _profile(tmp_path / str(id(log)), run)
+        (sp,) = log.spans
+        (ev,) = [e for e in _repro_events(pd) if e[2] == "repro.readback"]
+        (env,) = [p for p in pd.planes if p.name == "Task Environment"]
+        start_ns = dict(env.stats)["profile_start_time"]
+        span_ns = log.origin_unix_ns + sp.t_start_ms * 1e6
+        assert abs(start_ns + ev[0] - span_ns) < 1e6
+        assert abs((ev[1] - ev[0]) - sp.dur_ms * 1e6) < 1e6
+
+
+def test_phase_without_a_request_or_log_records_no_span():
+    on, off = SpanLog(), SpanLog(enabled=False)
+    with on.phase("drain"):
+        pass
+    with off.phase("drain", "r1"):
+        pass
+    with on.phase("drain", "r1", lane=2, bucket=64):
+        pass
+    assert off.spans == []
+    assert [(sp.name, sp.rid, sp.lane) for sp in on.spans] == [
+        ("drain", "r1", 2)]
+    assert on.spans[0].attrs == {"bucket": 64}
+    with pytest.raises(KeyError):
+        with on.phase("no_such_phase"):
+            pass
+
+
+def _abstract_plan_args(cap, nw, n, m, cyc_cap):
+    import jax
+    import jax.numpy as jnp
+    from repro.core.bitset_graph import BitsetGraph
+    from repro.core.frontier import CycleBuffer, Frontier
+    S = jax.ShapeDtypeStruct
+    i32, u32 = jnp.int32, jnp.uint32
+    g = BitsetGraph(offsets=S((n + 1,), i32), neighbors=S((2 * m,), i32),
+                    labels=S((n,), i32), adj_bits=S((n, nw), u32),
+                    labelgt_bits=S((n, nw), u32), degrees=S((n,), i32),
+                    n=n, m=m, max_degree=4)
+    f = Frontier(path=S((cap, nw), u32), blocked=S((cap, nw), u32),
+                 v1=S((cap,), i32), l2=S((cap,), i32),
+                 vlast=S((cap,), i32), count=S((), i32))
+    buf = CycleBuffer(masks=S((cyc_cap, nw), u32), count=S((), i32))
+    return g, f, buf, S((), i32)
+
+
+def _plan(cap, *, backend="pallas", rpl=1, store=False, nw=2, n=60,
+          m=110, cyc_cap=1):
+    from repro.core.plan import PlanKey, WavePlan
+    key = PlanKey(kind="wave", bucket=cap, nw=nw, cyc_rows=cyc_cap,
+                  delta=4, store=store, formulation="bitword",
+                  backend=backend, k_max=4, fused=True, rpl=rpl,
+                  extra=(n, m))
+    plan = WavePlan(key)
+    lowered = plan.lower(*_abstract_plan_args(cap, nw, n, m, cyc_cap))
+    return plan, lowered
+
+
+def _fused_limit(nw=2, n=60, m=110, cyc_cap=1, store=False):
+    """Largest power-of-two bucket whose single-round kernel fits VMEM."""
+    from repro.kernels.fused_round import fits_vmem
+    cap = 16
+    while fits_vmem(cap=2 * cap, nw=nw, n=n, n_neighbors=2 * m,
+                    cyc_cap=cyc_cap, formulation="bitword", store=store,
+                    persistent=False):
+        cap *= 2
+    return cap
+
+
+def test_lowered_plans_name_their_round_stage():
+    top = _fused_limit()
+    fused, lo_fused = _plan(top)
+    split, lo_split = _plan(2 * top)
+    text_fused = lo_fused.as_text(debug_info=True)
+    text_split = lo_split.as_text(debug_info=True)
+    assert "repro.round.fused" in text_fused
+    assert "repro.round.compact" not in text_fused
+    assert "repro.round.compact" in text_split
+    assert "repro.round.flags" in text_split
+    assert "repro.round.fused" not in text_split
+    # stage 1 is its own pair of programs
+    from repro.core import triplets as T
+    g = build_graph(*grid_graph(3, 4))
+    flags = T._flags_counts_program(g.max_degree, "pallas", False)
+    assert "repro.seed" in flags.lower(g).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("rpl", [1, 2])
+def test_plan_records_the_round_path_of_the_vmem_rule(rpl):
+    """The path a traced plan records is the one the VMEM rule gives: a
+    fused pallas round while one round's blocks fit the budget, the split
+    path past it; the jnp backend always splits."""
+    from repro.kernels.fused_round import fits_vmem
+    top = _fused_limit()
+    for cap in (top // 2, top, 2 * top, 4 * top):
+        fits = fits_vmem(cap=cap, nw=2, n=60, n_neighbors=220, cyc_cap=1,
+                         formulation="bitword", store=False,
+                         persistent=False)
+        plan, _ = _plan(cap, rpl=rpl)
+        assert plan.round_path == ("fused" if fits else "split"), cap
+        assert fits == (cap <= top)
+    plan, _ = _plan(top, backend="jnp", rpl=rpl)
+    assert plan.round_path == "split"
+
+
+def test_d2h_arrays_match_a_hand_count():
+    g = build_graph(*grid_graph(4, 5))
+    for store in (False, True):
+        svc = CycleService(EngineConfig(store=store, cycle_buffer_rows=16,
+                                        superstep_rounds=2), trace=True)
+        res = svc.enumerate(g)
+        steps = sum(e.kind == "superstep" for e in res.trace.events)
+        # stage 1: two counts, the triangle masks, the live count; each
+        # superstep reads status, rounds, two histories, two pending sizes
+        # and two counts; a stored run reads the ring count at the end and
+        # the masks of every drain
+        want = 4 + 8 * steps
+        if store:
+            want += 1 + res.stats["n_drains"]
+        assert res.stats["n_d2h_arrays"] == want, store
