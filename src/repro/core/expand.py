@@ -171,7 +171,9 @@ def compact_extensions(g: BitsetGraph, f: Frontier, cand_v: jnp.ndarray,
 # The scatter path above materializes every (path, slot) pair — cap·Δ rows of
 # nw words — before compacting them down to ≤cap survivors. The gather
 # formulation inverts the data flow: each OUTPUT slot locates its source row
-# via a prefix-sum over per-row survivor counts (O(cap), not O(cap·Δ)) and
+# by merging the sorted output slots with the sorted inclusive prefix of
+# per-row survivor counts — a histogram of the prefix over the slots and two
+# prefix scans, no binary search (O(cap + out_cap), not O(cap·Δ)) — and
 # rebuilds exactly its own row, so the round's frontier traffic drops from
 # O(cap·Δ·nw) to O(cap·nw) — the XLA realization of the two-phase-scatter
 # destination computation the fused pallas kernel performs on device.
@@ -180,20 +182,28 @@ def compact_extensions(g: BitsetGraph, f: Frontier, cand_v: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _source_rows(counts: jnp.ndarray, out_cap: int):
-    """Map output slots to source rows through an inclusive prefix sum.
+    """Map output slots to source rows by a merge of two sorted sequences.
 
     ``counts`` (cap,) survivors per row → (src, k, valid, total): for output
     slot o, ``src[o]`` is the row owning it, ``k[o]`` the rank within that
-    row, ``valid[o]`` whether o < min(total, out_cap)."""
+    row, ``valid[o]`` whether o < min(total, out_cap).
+
+    Both the slots and the inclusive prefix ``incl`` are sorted, so no search
+    is needed: one scatter-add histograms ``incl`` over the slots, and its
+    prefix sum counts the rows with ``incl[i] <= o`` — exactly
+    ``searchsorted(incl, o, "right")``. The segment of o starts at the last
+    slot ``j <= o`` that some ``incl[i]`` hits, a running max, so ``k``
+    needs no gather at ``src``."""
     cap = counts.shape[0]
     incl = jnp.cumsum(counts.astype(jnp.int32))
     total = incl[-1]
     o = jnp.arange(out_cap, dtype=jnp.int32)
-    src = jnp.searchsorted(incl, o, side="right").astype(jnp.int32)
-    src = jnp.minimum(src, cap - 1)
-    k = o - (incl[src] - counts[src])
+    hist = jnp.zeros(out_cap, jnp.int32).at[incl].add(
+        1, indices_are_sorted=True, mode="drop")
+    src = jnp.minimum(jnp.cumsum(hist), cap - 1)
+    start = jax.lax.cummax(jnp.where(hist > 0, o, 0))
     valid = o < jnp.minimum(total, out_cap)
-    return src, jnp.where(valid, k, 0), valid, total
+    return src, jnp.where(valid, o - start, 0), valid, total
 
 
 def _select_kth_bit(words: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
