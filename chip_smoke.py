@@ -17,8 +17,10 @@ Phases, in one process that shares one compile cache
   reference's.
 
 ``--chips 4`` runs only the sharded path: Grid_7x10 count-only on a
-4-device mesh, checked against Table 1's 8,136,453 with no dropped or lost
-rows and frontier rows on every device.
+4-device mesh, each device's rounds on the bitword/Pallas path at 2^23
+rows (the benchmark's ``table1_sharded4`` configuration), checked against
+Table 1's 8,136,453 with no dropped or lost rows and frontier rows on
+every device.
 
 Each phase prints one line of counters beside the device kind. Any
 mismatch or error exits non-zero before the last line, which is the JSON
@@ -170,8 +172,10 @@ def served(n_requests: int, kind: str, *, recycle: bool, seed: int = 0,
 
 def sharded(name: str, kind: str, *, n_devices: int, local_capacity: int,
             balance_block: int, expect: int | None = None) -> dict:
-    """Count-only enumeration on a 1-D mesh of ``n_devices`` devices:
-    exact count, no dropped or lost rows, frontier rows on every device."""
+    """Count-only enumeration on a 1-D mesh of ``n_devices`` devices, each
+    device's rounds on the bitword/Pallas path (the benchmark's
+    ``table1_sharded4`` configuration): exact count, no dropped or lost
+    rows, frontier rows on every device."""
     import numpy as np
     import jax
     from jax.sharding import Mesh
@@ -184,22 +188,20 @@ def sharded(name: str, kind: str, *, n_devices: int, local_capacity: int,
     devices = jax.devices()[:n_devices]
     assert len(devices) == n_devices, (len(devices), n_devices)
     mesh = Mesh(np.array(devices), ("data",))
-    cfg = EngineConfig(mesh=mesh, store=False, local_capacity=local_capacity,
+    cfg = EngineConfig(mesh=mesh, store=False, formulation="bitword",
+                       backend="pallas", local_capacity=local_capacity,
                        balance_block=balance_block)
-    svc = CycleService(cfg, trace=True)
+    svc = CycleService(cfg)
     res, t_cold = _timed(lambda: svc.enumerate(g))
     s = res.stats
-    peaks = np.zeros(n_devices, np.int64)
-    for ev in svc.last_trace.events:
-        if ev.kind == "dist":
-            peaks = np.maximum(peaks, ev.per_device)
+    peaks = np.array(s["per_device_peak_rows"])
     assert res.n_cycles == expect, (name, res.n_cycles, expect)
     assert s["dropped"] == 0 and s["lost"] == 0, (s["dropped"], s["lost"])
     assert (peaks > 0).all(), f"rows not on every device: {peaks.tolist()}"
     peak_total = max(h["T"] for h in res.history)
-    return _report(f"sharded {name} {n_devices} devices", kind,
-                   cycles=res.n_cycles, dropped=s["dropped"], lost=s["lost"],
-                   moved=s["moved"],
+    return _report(f"sharded {name} {n_devices} devices bitword pallas",
+                   kind, cycles=res.n_cycles, dropped=s["dropped"],
+                   lost=s["lost"], moved=s["moved"],
                    per_device_peak_rows=",".join(str(int(x)) for x in peaks),
                    peak_frontier_rows=peak_total,
                    local_capacity=local_capacity, cold_s=t_cold,

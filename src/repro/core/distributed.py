@@ -82,6 +82,7 @@ from . import expand as E
 from . import triplets as T
 from ..dist.collectives import ef_psum_tree, ef_quantize
 from ..dist import sharding as SH
+from ..obs.spans import SpanLog
 from ..tune.telemetry import disabled_trace
 
 # sharded supersteps exit RUN (round budget spent) or DONE (wave died);
@@ -151,20 +152,18 @@ def _psum_tiers(x, axis: str, host_axis: str | None):
     return x
 
 
-def _local_step(g: BitsetGraph, f: Frontier, delta: int, cap: int,
-                fused: bool = False):
+def _local_step(op: E.ExpandOp, g: BitsetGraph, f: Frontier, delta: int,
+                cap: int, fused: bool):
     """One expansion round on this device's rows. Returns (f', n_cyc, drop).
 
-    Programs against the same ``ExpandOp`` interface as the wave superstep
-    (DESIGN.md §6.7) — the sharded path is slot/jnp by validation. ``fused``
+    Runs the config's ``ExpandOp`` (DESIGN.md §6.7) the way the wave's
+    split path does — its flags, then its compaction at the fixed
+    ``local_capacity``, each under the scope the op opens. ``fused``
     selects the one-pass gather compaction (DESIGN.md §6.8): O(cap·nw)
     frontier traffic per round instead of the cap·Δ scatter
     materialization, bit-identical rows and drop counts."""
-    op = E.expand_op("slot", "jnp")
-    (cand, _, is_ext), n_cyc, _ = op.flags(g, f, delta)
-    compact = (E.compact_extensions_gather if fused
-               else E.compact_extensions)
-    f2, dropped = compact(g, f, cand, is_ext, cap)
+    flags, n_cyc, _ = op.flags(g, f, delta)
+    f2, dropped = op.compact(g, f, flags, delta, cap, fused)
     return f2, n_cyc, dropped
 
 
@@ -435,28 +434,32 @@ def make_dist_deal(mesh: Mesh, axis: str, g_spec, cap: int, delta: int,
     @functools.partial(shard_map, mesh=mesh, in_specs=(g_spec,),
                        out_specs=(fspec, P()), check_rep=False)
     def deal(g):
-        me = jax.lax.axis_index(axis)
-        if host_axis:
-            me = me + dev_size * jax.lax.axis_index(host_axis)
-        tri, trip = T.triplet_flags(g, delta)
-        flat_tri = tri.reshape(-1)
-        flat_trip = trip.reshape(-1)
-        n_grid = flat_trip.shape[0]
-        # deal triplet RANKS round-robin (the host deal's rows % ndev == d)
-        rank = jnp.cumsum(flat_trip.astype(jnp.int32)) - 1
-        mine = flat_trip & ((rank % ndev) == me)
-        dest, total = E.compaction_dests(mine, cap)
-        idx = jnp.zeros((cap,), jnp.int32).at[dest].set(
-            jnp.arange(n_grid, dtype=jnp.int32), mode="drop")
-        f = T.gather_triplets(g, idx, jnp.minimum(total, cap), cap)
-        overflow = _psum_tiers(jnp.maximum(total - cap, 0), axis, host_axis)
-        # triangles: count my round-robin share, psum to the global total
-        trank = jnp.cumsum(flat_tri.astype(jnp.int32)) - 1
-        my_tri = (flat_tri & ((trank % ndev) == me)).sum(dtype=jnp.int32)
-        n_tri = _psum_tiers(my_tri, axis, host_axis)
-        live = _psum_tiers(f.count, axis, host_axis)
-        f = dataclasses.replace(f, count=f.count[None])
-        return f, jnp.stack([n_tri, live, overflow])
+        with jax.named_scope("repro.seed"):
+            me = jax.lax.axis_index(axis)
+            if host_axis:
+                me = me + dev_size * jax.lax.axis_index(host_axis)
+            tri, trip = T.triplet_flags(g, delta)
+            flat_tri = tri.reshape(-1)
+            flat_trip = trip.reshape(-1)
+            n_grid = flat_trip.shape[0]
+            # deal triplet RANKS round-robin (the host deal's rows % ndev
+            # == d)
+            rank = jnp.cumsum(flat_trip.astype(jnp.int32)) - 1
+            mine = flat_trip & ((rank % ndev) == me)
+            dest, total = E.compaction_dests(mine, cap)
+            idx = jnp.zeros((cap,), jnp.int32).at[dest].set(
+                jnp.arange(n_grid, dtype=jnp.int32), mode="drop")
+            f = T.gather_triplets(g, idx, jnp.minimum(total, cap), cap)
+            overflow = _psum_tiers(jnp.maximum(total - cap, 0), axis,
+                                   host_axis)
+            # triangles: count my round-robin share, psum to the global total
+            trank = jnp.cumsum(flat_tri.astype(jnp.int32)) - 1
+            my_tri = (flat_tri & ((trank % ndev) == me)).sum(
+                dtype=jnp.int32)
+            n_tri = _psum_tiers(my_tri, axis, host_axis)
+            live = _psum_tiers(f.count, axis, host_axis)
+            f = dataclasses.replace(f, count=f.count[None])
+            return f, jnp.stack([n_tri, live, overflow])
 
     return deal
 
@@ -470,8 +473,9 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     """Build the UNJITTED sharded wave superstep.
 
     One ``shard_map(lax.while_loop)`` program runs up to
-    min(k_max, rounds_limit) fused rounds: local slot expansion + in-bucket
-    compaction at the fixed ``local_capacity``, a diffusion-balance step
+    min(k_max, rounds_limit) rounds: the config's ``ExpandOp`` (its flags,
+    then its compaction at the fixed ``local_capacity``, as the wave's
+    split path runs them), a diffusion-balance step
     every ``balance_every`` rounds on the device ring (``lax.cond``-gated
     so the collectives only run on balance rounds), a cross-host donation
     every ``balance_every × cross_balance_every`` rounds on the host ring
@@ -503,9 +507,30 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     cross_period = every * max(int(cfg.cross_balance_every), 1)
     compress = bool(cfg.compress_cross_host)
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
+    op = E.expand_op(cfg.formulation, cfg.backend)
+    fused = bool(cfg.fused_round)
     row_axes = (host_axis, axis) if host_axis else (axis,)
     fspec = _fspec(mesh, row_axes)
     rspec = fspec.count  # P over the row tiers (per-device outputs)
+
+    def balance(g, f2, gidx, active, ef):
+        """Both diffusion tiers of one round (``repro.round.balance``):
+        the device ring on the ``balance_every`` cadence, the host ring on
+        the cross-host cadence, both over the global round index."""
+        moved_i = moved_x = lost = jnp.int32(0)
+        with jax.named_scope("repro.round.balance"):
+            if dev_size > 1:
+                do_bal = active & ((gidx % every) == (every - 1))
+                f2, moved_i, lost_i = _balance(f2, block, axis, dev_size,
+                                               cap, do_bal)
+                lost = lost + lost_i
+            if host_size > 1:
+                do_x = active & ((gidx % cross_period) == (cross_period - 1))
+                f2, moved_x, lost_x, ef = _cross_balance(
+                    g, f2, block, host_axis, host_size, cap, do_x,
+                    compress, ef)
+                lost = lost + lost_x
+        return f2, moved_i, moved_x, lost, ef
 
     @functools.partial(
         shard_map, mesh=mesh,
@@ -522,24 +547,12 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
 
         def body(c):
             f, cnts, r, total, th, ch, lh, ef = c
-            f2, n_cyc, drop = _local_step(g, f, delta, cap,
-                                          fused=bool(cfg.fused_round))
-            moved_i = moved_x = lost = jnp.int32(0)
-            if dev_size > 1:
-                # cadence over the GLOBAL round index (round_base carries
-                # the rounds done by earlier supersteps) — the knob means
-                # "every N rounds of the run", not of this dispatch
-                do_bal = ((round_base + r) % every) == (every - 1)
-                f2, moved_i, lost_i = _balance(f2, block, axis, dev_size,
-                                               cap, do_bal)
-                lost = lost + lost_i
-            if host_size > 1:
-                do_x = ((round_base + r) % cross_period) == (cross_period
-                                                             - 1)
-                f2, moved_x, lost_x, ef = _cross_balance(
-                    g, f2, block, host_axis, host_size, cap, do_x,
-                    compress, ef)
-                lost = lost + lost_x
+            f2, n_cyc, drop = _local_step(op, g, f, delta, cap, fused)
+            # cadence over the GLOBAL round index (round_base carries the
+            # rounds done by earlier supersteps) — the knob means "every N
+            # rounds of the run", not of this dispatch
+            f2, moved_i, moved_x, lost, ef = balance(
+                g, f2, round_base + r, jnp.bool_(True), ef)
             total = _psum_tiers(f2.count, axis, host_axis)
             th = th.at[r].set(total)
             ch = ch.at[r].set(n_cyc)
@@ -560,23 +573,9 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
             def inner(i, ic):
                 f, cnts, total, th, ch, lh, ef, applied = ic
                 active = (i < rem) & (total > 0)
-                f2, n_cyc, drop = _local_step(g, f, delta, cap,
-                                              fused=bool(cfg.fused_round))
-                moved_i = moved_x = lost = jnp.int32(0)
-                gidx = round_base + r + i
-                ef2 = ef
-                if dev_size > 1:
-                    do_bal = active & ((gidx % every) == (every - 1))
-                    f2, moved_i, lost_i = _balance(f2, block, axis,
-                                                   dev_size, cap, do_bal)
-                    lost = lost + lost_i
-                if host_size > 1:
-                    do_x = active & ((gidx % cross_period)
-                                     == (cross_period - 1))
-                    f2, moved_x, lost_x, ef2 = _cross_balance(
-                        g, f2, block, host_axis, host_size, cap, do_x,
-                        compress, ef)
-                    lost = lost + lost_x
+                f2, n_cyc, drop = _local_step(op, g, f, delta, cap, fused)
+                f2, moved_i, moved_x, lost, ef2 = balance(
+                    g, f2, round_base + r + i, active, ef)
                 tot2 = _psum_tiers(f2.count, axis, host_axis)
                 idx = jnp.minimum(r + i, jnp.int32(k_max - 1))
                 sel = lambda a, b: jax.tree_util.tree_map(
@@ -614,13 +613,15 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
 # ---------------------------------------------------------------------------
 
 def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
-                      trace=None, progress=None,
-                      metrics=None) -> EnumerationResult:
+                      trace=None, progress=None, metrics=None,
+                      spans: SpanLog | None = None,
+                      rid: str = "") -> EnumerationResult:
     """Count all chordless cycles using every device of ``cfg.mesh`` (the
-    CycleService sharded path; cfg validated eagerly to slot/jnp/count-only
-    at construction). With ``cfg.host_axis`` the mesh is 2-level and the
-    superstep runs tiered (hierarchical psums, intra/cross balancing,
-    optionally EF-compressed cross-host donation).
+    CycleService sharded path; cfg validated eagerly to count-only at
+    construction). Each device's rounds run the config's ``ExpandOp``.
+    With ``cfg.host_axis`` the mesh is 2-level and the superstep runs
+    tiered (hierarchical psums, intra/cross balancing, optionally
+    EF-compressed cross-host donation).
 
     The host loop relaunches the sharded superstep until the wave dies or
     the |V|−3 budget runs out — one batched readback per superstep, so host
@@ -630,7 +631,9 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
     .WaveTrace``) records per-dispatch events incl. per-device wave peaks
     and per-tier balance traffic; ``metrics`` (a ``obs.MetricsRegistry``)
     accumulates the ``dist_comm_bytes`` / ``dist_balance_moved`` per-tier
-    counters.
+    counters. Every host statement between two programs runs inside one
+    host phase of ``spans`` (``seed``, ``superstep``, ``readback``), as in
+    the wave driver; ``rid`` names the request the phases record spans for.
     """
     mesh, axis, host_axis = cfg.mesh, cfg.axis, cfg.host_axis
     dev_size = int(mesh.shape[axis])
@@ -644,6 +647,7 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
     delta = max(g.max_degree, 1)
     nw = g.adj_bits.shape[1]
     trace = trace if trace is not None else disabled_trace()
+    phase = (spans if spans is not None else SpanLog(enabled=False)).phase
 
     if cfg.compress_cross_host and host_size > 1 and g.n > 127:
         raise ValueError(
@@ -659,150 +663,171 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                 iterations=0, dropped=0, moved=0, lost=0, n_devices=ndev,
                 moved_intra=0, moved_cross=0, n_hosts=host_size,
                 comm_bytes_intra=0, comm_bytes_cross=0,
-                per_device_live=[0] * ndev, superstep_rounds=k_max),
+                per_device_live=[0] * ndev, superstep_rounds=k_max,
+                local_capacity=cap, per_device_peak_rows=[0] * ndev,
+                live_rows_sum=0),
             trace=trace if trace.enabled else None)
-
-    rep = jax.sharding.NamedSharding(mesh, P())
-    g = jax.tree_util.tree_map(lambda x: jax.device_put(x, rep), g)
-    g_spec = jax.tree_util.tree_map(lambda _: P(), g)
 
     from .plan import DistPlan, PlanKey
     from ..tune.cost_model import dist_wire_bytes
 
-    def _plan(tag, builder, donate=()):
-        key = PlanKey(kind="dist", bucket=cap, nw=nw, cyc_rows=0,
-                      delta=delta, store=False, formulation=cfg.formulation,
-                      backend=cfg.backend, k_max=k_max, batch=ndev,
-                      donate=bool(donate), fused=bool(cfg.fused_round),
-                      extra=(tag, mesh, axis, host_axis, cfg.balance_block,
-                             cfg.balance_every, cfg.cross_balance_every,
-                             bool(cfg.compress_cross_host), g.n, g.m))
-        if cache is None:
-            return DistPlan(key, builder(), donate_argnums=donate)
-        return cache.get_or_build(
-            key, lambda: DistPlan(key, builder(), donate_argnums=donate))
+    with phase("seed", rid):
+        rep = jax.sharding.NamedSharding(mesh, P())
+        g = jax.tree_util.tree_map(lambda x: jax.device_put(x, rep), g)
+        g_spec = jax.tree_util.tree_map(lambda _: P(), g)
 
-    deal = _plan("deal",
-                 lambda: make_dist_deal(mesh, axis, g_spec, cap, delta,
-                                        host_axis=host_axis))
-    step = _plan("step",
-                 lambda: make_dist_superstep(mesh, axis, g_spec, cfg, delta,
-                                             k_max),
-                 donate=(1, 2))
+        def _plan(tag, builder, donate=()):
+            key = PlanKey(kind="dist", bucket=cap, nw=nw, cyc_rows=0,
+                          delta=delta, store=False,
+                          formulation=cfg.formulation, backend=cfg.backend,
+                          k_max=k_max, batch=ndev, donate=bool(donate),
+                          fused=bool(cfg.fused_round),
+                          extra=(tag, mesh, axis, host_axis,
+                                 cfg.balance_block, cfg.balance_every,
+                                 cfg.cross_balance_every,
+                                 bool(cfg.compress_cross_host), g.n, g.m))
+            if cache is None:
+                return DistPlan(key, builder(), donate_argnums=donate)
+            return cache.get_or_build(
+                key, lambda: DistPlan(key, builder(), donate_argnums=donate))
 
-    fresh = deal.n_calls == 0
-    trace.tic()
-    fshard, meta = deal(g)
-    n_tri, live, overflow = (int(x) for x in jax.device_get(meta))
-    trace.sync()
-    trace.dispatch(kind="deal", bucket=cap, cyc_cap=0, budget=0, rounds=0,
-                   status="RUN", enter_count=live, exit_count=live,
-                   t_ms=trace.toc_ms(), fresh=fresh,
-                   plan_key=str(deal.key), ndev=ndev)
-    if overflow:
-        raise ValueError(
-            f"initial triplets overflow local_capacity={cap} by {overflow} "
-            f"rows across {ndev} devices; raise cfg.local_capacity")
+        deal = _plan("deal",
+                     lambda: make_dist_deal(mesh, axis, g_spec, cap, delta,
+                                            host_axis=host_axis))
+        step = _plan("step",
+                     lambda: make_dist_superstep(mesh, axis, g_spec, cfg,
+                                                 delta, k_max),
+                     donate=(1, 2))
 
-    # modeled per-hop wire bytes (the same formula replay_dist charges)
-    row_b, stat_b = dist_wire_bytes(g.n, nw, False)
-    xrow_b, xstat_b = dist_wire_bytes(g.n, nw, bool(cfg.compress_cross_host))
+        fresh = deal.n_calls == 0
+        trace.tic()
+        fshard, meta = deal(g)
+    with phase("readback", rid):
+        n_tri, live, overflow = (int(x) for x in jax.device_get(meta))
+        trace.sync()
+        trace.d2h()
+        trace.dispatch(kind="deal", bucket=cap, cyc_cap=0, budget=0,
+                       rounds=0, status="RUN", enter_count=live,
+                       exit_count=live, t_ms=trace.toc_ms(), fresh=fresh,
+                       plan_key=str(deal.key), ndev=ndev)
+        if overflow:
+            raise ValueError(
+                f"initial triplets overflow local_capacity={cap} by "
+                f"{overflow} rows across {ndev} devices; raise "
+                "cfg.local_capacity")
 
-    history = [dict(step=0, T=live, C=n_tri)]
-    n_cycles = n_tri
-    row_axes = (host_axis, axis) if host_axis else (axis,)
-    counters = jax.device_put(
-        np.zeros((ndev, _N_COUNTERS), np.int32),
-        jax.sharding.NamedSharding(mesh, _fspec(mesh, row_axes).count))
+        # modeled per-hop wire bytes (the same formula replay_dist charges)
+        row_b, stat_b = dist_wire_bytes(g.n, nw, False)
+        xrow_b, xstat_b = dist_wire_bytes(g.n, nw,
+                                          bool(cfg.compress_cross_host))
+
+        history = [dict(step=0, T=live, C=n_tri)]
+        n_cycles = n_tri
+        row_axes = (host_axis, axis) if host_axis else (axis,)
+        counters = jax.device_put(
+            np.zeros((ndev, _N_COUNTERS), np.int32),
+            jax.sharding.NamedSharding(mesh, _fspec(mesh, row_axes).count))
     limit = cfg.max_iters if cfg.max_iters is not None else max(g.n - 3, 0)
     it = 0
     next_ckpt = cfg.checkpoint_every or 0
     prev_moved_i = prev_moved_x = prev_lost = 0
     bytes_intra = bytes_cross = 0
+    peak_rows = np.zeros(ndev, np.int64)   # per device, over all rounds
+    live_rows_sum = 0                      # Σ over rounds and devices
     while it < limit and live > 0:
-        k = min(k_max, limit - it)
-        fresh = step.n_calls == 0
-        trace.tic()
-        fshard, counters, r, status, th, ch, lh = step(
-            g, fshard, counters, jnp.int32(k), jnp.int32(it))
-        r_h, status_h, th_h, ch_h, lh_h, c_h = jax.device_get(
-            (r, status, th, ch, lh, counters))
-        trace.sync()
-        r_h = int(r_h)
-        if r_h == 0:    # defensive: cond refused on entry (live went stale)
-            break
-        ch_round = np.asarray(ch_h)[:, :r_h].sum(axis=0)
-        peak_dev = np.asarray(lh_h)[:, :r_h].max(axis=1)
-        c_now = np.asarray(c_h)
-        dropped_now = int(c_now[:, 1].sum())
-        if dropped_now:
-            # a dropped row means every later count is silently wrong —
-            # fail loudly (the deal-overflow ValueError's stage-2 twin)
-            raise RuntimeError(
-                f"sharded frontier overflow: {dropped_now} live rows "
-                f"dropped by compaction at local_capacity={cap} "
-                f"(per-device peaks {[int(x) for x in peak_dev]}); raise "
-                "cfg.local_capacity — a count computed past a drop would "
-                "be silently wrong")
-        moved_i_d = int(c_now[:, 2].sum()) - prev_moved_i
-        moved_x_d = int(c_now[:, 3].sum()) - prev_moved_x
-        lost_d = int(c_now[:, 4].sum()) - prev_lost
-        prev_moved_i += moved_i_d
-        prev_moved_x += moved_x_d
-        prev_lost += lost_d
-        # per-tier balance wire traffic of this dispatch: every device
-        # sends one block-sized hop on each balance round of its tier
-        # (sends are unconditional — static shapes — so cadence, not
-        # ``give``, sets the traffic)
-        n_bal = sum(1 for i in range(it, it + r_h)
-                    if dev_size > 1 and i % every == every - 1)
-        n_crs = sum(1 for i in range(it, it + r_h)
-                    if host_size > 1 and i % cross_period
-                    == cross_period - 1)
-        b_intra = n_bal * ndev * (block * row_b + stat_b)
-        b_cross = n_crs * ndev * (block * xrow_b + xstat_b)
-        bytes_intra += b_intra
-        bytes_cross += b_cross
-        if metrics is not None:
-            if b_intra:
-                metrics.counter("dist_comm_bytes").inc(b_intra,
-                                                       tier="intra")
-            if b_cross:
-                metrics.counter("dist_comm_bytes").inc(b_cross,
-                                                       tier="cross")
-            if moved_i_d:
-                metrics.counter("dist_balance_moved").inc(moved_i_d,
-                                                          tier="intra")
-            if moved_x_d:
-                metrics.counter("dist_balance_moved").inc(moved_x_d,
-                                                          tier="cross")
-        trace.dispatch(
-            kind="dist", bucket=cap, cyc_cap=0, budget=k, rounds=r_h,
-            status=STATUS_NAMES[int(status_h)],
-            t_sizes=np.asarray(th_h)[:r_h], c_counts=ch_round,
-            enter_count=live, exit_count=int(th_h[r_h - 1]),
-            t_ms=trace.toc_ms(), fresh=fresh, plan_key=str(step.key),
-            ndev=ndev, rounds_per_launch=max(int(cfg.rounds_per_launch), 1),
-            per_device=tuple(int(x) for x in peak_dev),
-            moved=moved_i_d + moved_x_d, lost=lost_d,
-            moved_cross=moved_x_d,
-            comm_bytes_intra=b_intra, comm_bytes_cross=b_cross)
-        for i in range(r_h):
-            n_cycles += int(ch_round[i])
-            rec = dict(step=it + i + 1, T=int(th_h[i]), C=n_cycles)
-            history.append(rec)
-            if progress:
-                progress(rec)
-        it += r_h
-        live = int(th_h[r_h - 1])
+        with phase("superstep", rid):
+            k = min(k_max, limit - it)
+            fresh = step.n_calls == 0
+            trace.tic()
+            fshard, counters, r, status, th, ch, lh = step(
+                g, fshard, counters, jnp.int32(k), jnp.int32(it))
+        with phase("readback", rid):
+            fetched = (r, status, th, ch, lh, counters)
+            r_h, status_h, th_h, ch_h, lh_h, c_h = jax.device_get(fetched)
+            trace.sync()
+            trace.d2h(len(fetched))
+            r_h = int(r_h)
+            if r_h == 0:  # defensive: cond refused on entry (live stale)
+                break
+            ch_round = np.asarray(ch_h)[:, :r_h].sum(axis=0)
+            lh_now = np.asarray(lh_h)[:, :r_h]
+            peak_dev = lh_now.max(axis=1)
+            peak_rows = np.maximum(peak_rows, peak_dev)
+            live_rows_sum += int(lh_now.sum())
+            c_now = np.asarray(c_h)
+            dropped_now = int(c_now[:, 1].sum())
+            if dropped_now:
+                # a dropped row means every later count is silently wrong —
+                # fail loudly (the deal-overflow ValueError's stage-2 twin)
+                raise RuntimeError(
+                    f"sharded frontier overflow: {dropped_now} live rows "
+                    f"dropped by compaction at local_capacity={cap} "
+                    f"(per-device peaks {[int(x) for x in peak_dev]}); "
+                    "raise cfg.local_capacity — a count computed past a "
+                    "drop would be silently wrong")
+            moved_i_d = int(c_now[:, 2].sum()) - prev_moved_i
+            moved_x_d = int(c_now[:, 3].sum()) - prev_moved_x
+            lost_d = int(c_now[:, 4].sum()) - prev_lost
+            prev_moved_i += moved_i_d
+            prev_moved_x += moved_x_d
+            prev_lost += lost_d
+            # per-tier balance wire traffic of this dispatch: every device
+            # sends one block-sized hop on each balance round of its tier
+            # (sends are unconditional — static shapes — so cadence, not
+            # ``give``, sets the traffic)
+            n_bal = sum(1 for i in range(it, it + r_h)
+                        if dev_size > 1 and i % every == every - 1)
+            n_crs = sum(1 for i in range(it, it + r_h)
+                        if host_size > 1 and i % cross_period
+                        == cross_period - 1)
+            b_intra = n_bal * ndev * (block * row_b + stat_b)
+            b_cross = n_crs * ndev * (block * xrow_b + xstat_b)
+            bytes_intra += b_intra
+            bytes_cross += b_cross
+            if metrics is not None:
+                if b_intra:
+                    metrics.counter("dist_comm_bytes").inc(b_intra,
+                                                           tier="intra")
+                if b_cross:
+                    metrics.counter("dist_comm_bytes").inc(b_cross,
+                                                           tier="cross")
+                if moved_i_d:
+                    metrics.counter("dist_balance_moved").inc(
+                        moved_i_d, tier="intra")
+                if moved_x_d:
+                    metrics.counter("dist_balance_moved").inc(
+                        moved_x_d, tier="cross")
+            trace.dispatch(
+                kind="dist", bucket=cap, cyc_cap=0, budget=k, rounds=r_h,
+                status=STATUS_NAMES[int(status_h)],
+                t_sizes=np.asarray(th_h)[:r_h], c_counts=ch_round,
+                enter_count=live, exit_count=int(th_h[r_h - 1]),
+                t_ms=trace.toc_ms(), fresh=fresh, plan_key=str(step.key),
+                ndev=ndev,
+                rounds_per_launch=max(int(cfg.rounds_per_launch), 1),
+                per_device=tuple(int(x) for x in peak_dev),
+                moved=moved_i_d + moved_x_d, lost=lost_d,
+                moved_cross=moved_x_d,
+                comm_bytes_intra=b_intra, comm_bytes_cross=b_cross)
+            for i in range(r_h):
+                n_cycles += int(ch_round[i])
+                rec = dict(step=it + i + 1, T=int(th_h[i]), C=n_cycles)
+                history.append(rec)
+                if progress:
+                    progress(rec)
+            it += r_h
+            live = int(th_h[r_h - 1])
         if cfg.checkpoint_every and it >= next_ckpt:
             from .. import checkpoint as ckpt
             ckpt.save_pytree(cfg.checkpoint_dir, it,
                              dict(frontier=fshard, counters=counters))
             next_ckpt = it + cfg.checkpoint_every
 
-    c_h, live_h = jax.device_get((counters, fshard.count))
-    trace.sync()
+    with phase("readback", rid):
+        fetched = (counters, fshard.count)
+        c_h, live_h = jax.device_get(fetched)
+        trace.sync()
+        trace.d2h(len(fetched))
     c = np.asarray(c_h)
     assert int(c[:, 0].sum()) == n_cycles - n_tri, \
         "device cycle counter disagrees with history accumulation"
@@ -815,7 +840,9 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
         lost=int(c[:, 4].sum()), n_devices=ndev, n_hosts=host_size,
         comm_bytes_intra=bytes_intra, comm_bytes_cross=bytes_cross,
         per_device_live=[int(x) for x in np.asarray(live_h)],
-        superstep_rounds=k_max)
+        superstep_rounds=k_max, local_capacity=cap,
+        per_device_peak_rows=[int(x) for x in peak_rows],
+        live_rows_sum=live_rows_sum)
     return EnumerationResult(
         n_cycles=n_cycles, n_triangles=n_tri, cycle_masks=None,
         iterations=it, history=history, stats=stats,

@@ -159,22 +159,12 @@ class EngineConfig:
                 f"local_capacity={self.local_capacity}: a donation block "
                 "must fit inside one device's frontier shard")
         if self.mesh is not None:
-            # the shard_map path is slot/jnp/count-only (DESIGN.md §5);
-            # anything else would fail deep inside shard_map tracing.
-            bad = []
-            if self.formulation != "slot":
-                bad.append(f"formulation={self.formulation!r} (allowed: "
-                           f"'slot')")
-            if self.backend != "jnp":
-                bad.append(f"backend={self.backend!r} (allowed: 'jnp')")
+            # the shard_map path runs any ExpandOp but counts only
+            # (DESIGN.md §5); a store request would fail deep inside tracing
             if self.store:
-                bad.append("store=True (allowed: False — counting is the "
-                           "scalable output)")
-            if bad:
                 raise ValueError(
-                    "mesh-sharded enumeration only supports the "
-                    "slot/jnp/count-only combination; got "
-                    + "; ".join(bad))
+                    "mesh-sharded enumeration is count-only; got store=True "
+                    "(allowed: False — counting is the scalable output)")
             if self.host_axis is not None:
                 if self.host_axis == self.axis:
                     raise ValueError(

@@ -357,34 +357,57 @@ class ExpandOp:
     dispatch per round.
 
     * ``flags(g, f, delta)`` → ``(flags, n_cyc, n_new)``: the round's flag
-      computation plus its cycle/extension counts, no host syncs;
-      ``flags`` is formulation-specific (slot: ``(cand_v, is_cyc,
-      is_ext)`` per (path, slot); bitword: ``(close_words, ext_words)``).
-    * ``apply(g, f, buf, flags, delta, store)`` → ``(f', buf')``: gather
-      this round's cycles + compact extensions at fixed capacity — the
-      T → T' update.
-    * ``apply_fused(...)`` (same signature as ``apply``): the one-pass
-      gather compaction variant (DESIGN.md §6.8) — O(cap·nw) frontier
-      traffic per round instead of O(cap·Δ·nw). Bit-identical output.
+      computation plus its cycle/extension counts, no host syncs, under
+      the ``repro.round.flags`` scope; ``flags`` is formulation-specific
+      (slot: ``(cand_v, is_cyc, is_ext)`` per (path, slot); bitword:
+      ``(close_words, ext_words)``).
+    * ``compact(g, f, flags, delta, cap, fused)`` → ``(f', n_dropped)``:
+      the round's extensions compacted into a fresh frontier of ``cap``
+      rows, under the ``repro.round.compact`` scope. ``fused`` selects the
+      one-pass gather compaction (DESIGN.md §6.8): O(cap·nw) frontier
+      traffic per round instead of the cap·Δ scatter. Bit-identical rows
+      either way; rows past ``cap`` are dropped and counted. The sharded
+      step calls it at its fixed per-device capacity.
+    * ``apply(g, f, buf, flags, delta, store, fused)`` → ``(f', buf')``:
+      gather this round's cycles into the ring (with ``store``) and
+      ``compact`` at the frontier's own capacity — the T → T' update.
     * ``fused_kernel`` (pallas ops only): the whole guarded round — flags,
       counts, cycle append, compaction — collapses into ONE pallas
       dispatch (``expand_count_compact`` routes there under ``fused``).
     """
     formulation: str
     backend: str
-    supports_fused: bool = False   # has apply_fused (gather compaction)
     fused_kernel: bool = False     # whole round is one pallas dispatch
 
+    # the stage scopes open here, so every caller's trace names them alike;
+    # subclasses implement ``_flags`` and ``_compact``
     def flags(self, g: BitsetGraph, f: Frontier, delta: int):
+        with jax.named_scope("repro.round.flags"):
+            return self._flags(g, f, delta)
+
+    def compact(self, g: BitsetGraph, f: Frontier, flags, delta: int,
+                cap: int, fused: bool):
+        with jax.named_scope("repro.round.compact"):
+            return self._compact(g, f, flags, delta, cap, fused)
+
+    def _flags(self, g: BitsetGraph, f: Frontier, delta: int):
+        raise NotImplementedError
+
+    def _compact(self, g: BitsetGraph, f: Frontier, flags, delta: int,
+                 cap: int, fused: bool):
+        raise NotImplementedError
+
+    def append_cycles(self, f: Frontier, flags, delta: int,
+                      buf: CycleBuffer) -> CycleBuffer:
         raise NotImplementedError
 
     def apply(self, g: BitsetGraph, f: Frontier, buf: CycleBuffer, flags,
-              delta: int, store: bool):
-        raise NotImplementedError
-
-    def apply_fused(self, g: BitsetGraph, f: Frontier, buf: CycleBuffer,
-                    flags, delta: int, store: bool):
-        raise NotImplementedError
+              delta: int, store: bool, fused: bool = False):
+        if store:
+            with jax.named_scope("repro.round.cycles"):
+                buf = self.append_cycles(f, flags, delta, buf)
+        f2, _ = self.compact(g, f, flags, delta, f.capacity, fused)
+        return f2, buf
 
     def fused_round(self, g: BitsetGraph, f: Frontier, buf: CycleBuffer,
                     delta: int, store: bool):
@@ -404,64 +427,40 @@ class ExpandOp:
 
 
 class _SlotApply:
-    """Shared slot-formulation T → T' update."""
-    supports_fused = True
+    """Shared slot-formulation compaction and cycle append."""
 
-    def apply(self, g, f, buf, flags, delta, store):
-        cand_v, is_cyc, is_ext = flags
-        if store:
-            with jax.named_scope("repro.round.cycles"):
-                buf = gather_cycles_into(f, cand_v, is_cyc, buf)
-        with jax.named_scope("repro.round.compact"):
-            f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
-        return f2, buf
+    def _compact(self, g, f, flags, delta, cap, fused):
+        cand_v, _, is_ext = flags
+        if fused:
+            return compact_extensions_gather(g, f, cand_v, is_ext, cap)
+        return compact_extensions(g, f, cand_v, is_ext, cap)
 
-    def apply_fused(self, g, f, buf, flags, delta, store):
-        cand_v, is_cyc, is_ext = flags
-        if store:
-            with jax.named_scope("repro.round.cycles"):
-                buf = gather_cycles_into(f, cand_v, is_cyc, buf)
-        with jax.named_scope("repro.round.compact"):
-            f2, _ = compact_extensions_gather(g, f, cand_v, is_ext,
-                                              f.capacity)
-        return f2, buf
+    def append_cycles(self, f, flags, delta, buf):
+        cand_v, is_cyc, _ = flags
+        return gather_cycles_into(f, cand_v, is_cyc, buf)
 
 
 class _BitwordApply:
-    """Shared bitword-formulation T → T' update (slot extraction from the
-    candidate words, then the same prefix-sum compaction)."""
-    supports_fused = True
+    """Shared bitword-formulation compaction and cycle append."""
 
-    def apply(self, g, f, buf, flags, delta, store):
-        close_w, ext_w = flags
-        with jax.named_scope("repro.round.compact"):
-            cand_v = bitword_to_slots(ext_w, delta)
-            is_ext = cand_v >= 0
-        if store:
-            with jax.named_scope("repro.round.cycles"):
-                ccand = bitword_to_slots(close_w, delta)
-                buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
-        with jax.named_scope("repro.round.compact"):
-            f2, _ = compact_extensions(g, f, cand_v, is_ext, f.capacity)
-        return f2, buf
+    def _compact(self, g, f, flags, delta, cap, fused):
+        ext_w = flags[1]
+        if fused:
+            # straight from the candidate words — no Δ-round slot
+            # extraction, no cap·Δ row materialization (DESIGN.md §6.8)
+            return bitword_compact_gather(g, f, ext_w, cap)
+        cand_v = bitword_to_slots(ext_w, delta)
+        return compact_extensions(g, f, cand_v, cand_v >= 0, cap)
 
-    def apply_fused(self, g, f, buf, flags, delta, store):
-        # frontier: straight from the candidate words — no Δ-round slot
-        # extraction, no cap·Δ row materialization (DESIGN.md §6.8)
-        close_w, ext_w = flags
-        if store:
-            with jax.named_scope("repro.round.cycles"):
-                ccand = bitword_to_slots(close_w, delta)
-                buf = gather_cycles_into(f, ccand, ccand >= 0, buf)
-        with jax.named_scope("repro.round.compact"):
-            f2, _ = bitword_compact_gather(g, f, ext_w, f.capacity)
-        return f2, buf
+    def append_cycles(self, f, flags, delta, buf):
+        ccand = bitword_to_slots(flags[0], delta)
+        return gather_cycles_into(f, ccand, ccand >= 0, buf)
 
 
 class SlotXlaExpand(_SlotApply, ExpandOp):
     formulation, backend = "slot", "jnp"
 
-    def flags(self, g, f, delta):
+    def _flags(self, g, f, delta):
         cand_v, is_cyc, is_ext = expand_flags_slot(g, f, delta)
         n_new, n_cyc = count_ext_and_cycles(is_cyc, is_ext)
         return (cand_v, is_cyc, is_ext), n_cyc, n_new
@@ -471,7 +470,7 @@ class SlotPallasExpand(_SlotApply, ExpandOp):
     formulation, backend = "slot", "pallas"
     fused_kernel = True
 
-    def flags(self, g, f, delta):
+    def _flags(self, g, f, delta):
         from ..kernels import ops as kops
         cand_v, is_cyc, is_ext = kops.expand_flags_slot(g, f, delta)
         n_new, n_cyc = count_ext_and_cycles(is_cyc, is_ext)
@@ -492,7 +491,7 @@ class SlotPallasExpand(_SlotApply, ExpandOp):
 class BitwordXlaExpand(_BitwordApply, ExpandOp):
     formulation, backend = "bitword", "jnp"
 
-    def flags(self, g, f, delta):
+    def _flags(self, g, f, delta):
         close_w, ext_w = expand_words_bitword(g, f)
         return ((close_w, ext_w), popcount(close_w).sum(),
                 popcount(ext_w).sum())
@@ -502,7 +501,7 @@ class BitwordPallasExpand(_BitwordApply, ExpandOp):
     formulation, backend = "bitword", "pallas"
     fused_kernel = True
 
-    def flags(self, g, f, delta):
+    def _flags(self, g, f, delta):
         from ..kernels import ops as kops
         close_w, ext_w, n_cyc, n_new = kops.bitword_fused_counts(g, f)
         return (close_w, ext_w), n_cyc, n_new
@@ -592,14 +591,13 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
     and escalates to the host (bucket transition).  ``op`` defaults to the
     registered ``expand_op(formulation, backend)``.
 
-    ``fused`` selects the one-pass round (DESIGN.md §6.8) when the op
-    supports it: pallas ops with a fused kernel collapse the whole guarded
-    round into ONE device dispatch (two-phase scatter, guard evaluated in
-    kernel) while the bucket fits the kernel's VMEM budget
-    (``fused_kernel_fits``), and take the split path past it; jnp ops swap
-    the scatter compaction for the gather formulation (one frontier pass
-    instead of two). Output is bit-identical either way; ops without fused
-    support fall back to the split path silently.
+    ``fused`` selects the one-pass round (DESIGN.md §6.8): pallas ops with
+    a fused kernel collapse the whole guarded round into ONE device
+    dispatch (two-phase scatter, guard evaluated in kernel) while the
+    bucket fits the kernel's VMEM budget (``fused_kernel_fits``), and take
+    the split path past it; the split path swaps the scatter compaction
+    for the gather formulation (one frontier pass instead of two). Output
+    is bit-identical either way.
 
     Returns (f2, buf2, n_cyc, n_new, ok_frontier, ok_cycles).
     """
@@ -611,8 +609,7 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
         with jax.named_scope("repro.round.fused"):
             return op.fused_round(g, f, buf, delta, store)
     _took("split")
-    with jax.named_scope("repro.round.flags"):
-        flags, n_cyc, n_new = op.flags(g, f, delta)
+    flags, n_cyc, n_new = op.flags(g, f, delta)
     ok_frontier = n_new <= f.capacity
     if store:
         ok_cycles = (buf.count + n_cyc) <= buf.capacity
@@ -620,10 +617,9 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
         ok_cycles = jnp.bool_(True)
     ok = ok_frontier & ok_cycles
 
-    apply = op.apply_fused if (fused and op.supports_fused) else op.apply
     f2, buf2 = jax.lax.cond(
         ok,
-        lambda _: apply(g, f, buf, flags, delta, store),
+        lambda _: op.apply(g, f, buf, flags, delta, store, fused),
         lambda _: (f, buf),
         None)
     return f2, buf2, n_cyc, n_new, ok_frontier, ok_cycles

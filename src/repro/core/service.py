@@ -315,7 +315,8 @@ class CycleService:
                 from .distributed import enumerate_sharded
                 res = enumerate_sharded(g, cfg, cache=self._cache,
                                         trace=trace, progress=progress,
-                                        metrics=self.metrics)
+                                        metrics=self.metrics,
+                                        spans=self.spans, rid=rid)
             elif not wave:
                 res = _enumerate_host(g, cfg, progress, trace=trace)
             else:
@@ -334,8 +335,10 @@ class CycleService:
                             np.concatenate(chunks, axis=0) if chunks
                             else np.zeros((0, nw), np.uint32))
             self._after_run(g, cfg, tkey, observe, trace, res)
-        # the wave driver's host phases already recorded its dispatches
-        self._request_spans(rid, t_req, trace, dispatches=not wave)
+        # the wave and sharded drivers' host phases already recorded their
+        # dispatches
+        self._request_spans(rid, t_req, trace,
+                            dispatches=not wave and cfg.mesh is None)
         return res
 
     def stream(self, g: BitsetGraph, *,

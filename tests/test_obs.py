@@ -675,3 +675,45 @@ def test_d2h_arrays_match_a_hand_count():
         if store:
             want += 1 + res.stats["n_drains"]
         assert res.stats["n_d2h_arrays"] == want, store
+
+
+def test_sharded_driver_records_its_phases_and_reads(tmp_path):
+    """The sharded driver opens the wave driver's host phases: a span each
+    for the deal (``seed``), every superstep and every readback, nested in
+    the request, and its sync and read counters match the readbacks."""
+    import jax
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    svc = CycleService(EngineConfig(store=False, mesh=mesh,
+                                    formulation="bitword", backend="pallas",
+                                    local_capacity=1 << 10, balance_block=64,
+                                    superstep_rounds=3), trace=True)
+    g = build_graph(*grid_graph(4, 5))
+    svc.enumerate(g)                             # compile outside the trace
+    svc.spans.clear()
+    res = {}
+    pd = _profile(tmp_path, lambda: res.update(r=svc.enumerate(g)))
+    res = res["r"]
+    (rid,) = svc.spans.roots()
+    names = [sp.name for sp in svc.spans.spans if sp.rid == rid]
+    steps = sum(e.kind == "dist" for e in res.trace.events)
+    assert steps > 1
+    assert names.count("seed") == 1 and names.count("superstep") == steps
+    # one readback after the deal, one per superstep, one at the end, and
+    # no dispatch slices added a second time by the request's decomposition
+    assert names.count("readback") == steps + 2
+    assert not {"deal", "dist"} & set(names)
+    assert res.stats["n_host_syncs"] == steps + 2
+    # the deal's meta; per superstep the rounds, status, three histories
+    # and the counters; at the end the counters and the live counts
+    assert res.stats["n_d2h_arrays"] == 1 + 6 * steps + 2
+
+    evs = _repro_events(pd)
+    (root,) = [e for e in evs if e[2] == "repro.enumerate"]
+    kids = [e for e in evs if e is not root]
+    assert {e[2] for e in kids} == {"repro.seed", "repro.superstep",
+                                    "repro.readback"}
+    assert all(root[0] <= s and e <= root[1] for s, e, _ in kids)
+    assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
+    covered = sum(e - s for s, e, _ in kids)
+    assert covered >= 0.9 * (root[1] - root[0])

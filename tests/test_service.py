@@ -40,15 +40,17 @@ def test_config_unknown_values_raise_eagerly():
 def test_config_mesh_mismatches_raise_eagerly():
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    # the sharded path is slot/jnp/count-only; all three mismatches listed
-    with pytest.raises(ValueError, match="formulation='bitword'"):
-        EngineConfig(store=False, formulation="bitword", mesh=mesh)
-    with pytest.raises(ValueError, match="backend='pallas'"):
-        EngineConfig(store=False, backend="pallas", mesh=mesh)
+    # the sharded path runs any ExpandOp but counts only
     with pytest.raises(ValueError, match="store=True"):
         EngineConfig(store=True, mesh=mesh)
-    # and the valid combination constructs fine
-    EngineConfig(store=False, mesh=mesh)
+    with pytest.raises(ValueError, match="store=True"):
+        EngineConfig(store=True, formulation="bitword", backend="pallas",
+                     mesh=mesh)
+    # and every count-only combination constructs fine, the fast path too
+    for formulation in ("slot", "bitword"):
+        for backend in ("jnp", "pallas"):
+            EngineConfig(store=False, formulation=formulation,
+                         backend=backend, mesh=mesh)
 
 
 def test_compat_wrapper_validates_before_tracing():
