@@ -21,6 +21,15 @@ every device (replicated graph), each device takes the triplets whose RANK
 perform — and cumsum-scatters them straight into its local shard of the
 frontier. No host-side nonzero, no H2D copy of every initial row.
 
+Per-device bucketing: every superstep runs at one per-device bucket (a
+power of two between two balance blocks and ``local_capacity``), the
+sharded twin of the wave engine's GROW/SHRINK re-bucketing — a round the
+busiest device's bucket cannot hold is aborted on every device and the
+superstep exits GROW; a wave that shrank well below the bucket exits
+SHRINK; the host picks the next bucket with
+``tune.cost_model.dist_next_bucket`` and resizes every shard. A round's
+compaction costs per bucket row, so this work follows the live rows.
+
 Load balance: DFS trees are lopsided, so on balance rounds each device
 donates a fixed-size block of tail rows to its ring neighbor iff its live
 count exceeds the neighbor's by more than the block size (diffusion load
@@ -76,18 +85,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
 from .bitset_graph import BitsetGraph
-from .engine import STATUS_NAMES, EngineConfig, EnumerationResult
-from .frontier import Frontier
+from .engine import (_DONE, _GROW, _RUN, _SHRINK, STATUS_NAMES,
+                     EngineConfig, EnumerationResult)
+from .frontier import Frontier, with_capacity
 from . import expand as E
 from . import triplets as T
 from ..dist.collectives import ef_psum_tree, ef_quantize
 from ..dist import sharding as SH
 from ..obs.spans import SpanLog
 from ..tune.telemetry import disabled_trace
-
-# sharded supersteps exit RUN (round budget spent) or DONE (wave died);
-# codes index telemetry.STATUSES like the single-device engine's.
-_RUN, _DONE = 0, 1
 
 # counter columns of the sharded superstep's per-device accumulator
 # (``counters`` below): cycles found, rows dropped (compaction overflow +
@@ -142,29 +148,14 @@ def _fspec(mesh: Mesh, row_axes: tuple[str, ...]) -> Frontier:
                     vlast=rows, count=rows)
 
 
-def _psum_tiers(x, axis: str, host_axis: str | None):
+def _reduce_tiers(x, axis: str, host_axis: str | None, reduce=jax.lax.psum):
     """Hierarchical reduction: the device tier first, then the host tier
-    (one nested psum per mesh level; collapses to a plain psum on flat
-    meshes)."""
-    x = jax.lax.psum(x, axis)
+    (one nested ``reduce`` per mesh level; collapses to a plain one on
+    flat meshes)."""
+    x = reduce(x, axis)
     if host_axis:
-        x = jax.lax.psum(x, host_axis)
+        x = reduce(x, host_axis)
     return x
-
-
-def _local_step(op: E.ExpandOp, g: BitsetGraph, f: Frontier, delta: int,
-                cap: int, fused: bool):
-    """One expansion round on this device's rows. Returns (f', n_cyc, drop).
-
-    Runs the config's ``ExpandOp`` (DESIGN.md §6.7) the way the wave's
-    split path does — its flags, then its compaction at the fixed
-    ``local_capacity``, each under the scope the op opens. ``fused``
-    selects the one-pass gather compaction (DESIGN.md §6.8): O(cap·nw)
-    frontier traffic per round instead of the cap·Δ scatter
-    materialization, bit-identical rows and drop counts."""
-    flags, n_cyc, _ = op.flags(g, f, delta)
-    f2, dropped = op.compact(g, f, flags, delta, cap, fused)
-    return f2, n_cyc, dropped
 
 
 def _donate(f: Frontier, give: jnp.ndarray, block: int, axis: str,
@@ -450,14 +441,14 @@ def make_dist_deal(mesh: Mesh, axis: str, g_spec, cap: int, delta: int,
             idx = jnp.zeros((cap,), jnp.int32).at[dest].set(
                 jnp.arange(n_grid, dtype=jnp.int32), mode="drop")
             f = T.gather_triplets(g, idx, jnp.minimum(total, cap), cap)
-            overflow = _psum_tiers(jnp.maximum(total - cap, 0), axis,
+            overflow = _reduce_tiers(jnp.maximum(total - cap, 0), axis,
                                    host_axis)
             # triangles: count my round-robin share, psum to the global total
             trank = jnp.cumsum(flat_tri.astype(jnp.int32)) - 1
             my_tri = (flat_tri & ((trank % ndev) == me)).sum(
                 dtype=jnp.int32)
-            n_tri = _psum_tiers(my_tri, axis, host_axis)
-            live = _psum_tiers(f.count, axis, host_axis)
+            n_tri = _reduce_tiers(my_tri, axis, host_axis)
+            live = _reduce_tiers(f.count, axis, host_axis)
             f = dataclasses.replace(f, count=f.count[None])
             return f, jnp.stack([n_tri, live, overflow])
 
@@ -469,13 +460,14 @@ def make_dist_deal(mesh: Mesh, axis: str, g_spec, cap: int, delta: int,
 # ---------------------------------------------------------------------------
 
 def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
-                        delta: int, k_max: int):
-    """Build the UNJITTED sharded wave superstep.
+                        delta: int, k_max: int, bucket: int):
+    """Build the UNJITTED sharded wave superstep at the per-device
+    ``bucket`` (at most the ceiling ``local_capacity``).
 
     One ``shard_map(lax.while_loop)`` program runs up to
     min(k_max, rounds_limit) rounds: the config's ``ExpandOp`` (its flags,
-    then its compaction at the fixed ``local_capacity``, as the wave's
-    split path runs them), a diffusion-balance step
+    then its compaction at ``bucket`` rows, as the wave's split path runs
+    them), a diffusion-balance step
     every ``balance_every`` rounds on the device ring (``lax.cond``-gated
     so the collectives only run on balance rounds), a cross-host donation
     every ``balance_every × cross_balance_every`` rounds on the host ring
@@ -485,20 +477,37 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     that is carried into the loop condition — the wave terminates ON DEVICE
     the round the global frontier empties, with no host involvement.
 
+    Per-device bucketing (DESIGN.md §5), the sharded twin of the wave
+    engine's GROW/SHRINK exits: after a round's flags the busiest device's
+    extension count (``pmax`` over the row tiers) is the rows its
+    compaction must hold. Below the ceiling a round that would not fit is
+    aborted on every device — no compaction, no balance, no history entry
+    — and the superstep exits GROW with that count in the history slot of
+    the aborted round, for the host to re-bucket. At the ceiling the round
+    runs and drops rows, which the host refuses. Above the floor
+    (``tune.cost_model.dist_bucket_range``) the superstep exits SHRINK
+    once no device holds more than a quarter of the bucket (checked where
+    a launch of ``rounds_per_launch`` rounds ends).
+
     Compilation (jit + frontier/counter donation + the cross-request
     program cache) is ``core.plan.DistPlan``'s job; the host driver loop is
     ``enumerate_sharded``.
 
     Returns ``superstep(g, f, counters, rounds_limit, round_base) ->
-    (f', counters', rounds_done, status, total_hist, cyc_hist, live_hist)``
+    (f', counters', rounds_done, status, need_hist, cyc_hist, live_hist)``
     (``round_base`` = rounds completed by earlier supersteps, so both
     balance cadences run over the global round index)
-    where ``total_hist`` (k_max,) is the replicated per-round global live
-    count, and ``cyc_hist`` / ``live_hist`` (ndev, k_max) are the
-    per-device per-round cycle counts and live counts (the per-device wave
-    profiles the tuner's sharded replay twin consumes).
+    where ``need_hist`` (k_max,) is the replicated per-round busiest-device
+    extension count (applied rounds, then a GROW abort's), and
+    ``cyc_hist`` / ``live_hist`` (ndev, k_max) are the per-device per-round
+    cycle counts and live counts (the per-device wave profiles the tuner's
+    sharded replay twin consumes; their column sums are the global ones).
     """
-    cap = int(cfg.local_capacity)
+    from ..tune.cost_model import dist_bucket_range
+    floor, ceiling = dist_bucket_range(cfg)
+    cap = int(bucket)
+    can_grow = cap < ceiling
+    shrink_below = cap // 4 if cap > floor else 0
     block = int(cfg.balance_block)
     every = max(int(cfg.balance_every), 1)
     host_axis = cfg.host_axis
@@ -512,6 +521,8 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     row_axes = (host_axis, axis) if host_axis else (axis,)
     fspec = _fspec(mesh, row_axes)
     rspec = fspec.count  # P over the row tiers (per-device outputs)
+    pmax = functools.partial(_reduce_tiers, axis=axis, host_axis=host_axis,
+                             reduce=jax.lax.pmax)
 
     def balance(g, f2, gidx, active, ef):
         """Both diffusion tiers of one round (``repro.round.balance``):
@@ -532,6 +543,43 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
                 lost = lost + lost_x
         return f2, moved_i, moved_x, lost, ef
 
+    def round_(g, f, ef, gidx, active):
+        """One round on this device's rows: the op's flags, then — when
+        the busiest device's extensions fit the bucket — its compaction
+        and the balance step. ``fused`` selects the one-pass gather
+        compaction (DESIGN.md §6.8). Returns (f', ef', counter increments,
+        need, fits)."""
+        flags, n_cyc, n_new = op.flags(g, f, delta)
+        need = pmax(n_new.astype(jnp.int32))
+
+        def run(args):
+            f, ef = args
+            f2, drop = op.compact(g, f, flags, delta, cap, fused)
+            f2, moved_i, moved_x, lost, ef = balance(g, f2, gidx, active,
+                                                     ef)
+            return (f2, ef), jnp.stack([n_cyc, drop + lost, moved_i,
+                                        moved_x, lost]).astype(jnp.int32)
+
+        def abort(args):
+            return args, jnp.zeros((_N_COUNTERS,), jnp.int32)
+
+        if not can_grow:
+            (f2, ef), inc = run((f, ef))
+            return f2, ef, inc, need, jnp.bool_(True)
+        fits = need <= cap
+        (f2, ef), inc = jax.lax.cond(fits, run, abort, (f, ef))
+        return f2, ef, inc, need, fits
+
+    def exit_status(grown, f, total):
+        """RUN, GROW, or SHRINK once no device holds more than a quarter
+        of the bucket (above the floor)."""
+        status = jnp.where(grown, jnp.int32(_GROW), jnp.int32(_RUN))
+        if shrink_below:
+            low = pmax(f.count) <= shrink_below
+            status = jnp.where(~grown & (total > 0) & low,
+                               jnp.int32(_SHRINK), status)
+        return status
+
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(g_spec, fspec, rspec, P(), P()),
@@ -542,70 +590,88 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
         cnts = counters[0]  # (_N_COUNTERS,) cumulative — see _N_COUNTERS
 
         def cond(c):
-            f, cnts, r, total, th, ch, lh, ef = c
-            return (r < rounds_limit) & (total > 0)
+            f, cnts, r, total, status, nh, ch, lh, ef = c
+            return (status == _RUN) & (r < rounds_limit) & (total > 0)
 
         def body(c):
-            f, cnts, r, total, th, ch, lh, ef = c
-            f2, n_cyc, drop = _local_step(op, g, f, delta, cap, fused)
+            f, cnts, r, total, status, nh, ch, lh, ef = c
             # cadence over the GLOBAL round index (round_base carries the
             # rounds done by earlier supersteps) — the knob means "every N
             # rounds of the run", not of this dispatch
-            f2, moved_i, moved_x, lost, ef = balance(
-                g, f2, round_base + r, jnp.bool_(True), ef)
-            total = _psum_tiers(f2.count, axis, host_axis)
-            th = th.at[r].set(total)
-            ch = ch.at[r].set(n_cyc)
+            f2, ef, inc, need, fits = round_(g, f, ef, round_base + r,
+                                             jnp.bool_(True))
+            total = _reduce_tiers(f2.count, axis, host_axis)
+            nh = nh.at[r].set(need)
+            ch = ch.at[r].set(inc[0])
             lh = lh.at[r].set(f2.count)
-            cnts = cnts + jnp.stack([n_cyc, drop + lost, moved_i, moved_x,
-                                     lost])
-            return f2, cnts, r + 1, total, th, ch, lh, ef
+            return (f2, cnts + inc, r + fits.astype(jnp.int32), total,
+                    exit_status(~fits, f2, total), nh, ch, lh, ef)
 
         def body_multi(c):
             # persistent multi-round twin (DESIGN.md §6.11): one while-loop
-            # iteration advances up to ``rpl`` masked rounds — past-budget
-            # or dead inner rounds select the old state, so the applied
-            # rounds are bit-identical to the R=1 body (balance cadence
-            # still keyed to the GLOBAL round index round_base + r + i).
-            f, cnts, r, total, th, ch, lh, ef = c
+            # iteration advances up to ``rpl`` masked rounds — past-budget,
+            # dead or post-abort inner rounds select the old state, so the
+            # applied rounds are bit-identical to the R=1 body (balance
+            # cadence still keyed to the GLOBAL round index
+            # round_base + r + i).
+            f, cnts, r, total, status, nh, ch, lh, ef = c
             rem = rounds_limit - r
 
             def inner(i, ic):
-                f, cnts, total, th, ch, lh, ef, applied = ic
-                active = (i < rem) & (total > 0)
-                f2, n_cyc, drop = _local_step(op, g, f, delta, cap, fused)
-                f2, moved_i, moved_x, lost, ef2 = balance(
-                    g, f2, round_base + r + i, active, ef)
-                tot2 = _psum_tiers(f2.count, axis, host_axis)
+                f, cnts, total, nh, ch, lh, ef, applied, grown = ic
+                active = (i < rem) & (total > 0) & ~grown
+                f2, ef2, inc, need, fits = round_(g, f, ef,
+                                                  round_base + r + i, active)
+                ok = active & fits
+                tot2 = _reduce_tiers(f2.count, axis, host_axis)
                 idx = jnp.minimum(r + i, jnp.int32(k_max - 1))
                 sel = lambda a, b: jax.tree_util.tree_map(
-                    lambda x, y: jnp.where(active, x, y), a, b)
-                th = th.at[idx].set(jnp.where(active, tot2, th[idx]))
-                ch = ch.at[idx].set(jnp.where(active, n_cyc, ch[idx]))
-                lh = lh.at[idx].set(jnp.where(active, f2.count, lh[idx]))
-                cnts2 = cnts + jnp.stack([n_cyc, drop + lost, moved_i,
-                                          moved_x, lost])
-                return (sel(f2, f), jnp.where(active, cnts2, cnts),
-                        jnp.where(active, tot2, total), th, ch, lh,
-                        sel(ef2, ef), applied + active.astype(jnp.int32))
+                    lambda x, y: jnp.where(ok, x, y), a, b)
+                nh = nh.at[idx].set(jnp.where(active, need, nh[idx]))
+                ch = ch.at[idx].set(jnp.where(ok, inc[0], ch[idx]))
+                lh = lh.at[idx].set(jnp.where(ok, f2.count, lh[idx]))
+                return (sel(f2, f), jnp.where(ok, cnts + inc, cnts),
+                        jnp.where(ok, tot2, total), nh, ch, lh,
+                        sel(ef2, ef), applied + ok.astype(jnp.int32),
+                        grown | (active & ~fits))
 
-            f, cnts, total, th, ch, lh, ef, applied = jax.lax.fori_loop(
-                0, rpl, inner,
-                (f, cnts, total, th, ch, lh, ef, jnp.int32(0)))
-            return f, cnts, r + applied, total, th, ch, lh, ef
+            f, cnts, total, nh, ch, lh, ef, applied, grown = (
+                jax.lax.fori_loop(0, rpl, inner,
+                                  (f, cnts, total, nh, ch, lh, ef,
+                                   jnp.int32(0), jnp.bool_(False))))
+            return (f, cnts, r + applied, total,
+                    exit_status(grown, f, total), nh, ch, lh, ef)
 
         zeros = jnp.zeros((k_max,), jnp.int32)
-        total0 = _psum_tiers(f.count, axis, host_axis)
+        total0 = _reduce_tiers(f.count, axis, host_axis)
         ef0 = dict(psum_err=jnp.float32(0.0),
                    id_err=jnp.zeros((2, block), jnp.float32))
-        f, cnts, r, total, th, ch, lh, ef = jax.lax.while_loop(
+        f, cnts, r, total, status, nh, ch, lh, ef = jax.lax.while_loop(
             cond, body if rpl <= 1 else body_multi,
-            (f, cnts, jnp.int32(0), total0, zeros, zeros, zeros, ef0))
-        status = jnp.where(total == 0, jnp.int32(_DONE), jnp.int32(_RUN))
+            (f, cnts, jnp.int32(0), total0, jnp.int32(_RUN), zeros, zeros,
+             zeros, ef0))
+        status = jnp.where((status != _GROW) & (total == 0),
+                           jnp.int32(_DONE), status)
         f = dataclasses.replace(f, count=f.count[None])
-        return f, cnts[None], r, status, th, ch[None], lh[None]
+        return f, cnts[None], r, status, nh, ch[None], lh[None]
 
     return superstep
+
+
+def make_dist_rebucket(mesh: Mesh, row_axes: tuple[str, ...], bucket: int):
+    """The UNJITTED per-shard resize to ``bucket`` rows per device: each
+    device keeps its live rows (``frontier.with_capacity`` on its shard),
+    under the device scope ``repro.rebucket``. The host calls it between
+    supersteps, only to a bucket that holds every device's live rows."""
+    fspec = _fspec(mesh, row_axes)
+
+    @functools.partial(shard_map, mesh=mesh, in_specs=(fspec,),
+                       out_specs=fspec, check_rep=False)
+    def rebucket(f):
+        with jax.named_scope("repro.rebucket"):
+            return with_capacity(f, bucket)
+
+    return rebucket
 
 
 # ---------------------------------------------------------------------------
@@ -625,15 +691,22 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
 
     The host loop relaunches the sharded superstep until the wave dies or
     the |V|−3 budget runs out — one batched readback per superstep, so host
-    syncs are O(iterations / superstep_rounds) + O(1). ``cache`` (a
+    syncs are O(iterations / superstep_rounds + bucket transitions) + O(1).
+    Every superstep runs at one per-device bucket: the deal at the bucket
+    of n·C(Δ, 2) wedges per device (every triplet is a wedge), then, after each superstep, the bucket
+    ``tune.cost_model.dist_next_bucket`` picks from its exit (GROW: the
+    aborted round's busiest-device need; RUN/SHRINK: the busiest device's
+    live rows), between the floor and the ceiling ``local_capacity``; a
+    change resizes every shard in the ``rebucket`` phase. ``cache`` (a
     ``core.plan.ProgramCache``) memoizes the jitted deal + superstep across
     requests on the same mesh/shape; ``trace`` (a ``tune.telemetry
     .WaveTrace``) records per-dispatch events incl. per-device wave peaks
     and per-tier balance traffic; ``metrics`` (a ``obs.MetricsRegistry``)
     accumulates the ``dist_comm_bytes`` / ``dist_balance_moved`` per-tier
     counters. Every host statement between two programs runs inside one
-    host phase of ``spans`` (``seed``, ``superstep``, ``readback``), as in
-    the wave driver; ``rid`` names the request the phases record spans for.
+    host phase of ``spans`` (``seed``, ``superstep``, ``readback``,
+    ``rebucket``), as in the wave driver; ``rid`` names the request the
+    phases record spans for.
     """
     mesh, axis, host_axis = cfg.mesh, cfg.axis, cfg.host_axis
     dev_size = int(mesh.shape[axis])
@@ -665,19 +738,28 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                 comm_bytes_intra=0, comm_bytes_cross=0,
                 per_device_live=[0] * ndev, superstep_rounds=k_max,
                 local_capacity=cap, per_device_peak_rows=[0] * ndev,
-                live_rows_sum=0),
+                live_rows_sum=0, slot_rows_sum=0, bucket_transitions=0,
+                grow_aborts=0),
             trace=trace if trace.enabled else None)
 
-    from .plan import DistPlan, PlanKey
-    from ..tune.cost_model import dist_wire_bytes
+    from .plan import DistPlan, PlanKey, ProgramCache
+    from ..tune.cost_model import (dist_first_bucket, dist_next_bucket,
+                                   dist_wire_bytes)
 
+    row_axes = (host_axis, axis) if host_axis else (axis,)
+    # one plan per bucket, kept for the run when no cache is shared
+    cache = cache if cache is not None else ProgramCache()
     with phase("seed", rid):
+        # the deal's bucket: every triplet is a wedge and a graph has at
+        # most n·C(Δ, 2) of them, so a device's share bounds its rows
+        wedges = g.n * (delta * (delta - 1) // 2)
+        bucket = dist_first_bucket(cfg, -(-wedges // ndev))
         rep = jax.sharding.NamedSharding(mesh, P())
         g = jax.tree_util.tree_map(lambda x: jax.device_put(x, rep), g)
         g_spec = jax.tree_util.tree_map(lambda _: P(), g)
 
-        def _plan(tag, builder, donate=()):
-            key = PlanKey(kind="dist", bucket=cap, nw=nw, cyc_rows=0,
+        def _plan(tag, rows, builder, donate=()):
+            key = PlanKey(kind="dist", bucket=rows, nw=nw, cyc_rows=0,
                           delta=delta, store=False,
                           formulation=cfg.formulation, backend=cfg.backend,
                           k_max=k_max, batch=ndev, donate=bool(donate),
@@ -685,20 +767,24 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                           extra=(tag, mesh, axis, host_axis,
                                  cfg.balance_block, cfg.balance_every,
                                  cfg.cross_balance_every,
-                                 bool(cfg.compress_cross_host), g.n, g.m))
-            if cache is None:
-                return DistPlan(key, builder(), donate_argnums=donate)
+                                 bool(cfg.compress_cross_host),
+                                 int(cfg.local_capacity), g.n, g.m))
             return cache.get_or_build(
                 key, lambda: DistPlan(key, builder(), donate_argnums=donate))
 
-        deal = _plan("deal",
-                     lambda: make_dist_deal(mesh, axis, g_spec, cap, delta,
-                                            host_axis=host_axis))
-        step = _plan("step",
-                     lambda: make_dist_superstep(mesh, axis, g_spec, cfg,
-                                                 delta, k_max),
-                     donate=(1, 2))
+        def step_plan(rows):
+            return _plan("step", rows,
+                         lambda: make_dist_superstep(mesh, axis, g_spec, cfg,
+                                                     delta, k_max, rows),
+                         donate=(1, 2))
 
+        def rebucket_plan(frm, to):
+            return _plan(("rebucket", frm), to,
+                         lambda: make_dist_rebucket(mesh, row_axes, to))
+
+        deal = _plan("deal", bucket,
+                     lambda: make_dist_deal(mesh, axis, g_spec, bucket,
+                                            delta, host_axis=host_axis))
         fresh = deal.n_calls == 0
         trace.tic()
         fshard, meta = deal(g)
@@ -706,13 +792,13 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
         n_tri, live, overflow = (int(x) for x in jax.device_get(meta))
         trace.sync()
         trace.d2h()
-        trace.dispatch(kind="deal", bucket=cap, cyc_cap=0, budget=0,
+        trace.dispatch(kind="deal", bucket=bucket, cyc_cap=0, budget=0,
                        rounds=0, status="RUN", enter_count=live,
                        exit_count=live, t_ms=trace.toc_ms(), fresh=fresh,
                        plan_key=str(deal.key), ndev=ndev)
         if overflow:
             raise ValueError(
-                f"initial triplets overflow local_capacity={cap} by "
+                f"initial triplets overflow the deal's bucket {bucket} by "
                 f"{overflow} rows across {ndev} devices; raise "
                 "cfg.local_capacity")
 
@@ -723,7 +809,6 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
 
         history = [dict(step=0, T=live, C=n_tri)]
         n_cycles = n_tri
-        row_axes = (host_axis, axis) if host_axis else (axis,)
         counters = jax.device_put(
             np.zeros((ndev, _N_COUNTERS), np.int32),
             jax.sharding.NamedSharding(mesh, _fspec(mesh, row_axes).count))
@@ -734,26 +819,35 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
     bytes_intra = bytes_cross = 0
     peak_rows = np.zeros(ndev, np.int64)   # per device, over all rounds
     live_rows_sum = 0                      # Σ over rounds and devices
+    slot_rows_sum = 0                      # Σ of the buckets they ran at
+    grow_aborts = 0
     while it < limit and live > 0:
         with phase("superstep", rid):
             k = min(k_max, limit - it)
+            step = step_plan(bucket)
             fresh = step.n_calls == 0
             trace.tic()
-            fshard, counters, r, status, th, ch, lh = step(
+            fshard, counters, r, status, nh, ch, lh = step(
                 g, fshard, counters, jnp.int32(k), jnp.int32(it))
         with phase("readback", rid):
-            fetched = (r, status, th, ch, lh, counters)
-            r_h, status_h, th_h, ch_h, lh_h, c_h = jax.device_get(fetched)
+            fetched = (r, status, nh, ch, lh, counters)
+            r_h, status_h, nh_h, ch_h, lh_h, c_h = jax.device_get(fetched)
             trace.sync()
             trace.d2h(len(fetched))
             r_h = int(r_h)
-            if r_h == 0:  # defensive: cond refused on entry (live stale)
-                break
+            status_h = STATUS_NAMES[int(status_h)]
+            if r_h == 0 and status_h != "GROW":
+                break  # defensive: cond refused on entry (live stale)
+            need = int(nh_h[r_h]) if status_h == "GROW" else 0
+            grow_aborts += status_h == "GROW"
             ch_round = np.asarray(ch_h)[:, :r_h].sum(axis=0)
             lh_now = np.asarray(lh_h)[:, :r_h]
-            peak_dev = lh_now.max(axis=1)
+            th_h = lh_now.sum(axis=0)              # global live per round
+            peak_dev = (lh_now.max(axis=1) if r_h
+                        else np.zeros(ndev, np.int64))
             peak_rows = np.maximum(peak_rows, peak_dev)
             live_rows_sum += int(lh_now.sum())
+            slot_rows_sum += r_h * ndev * bucket
             c_now = np.asarray(c_h)
             dropped_now = int(c_now[:, 1].sum())
             if dropped_now:
@@ -797,15 +891,16 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                 if moved_x_d:
                     metrics.counter("dist_balance_moved").inc(
                         moved_x_d, tier="cross")
+            exit_live = int(th_h[-1]) if r_h else live
             trace.dispatch(
-                kind="dist", bucket=cap, cyc_cap=0, budget=k, rounds=r_h,
-                status=STATUS_NAMES[int(status_h)],
-                t_sizes=np.asarray(th_h)[:r_h], c_counts=ch_round,
-                enter_count=live, exit_count=int(th_h[r_h - 1]),
-                t_ms=trace.toc_ms(), fresh=fresh, plan_key=str(step.key),
-                ndev=ndev,
+                kind="dist", bucket=bucket, cyc_cap=0, budget=k,
+                rounds=r_h, status=status_h, t_sizes=th_h,
+                c_counts=ch_round, enter_count=live, exit_count=exit_live,
+                pending_new=need, t_ms=trace.toc_ms(), fresh=fresh,
+                plan_key=str(step.key), ndev=ndev,
                 rounds_per_launch=max(int(cfg.rounds_per_launch), 1),
                 per_device=tuple(int(x) for x in peak_dev),
+                dev_new=np.asarray(nh_h)[:r_h], dev_live=lh_now.max(axis=0),
                 moved=moved_i_d + moved_x_d, lost=lost_d,
                 moved_cross=moved_x_d,
                 comm_bytes_intra=b_intra, comm_bytes_cross=b_cross)
@@ -816,7 +911,21 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                 if progress:
                     progress(rec)
             it += r_h
-            live = int(th_h[r_h - 1])
+            live = exit_live
+        if it < limit and live > 0:
+            new_bucket = dist_next_bucket(
+                cfg, status_h, bucket, need=need,
+                peak=int(lh_now[:, -1].max()) if r_h else 0)
+            if status_h == "GROW" and new_bucket <= bucket:
+                raise RuntimeError(
+                    f"sharded superstep at bucket {bucket} aborted a round "
+                    f"that needs {need} rows per device, and no larger "
+                    f"bucket is left under local_capacity={cap}")
+            if new_bucket != bucket:
+                with phase("rebucket", rid):
+                    fshard = rebucket_plan(bucket, new_bucket)(fshard)
+                    trace.transition()
+                bucket = new_bucket
         if cfg.checkpoint_every and it >= next_ckpt:
             from .. import checkpoint as ckpt
             ckpt.save_pytree(cfg.checkpoint_dir, it,
@@ -842,7 +951,9 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
         per_device_live=[int(x) for x in np.asarray(live_h)],
         superstep_rounds=k_max, local_capacity=cap,
         per_device_peak_rows=[int(x) for x in peak_rows],
-        live_rows_sum=live_rows_sum)
+        live_rows_sum=live_rows_sum, slot_rows_sum=slot_rows_sum,
+        bucket_transitions=trace.n_bucket_transitions,
+        grow_aborts=grow_aborts)
     return EnumerationResult(
         n_cycles=n_cycles, n_triangles=n_tri, cycle_masks=None,
         iterations=it, history=history, stats=stats,

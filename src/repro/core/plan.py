@@ -59,8 +59,9 @@ class PlanKey:
     """Identity of one compiled program. ``batch=0`` means unbatched;
     ``batch=B`` is the vmapped multi-graph superstep (for ``kind='dist'``
     it carries the device count). ``extra`` carries kind-specific statics
-    (the dist programs put ``('deal'|'step', mesh, axis, balance_block,
-    balance_every, n, m)`` there)."""
+    (the dist programs put their tag, the mesh and its axes, the balance
+    knobs, ``local_capacity`` — the superstep's GROW/SHRINK exits depend on
+    it — and ``n, m`` there)."""
     kind: str                # 'wave' | 'dist'
     bucket: int              # frontier capacity (rows)
     nw: int                  # mask words per row

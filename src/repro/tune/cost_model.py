@@ -19,9 +19,10 @@ host-side computation:
 * ``DistProfile`` / ``replay_dist`` — the sharded twin: the same wave shape
                     plus the observed per-device peaks and balance cadence
                     of a ``core.distributed`` run. The dispatch/sync/round
-                    chop is exact (the sharded driver has no buckets to
-                    guess); per-device placement under a DIFFERENT
-                    ``balance_every`` / ``local_capacity`` is not
+                    chop is exact (the busiest device's per-round rows,
+                    recorded in the trace, drive the shared bucket policy
+                    ``dist_next_bucket``); per-device placement under a
+                    DIFFERENT ``balance_every`` / ``local_capacity`` is not
                     replayable without re-running the diffusion, so the
                     twin carries a conservative *feasibility guard*: a
                     candidate whose local capacity cannot provably hold the
@@ -671,6 +672,41 @@ def dist_wire_bytes(n: int, nw: int, compress: bool) -> tuple[int, int]:
     return 8 * int(nw) + 12, 8
 
 
+def dist_bucket_range(cfg) -> tuple[int, int]:
+    """(floor, ceiling) of the sharded superstep's per-device bucket.
+
+    The ceiling is ``local_capacity``; the floor is the bucket of two
+    balance blocks (capped at the ceiling), so a device can still donate a
+    whole block."""
+    ceiling = int(cfg.local_capacity)
+    return min(cfg.bucket(2 * int(cfg.balance_block)), ceiling), ceiling
+
+
+def dist_first_bucket(cfg, rows: int) -> int:
+    """The bucket the sharded deal runs at, for at most ``rows`` triplets
+    per device."""
+    floor, ceiling = dist_bucket_range(cfg)
+    return min(max(cfg.bucket(max(int(rows), 1)), floor), ceiling)
+
+
+def dist_next_bucket(cfg, status: str, cur: int, *, need: int,
+                     peak: int) -> int:
+    """The sharded driver's bucket policy — one function for the driver
+    and its replay twin. A superstep at bucket ``cur`` exited with
+    ``status``: on GROW the busiest device's aborted round needed ``need``
+    rows, so grow to its bucket with ``grow_headroom`` extra doublings; on
+    RUN or SHRINK shrink to the bucket of ``peak``, the busiest device's
+    live rows, when that is smaller. Floor and ceiling from
+    ``dist_bucket_range``."""
+    floor, ceiling = dist_bucket_range(cfg)
+    if status == _GROW:
+        return min(cfg.bucket(cfg.bucket(max(int(need), 1))
+                              << max(int(cfg.grow_headroom), 0)), ceiling)
+    if status in (_RUN, _SHRINK):
+        return min(max(cfg.bucket(max(int(peak), 1)), floor), cur)
+    return cur
+
+
 @dataclasses.dataclass(frozen=True)
 class DistProfile:
     """Wave shape of one SHARDED enumeration plus the placement facts the
@@ -696,6 +732,12 @@ class DistProfile:
     # 2-level mesh facts (flat runs leave the defaults; DESIGN.md §7)
     nhost: int = 1                     # host-tier size H (ndev = H·D)
     base_cross_balance_every: int = 1  # cross cadence of the profiled run
+    # per-device bucketing facts (``TraceEvent.dev_new`` / ``dev_live`` of
+    # the profiled run, one entry per round; empty without a trace, when
+    # the global sizes stand in — everything on one device)
+    dev_new: tuple[int, ...] = ()      # busiest device's rows before balance
+    dev_live: tuple[int, ...] = ()     # busiest device's rows after it
+    deal_bucket: int = 0               # bucket the deal ran at (0: unknown)
 
     @property
     def limit(self) -> int:
@@ -716,11 +758,18 @@ class DistProfile:
         feasibility guard stricter."""
         base = WaveProfile.from_history(history, n=n, nw=nw,
                                         max_iters=cfg.max_iters)
-        peak_dev = 0
+        peak_dev = deal_bucket = 0
+        dev_new: list[int] = []
+        dev_live: list[int] = []
         for tr in traces:
             for e in getattr(tr, "events", []):
                 if e.kind == "dist" and e.per_device:
                     peak_dev = max(peak_dev, max(e.per_device))
+                if e.kind == "dist":
+                    dev_new.extend(e.dev_new)
+                    dev_live.extend(e.dev_live)
+                elif e.kind == "deal":
+                    deal_bucket = e.bucket
         if peak_dev == 0:
             peak_dev = base.peak
         host_axis = getattr(cfg, "host_axis", None)
@@ -735,7 +784,9 @@ class DistProfile:
                    balance_block=int(cfg.balance_block),
                    max_iters=cfg.max_iters, nhost=max(nhost, 1),
                    base_cross_balance_every=max(
-                       int(getattr(cfg, "cross_balance_every", 1)), 1))
+                       int(getattr(cfg, "cross_balance_every", 1)), 1),
+                   dev_new=tuple(dev_new), dev_live=tuple(dev_live),
+                   deal_bucket=int(deal_bucket))
 
 
 def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
@@ -743,11 +794,16 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
     candidate config.
 
     ``cfg`` is duck-typed: needs ``superstep_rounds``, ``local_capacity``,
-    ``balance_every``, ``balance_block``. Mirrors the driver exactly where
-    the driver is deterministic — the K-round dispatch chop with on-device
-    termination (a superstep ends on budget or the round the global wave
-    dies), one deal dispatch + one readback per superstep + one final
-    counter fetch — and conservatively where it is not: per-device peaks
+    ``balance_every``, ``balance_block``, ``grow_headroom`` and ``bucket``.
+    Mirrors the driver exactly where the driver is deterministic — the
+    K-round dispatch chop with on-device exits (a superstep ends on budget,
+    the round the global wave dies, a GROW abort or a SHRINK exit), each
+    superstep charged at the per-device bucket it ran at and re-bucketed
+    through ``dist_next_bucket``, one deal dispatch + one
+    readback per superstep + one final counter fetch. The busiest device's
+    rows per round come from the profiled run, so the chop is exact under
+    its balance cadence and block and an estimate under another. It is
+    conservative where the driver is not deterministic: per-device peaks
     under a different balance cadence are ESTIMATED (scaled linearly with
     the cadence ratio) and a candidate is marked infeasible unless its
     capacity holds twice the estimate (capacities at or above the base
@@ -795,43 +851,73 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
 
     passes = 1 if getattr(cfg, "fused_round", True) else 2
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
-    dispatches = syncs = 0
+    # per-device bucketing: the busiest device's rows per round (the
+    # profiled run's, else the global sizes — all rows on one device)
+    n_rounds = len(t)
+    dev_new = profile.dev_new if len(profile.dev_new) >= n_rounds else t
+    dev_live = profile.dev_live if len(profile.dev_live) >= n_rounds else t
+    floor, ceiling = dist_bucket_range(cfg)
+    b = dist_first_bucket(cfg, profile.deal_bucket or ceiling)
+    programs = {("deal", b)}
+    peak_bucket = b
+    dispatches = 1                            # stage-1 device-side deal
+    syncs = 1                                 # ... and its meta readback
     row_work = waste = balance_rounds = cross_rounds = launches = 0
-    by_cause: dict[str, int] = {}
+    transitions = 0
+    by_cause: dict[str, int] = {_RUN: 1}
     cnt = profile.n0
-    dispatches += 1                           # stage-1 device-side deal
-    syncs += 1                                # ... and its meta readback
-    by_cause["RUN"] = by_cause.get("RUN", 0) + 1
     it = 0
-    while it < min(limit, len(t)) and cnt > 0:
+    while it < min(limit, n_rounds) and cnt > 0:
         k = min(K, limit - it)
-        r = 0
-        while r < k and cnt > 0 and it + r < len(t):
-            enter = cnt
+        shrink_below = b // 4 if b > floor else 0
+        programs.add(("step", b))
+        peak_bucket = max(peak_bucket, b)
+        status = _RUN
+        r = att = 0
+        while status == _RUN and r < k and cnt > 0 and it + r < n_rounds:
+            att += 1
+            row_work += passes * b * ndev * nw
+            waste += passes * max(b * ndev - max(cnt, 1), 0) * nw
+            if b < ceiling and dev_new[it + r] > b:
+                status = _GROW                # aborted: nothing applied
+                break
             cnt = t[it + r]
-            row_work += passes * cap * ndev * nw
-            waste += passes * max(cap * ndev - max(enter, 1), 0) * nw
             r += 1
             # global-round cadences, matching the driver's round_base + r
             if dev_size > 1 and (it + r) % every == 0:
                 balance_rounds += 1
             if nhost > 1 and (it + r) % cross_period == 0:
                 cross_rounds += 1
+            # SHRINK is evaluated where a launch of R rounds ends
+            launch_end = (att % rpl == 0 or r == k or cnt == 0
+                          or it + r == n_rounds)
+            if (launch_end and shrink_below and cnt > 0
+                    and dev_live[it + r - 1] <= shrink_below):
+                status = _SHRINK
         # while-loop iterations of the multi-round body: each advances up
-        # to R masked rounds, so inner rounds past the wave's death still
-        # run a (discarded) local step — full passes, all waste
-        n_launches = -(-r // rpl) if r else 0
+        # to R masked rounds, so inner rounds past the wave's death or an
+        # abort still run a (discarded) local step — full passes, all waste
+        n_launches = -(-att // rpl)
         launches += n_launches
-        idle = n_launches * rpl - r
-        row_work += idle * passes * cap * ndev * nw
-        waste += idle * passes * cap * ndev * nw
+        idle = n_launches * rpl - att
+        row_work += idle * passes * b * ndev * nw
+        waste += idle * passes * b * ndev * nw
+        if status != _GROW and cnt == 0:
+            status = _DONE
+        by_cause[status] = by_cause.get(status, 0) + 1
         dispatches += 1
         syncs += 1
-        status = _DONE if cnt == 0 else _RUN
-        by_cause[status] = by_cause.get(status, 0) + 1
+        need = dev_new[it + r] if status == _GROW else 0
         it += r
-        if r == 0:
+        if r == 0 and status != _GROW:
             break
+        if it < limit and cnt > 0:
+            nb = dist_next_bucket(cfg, status, b, need=need,
+                                  peak=dev_live[it - 1] if r else 0)
+            if nb != b:
+                programs.add(("rebucket", b, nb))
+                transitions += 1
+                b = nb
     syncs += 1                                # final counter readback
     row_work += (balance_rounds + cross_rounds) * block * ndev * nw
     row_b, stat_b = dist_wire_bytes(profile.n, nw, False)
@@ -840,10 +926,10 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
     bytes_cross = cross_rounds * ndev * (block * xrow_b + xstat_b)
     return ReplaySummary(
         n_dispatches=dispatches, n_host_syncs=syncs,
-        n_bucket_transitions=0, n_drains=0, rounds=it,
+        n_bucket_transitions=transitions, n_drains=0, rounds=it,
         row_work=row_work, padded_waste=waste,
-        n_programs=2,                         # the deal + the superstep
-        peak_bucket=cap, by_cause=by_cause,
+        n_programs=len(programs), peak_bucket=peak_bucket,
+        by_cause=by_cause,
         feasible=feasible, est_peak_device=int(est_peak),
         bytes_intra=int(bytes_intra), bytes_cross=int(bytes_cross),
         n_kernel_launches=launches)

@@ -87,6 +87,14 @@ class TraceEvent:
     per_device: tuple[int, ...] = ()  # per-device PEAK live rows inside the
     #                            dispatch — the placement fact the sharded
     #                            replay twin's feasibility guard consumes
+    dev_new: tuple[int, ...] = ()  # per applied round, the busiest device's
+    #                            extension rows before balancing (what its
+    #                            bucket must hold; a GROW abort's own sits
+    #                            in ``pending_new``)
+    dev_live: tuple[int, ...] = ()  # per applied round, the busiest
+    #                            device's live rows after balancing — with
+    #                            ``dev_new`` what the sharded replay twin's
+    #                            bucket policy reads
     moved: int = 0             # rows shipped by diffusion balancing (both
     #                            tiers; ``moved - moved_cross`` is intra)
     lost: int = 0              # receiver-side balance overflow (must be 0
@@ -235,7 +243,8 @@ class WaveTrace:
                  cyc_fill: int = 0, t_ms: float = 0.0,
                  fresh: bool = False, plan_key: str = "",
                  launches: int = 1, ndev: int = 0,
-                 per_device=(), moved: int = 0, lost: int = 0,
+                 per_device=(), dev_new=(), dev_live=(),
+                 moved: int = 0, lost: int = 0,
                  moved_cross: int = 0, comm_bytes_intra: int = 0,
                  comm_bytes_cross: int = 0,
                  lanes: int = 0, live_lanes: int = 0, retired: int = 0,
@@ -269,6 +278,8 @@ class WaveTrace:
             t_start_ms=float(t_start_ms), wall_ms=float(wall_ms),
             fresh=bool(fresh), plan_key=str(plan_key),
             ndev=int(ndev), per_device=tuple(int(x) for x in per_device),
+            dev_new=tuple(int(x) for x in dev_new),
+            dev_live=tuple(int(x) for x in dev_live),
             moved=int(moved), lost=int(lost),
             moved_cross=int(moved_cross),
             comm_bytes_intra=int(comm_bytes_intra),

@@ -139,7 +139,8 @@ f = Frontier(path=S((ndev * cap, nw), jnp.uint32),
              blocked=S((ndev * cap, nw), jnp.uint32),
              v1=S((ndev * cap,), jnp.int32), l2=S((ndev * cap,), jnp.int32),
              vlast=S((ndev * cap,), jnp.int32), count=S((ndev,), jnp.int32))
-step = D.make_dist_superstep(mesh, 'data', g_spec, cfg, g.max_degree, 4)
+step = D.make_dist_superstep(mesh, 'data', g_spec, cfg, g.max_degree, 4,
+                             cap)
 text = jax.jit(step).lower(g, f, S((ndev, D._N_COUNTERS), jnp.int32),
                            jnp.int32(4), jnp.int32(0)).as_text(
                                debug_info=True)
@@ -179,9 +180,12 @@ svc, cfg, res = run(8)
 s = res.stats
 R = s['iterations']
 assert R > 8, R                       # multiple supersteps exercised
-# one deal dispatch + ceil(R/8) supersteps; one sync each + final fetch
-assert s['n_dispatches'] <= -(-R // 8) + 1, s
-assert s['n_host_syncs'] <= -(-R // 8) + 2, s
+# one deal dispatch + ceil(R/8) supersteps, plus one superstep per early
+# exit (each GROW abort and each SHRINK re-buckets); one sync each + the
+# final fetch
+extra = s['bucket_transitions'] + s['grow_aborts']
+assert s['n_dispatches'] <= -(-R // 8) + 1 + extra, s
+assert s['n_host_syncs'] <= -(-R // 8) + 2 + extra, s
 ev = res.trace.events
 assert [e.kind for e in ev] == ['deal'] + ['dist'] * (len(ev) - 1)
 assert all(e.ndev == 4 for e in ev)
@@ -198,6 +202,9 @@ rep = replay_dist(prof, cfg)
 assert rep.n_dispatches == s['n_dispatches'], (rep, s)
 assert rep.n_host_syncs == s['n_host_syncs'], (rep, s)
 assert rep.rounds == R and rep.feasible
+# ... and the same GROW/SHRINK exits and re-buckets, through one policy
+assert rep.by_cause == s['exit_causes'], (rep.by_cause, s['exit_causes'])
+assert rep.n_bucket_transitions == s['bucket_transitions'] > 0, (rep, s)
 
 # per-round arm (K=1): the old dispatch-per-round pattern
 _, _, res1 = run(1)
@@ -212,6 +219,247 @@ res2 = svc.enumerate(g)
 assert res2.n_cycles == res.n_cycles
 assert svc.stats['n_traces'] == t0, 'warm sharded path retraced'
 print('OK', R, s['n_dispatches'], s1['n_dispatches'])
+"""))
+
+
+# the fixed-capacity run: the bucket policy's floor raised to the ceiling,
+# so the deal and every superstep run at ``local_capacity`` and no
+# superstep exits GROW or SHRINK. The driver and the superstep builder
+# look the policy up in ``tune.cost_model`` when they run, so patching the
+# module reaches both.
+_FIXED = """
+import repro.tune.cost_model as cm
+cm.dist_bucket_range = lambda cfg: (int(cfg.local_capacity),) * 2
+"""
+
+
+@pytest.mark.parametrize("rpl", [1, 2])
+def test_bucketed_superstep_matches_fixed_capacity(rpl):
+    """Per-device bucketing changes no answer and no placement: against
+    the fixed-capacity run (every superstep at ``local_capacity``, no
+    GROW or SHRINK exit), the bucketed run's count, history,
+    per-device peaks and final live rows, balance moves and live-row sum
+    are equal, no row is dropped or lost, the mechanism engaged (it
+    re-bucketed, aborted a round to GROW, and ran fewer row slots), and
+    the replay twin reproduces its dispatches, syncs and exits."""
+    print(_run("""
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core import (CycleService, EngineConfig, build_graph,
+                        sequential_chordless_cycles)
+from repro.core.graphs import grid_graph, random_gnp
+from repro.tune import DistProfile, replay_dist
+import repro.tune.cost_model as cm
+
+mesh = Mesh(np.array(jax.devices())[:4], ('data',))
+cap = 1 << 13
+policy = cm.dist_bucket_range
+for n, edges in (grid_graph(5, 6), random_gnp(30, 0.2, 11)):
+    g = build_graph(n, edges)
+    ref, _ = sequential_chordless_cycles(n, edges)
+    cfg = EngineConfig(store=False, mesh=mesh, local_capacity=cap,
+                       balance_block=64, rounds_per_launch=%d)
+    out = {}
+    for arm in ('bucketed', 'fixed'):
+        if arm == 'fixed':
+%s
+        res = CycleService(cfg, trace=True).enumerate(g)
+        cm.dist_bucket_range = policy
+        s = res.stats
+        assert res.n_cycles == ref, (arm, n, res.n_cycles, ref)
+        assert s['dropped'] == 0 and s['lost'] == 0, (arm, s)
+        out[arm] = res
+    b, f = out['bucketed'], out['fixed']
+    # the replay twin chops the bucketed run exactly
+    rep = replay_dist(DistProfile.from_run(
+        b.history, n=g.n, nw=g.adj_bits.shape[1], ndev=4, cfg=cfg,
+        traces=(b.trace,)), cfg)
+    assert (rep.n_dispatches, rep.n_host_syncs, rep.by_cause) == (
+        b.stats['n_dispatches'], b.stats['n_host_syncs'],
+        b.stats['exit_causes']), (rep, b.stats)
+    assert b.history == f.history, n
+    for key in ('per_device_peak_rows', 'per_device_live', 'moved',
+                'live_rows_sum', 'iterations'):
+        assert b.stats[key] == f.stats[key], (key, b.stats[key],
+                                              f.stats[key])
+    assert f.stats['slot_rows_sum'] == f.stats['iterations'] * 4 * cap
+    assert f.stats['bucket_transitions'] == f.stats['grow_aborts'] == 0
+    assert set(f.stats['exit_causes']) <= {'RUN', 'DONE'}, f.stats
+    assert b.stats['bucket_transitions'] > 0 and b.stats['grow_aborts'] > 0
+    assert b.stats['slot_rows_sum'] < f.stats['slot_rows_sum'], b.stats
+print('OK')
+""" % (rpl, "\n".join("            " + line
+                       for line in _FIXED.strip().splitlines()))))
+
+
+def test_grow_abort_leaves_the_round_untouched():
+    """A superstep whose busiest device's extensions outgrow its bucket
+    aborts that round on every device: its rows and counters are those of
+    the same superstep stopped by its budget just before the round, the
+    status is GROW, and the need it hands back exceeds the bucket."""
+    print(_run("""
+import jax, numpy as np
+import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+from repro.core import EngineConfig, build_graph
+from repro.core import distributed as D
+from repro.core.engine import STATUS_NAMES
+from repro.core.graphs import grid_graph
+
+ndev, b = 4, 128
+mesh = Mesh(np.array(jax.devices())[:ndev], ('data',))
+g = build_graph(*grid_graph(5, 6))
+g_spec = jax.tree_util.tree_map(lambda _: P(), g)
+cfg = EngineConfig(store=False, mesh=mesh, local_capacity=1 << 13,
+                   balance_block=64)          # floor 128: no SHRINK at b
+deal = jax.jit(D.make_dist_deal(mesh, 'data', g_spec, b, g.max_degree))
+step = jax.jit(D.make_dist_superstep(mesh, 'data', g_spec, cfg,
+                                     g.max_degree, 16, b))
+zeros = jnp.zeros((ndev, D._N_COUNTERS), jnp.int32)
+
+f, _ = deal(g)
+fa, ca, r, status, nh, ch, lh = step(g, f, zeros, jnp.int32(16),
+                                     jnp.int32(0))
+r = int(r)
+assert STATUS_NAMES[int(status)] == 'GROW', int(status)
+assert int(nh[r]) > b, (r, np.asarray(nh))
+assert 0 < r < 16, r
+
+f, _ = deal(g)
+fb, cb, rb, sb, _, _, lhb = step(g, f, zeros, jnp.int32(r), jnp.int32(0))
+assert int(rb) == r and STATUS_NAMES[int(sb)] == 'RUN'
+assert np.array_equal(np.asarray(ca), np.asarray(cb))
+assert np.array_equal(np.asarray(lh)[:, :r], np.asarray(lhb)[:, :r])
+cnt = np.asarray(fa.count)
+assert np.array_equal(cnt, np.asarray(fb.count))
+for name in ('path', 'blocked', 'v1', 'l2', 'vlast'):
+    xa = np.asarray(getattr(fa, name)).reshape(ndev, b, -1)
+    xb = np.asarray(getattr(fb, name)).reshape(ndev, b, -1)
+    for d in range(ndev):
+        assert np.array_equal(xa[d, :cnt[d]], xb[d, :cnt[d]]), (name, d)
+print('OK', r, int(nh[r]))
+"""))
+
+
+def test_slot_rows_fall_below_the_ceiling_as_the_wave_rises_and_falls():
+    """Over a wave that rises and falls, the rounds run at buckets that
+    sum to less than every round at ``local_capacity`` on every device,
+    and still hold every live row."""
+    print(_run("""
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core import CycleService, EngineConfig, build_graph
+from repro.core.graphs import grid_graph
+
+mesh = Mesh(np.array(jax.devices())[:4], ('data',))
+cap = 1 << 13
+res = CycleService(EngineConfig(store=False, mesh=mesh, local_capacity=cap,
+                                balance_block=32)).enumerate(
+    build_graph(*grid_graph(5, 8)))
+s = res.stats
+T = [h['T'] for h in res.history]
+assert max(T) > T[0] and T[-1] < max(T)          # the wave rose and fell
+assert s['live_rows_sum'] <= s['slot_rows_sum']
+assert s['slot_rows_sum'] < s['iterations'] * 4 * cap, s
+assert s['dropped'] == 0 and s['lost'] == 0
+print('OK', s['slot_rows_sum'], s['iterations'] * 4 * cap)
+"""))
+
+
+def test_overflow_at_the_ceiling_still_raises():
+    """Bucketing grows no bucket past ``local_capacity``: a wave whose
+    busiest device outgrows the ceiling still drops rows there, and the
+    driver refuses the count."""
+    print(_run("""
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core import CycleService, EngineConfig, build_graph
+from repro.core.graphs import grid_graph
+
+mesh = Mesh(np.array(jax.devices())[:4], ('data',))
+cfg = EngineConfig(store=False, mesh=mesh, local_capacity=256,
+                   balance_block=64)
+try:
+    CycleService(cfg).enumerate(build_graph(*grid_graph(5, 8)))
+    raise SystemExit('expected the overflow RuntimeError')
+except RuntimeError as e:
+    assert 'sharded frontier overflow' in str(e), e
+    assert 'local_capacity=256' in str(e), e
+print('OK')
+"""))
+
+
+def test_a_grow_exit_the_policy_cannot_serve_raises():
+    """A superstep that aborts a round to GROW hands the host a need its
+    bucket cannot hold; a policy that gives back the same bucket would
+    relaunch the same abort forever, so the driver refuses it."""
+    print(_run("""
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core import CycleService, EngineConfig, build_graph
+from repro.core.graphs import grid_graph
+import repro.tune.cost_model as cm
+
+mesh = Mesh(np.array(jax.devices())[:4], ('data',))
+cfg = EngineConfig(store=False, mesh=mesh, local_capacity=1 << 13,
+                   balance_block=64)
+cm.dist_next_bucket = lambda cfg, status, cur, **kw: cur
+try:
+    CycleService(cfg).enumerate(build_graph(*grid_graph(5, 8)))
+    raise SystemExit('expected the RuntimeError')
+except RuntimeError as e:
+    assert 'no larger bucket is left' in str(e), e
+print('OK')
+"""))
+
+
+def test_one_service_keeps_programs_apart_by_local_capacity():
+    """The superstep's GROW and SHRINK exits depend on ``local_capacity``,
+    so one service enumerating with two configs that differ only there
+    builds separate programs: the small ceiling still refuses the wave
+    that overflows it, whichever config ran first, and the large one
+    still counts it exactly."""
+    print(_run("""
+import dataclasses
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core import (CycleService, EngineConfig, build_graph,
+                        sequential_chordless_cycles)
+from repro.core.graphs import grid_graph
+
+mesh = Mesh(np.array(jax.devices())[:4], ('data',))
+n, edges = grid_graph(5, 8)
+g = build_graph(n, edges)
+ref, _ = sequential_chordless_cycles(n, edges)
+small = EngineConfig(store=False, mesh=mesh, local_capacity=2048,
+                     balance_block=64)          # the busiest device peaks at 2640
+large = dataclasses.replace(small, local_capacity=1 << 13)
+
+
+def refused(svc):
+    try:
+        svc.enumerate(g, config=small)
+    except RuntimeError as e:
+        assert 'sharded frontier overflow' in str(e), e
+        return
+    raise SystemExit('expected the overflow RuntimeError')
+
+
+def exact(svc):
+    res = svc.enumerate(g, config=large)
+    assert res.n_cycles == ref, (res.n_cycles, ref)
+    assert res.stats['dropped'] == res.stats['lost'] == 0, res.stats
+    return {e.bucket for e in res.trace.events if e.kind == 'dist'}
+
+
+svc = CycleService(small, trace=True)
+refused(svc)                       # then the capacity raised, as told
+buckets = exact(svc)
+assert 2048 in buckets, buckets    # a bucket both configs run at
+svc = CycleService(small, trace=True)
+exact(svc)
+refused(svc)
+print('OK', sorted(buckets))
 """))
 
 

@@ -679,14 +679,15 @@ def test_d2h_arrays_match_a_hand_count():
 
 def test_sharded_driver_records_its_phases_and_reads(tmp_path):
     """The sharded driver opens the wave driver's host phases: a span each
-    for the deal (``seed``), every superstep and every readback, nested in
-    the request, and its sync and read counters match the readbacks."""
+    for the deal (``seed``), every superstep, every readback and every
+    resize of the shards (``rebucket``), nested in the request, and its
+    sync and read counters match the readbacks."""
     import jax
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     svc = CycleService(EngineConfig(store=False, mesh=mesh,
                                     formulation="bitword", backend="pallas",
-                                    local_capacity=1 << 10, balance_block=64,
+                                    local_capacity=1 << 10, balance_block=8,
                                     superstep_rounds=3), trace=True)
     g = build_graph(*grid_graph(4, 5))
     svc.enumerate(g)                             # compile outside the trace
@@ -702,6 +703,9 @@ def test_sharded_driver_records_its_phases_and_reads(tmp_path):
     # one readback after the deal, one per superstep, one at the end, and
     # no dispatch slices added a second time by the request's decomposition
     assert names.count("readback") == steps + 2
+    # one resize of the shards per bucket transition
+    assert res.stats["bucket_transitions"] > 0
+    assert names.count("rebucket") == res.stats["bucket_transitions"]
     assert not {"deal", "dist"} & set(names)
     assert res.stats["n_host_syncs"] == steps + 2
     # the deal's meta; per superstep the rounds, status, three histories
@@ -712,7 +716,7 @@ def test_sharded_driver_records_its_phases_and_reads(tmp_path):
     (root,) = [e for e in evs if e[2] == "repro.enumerate"]
     kids = [e for e in evs if e is not root]
     assert {e[2] for e in kids} == {"repro.seed", "repro.superstep",
-                                    "repro.readback"}
+                                    "repro.readback", "repro.rebucket"}
     assert all(root[0] <= s and e <= root[1] for s, e, _ in kids)
     assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
     covered = sum(e - s for s, e, _ in kids)
