@@ -26,8 +26,8 @@ power of two between two balance blocks and ``local_capacity``), the
 sharded twin of the wave engine's GROW/SHRINK re-bucketing — a round the
 busiest device's bucket cannot hold is aborted on every device and the
 superstep exits GROW; a wave that shrank well below the bucket exits
-SHRINK; the host picks the next bucket with
-``tune.cost_model.dist_next_bucket`` and resizes every shard. A round's
+SHRINK; the host picks the next bucket with ``dist_next_bucket`` and
+resizes every shard. A round's
 compaction costs per bucket row, so this work follows the live rows.
 
 Load balance: DFS trees are lopsided, so on balance rounds each device
@@ -459,6 +459,59 @@ def make_dist_deal(mesh: Mesh, axis: str, g_spec, cap: int, delta: int,
 # Stage 2: the sharded wave superstep
 # ---------------------------------------------------------------------------
 
+def dist_wire_bytes(n: int, nw: int, compress: bool) -> tuple[int, int]:
+    """Modeled wire size of one balance hop: (bytes per donated row,
+    per-round stat overhead per device).
+
+    The SAME formula the sharded driver charges into its per-tier metrics
+    and trace events — replay and reality share one accounting. Exact rows
+    ship path + blocked (nw uint32 words each) + three int32 ids, plus the
+    int32 count and the reverse-permuted neighbor count. The compressed
+    cross-host wire ships the bit-packed path (⌈n/8⌉ bytes) + two
+    ``ef_quantize``d int8 endpoint ids per row (``blocked``/``l2`` are
+    reconstructed receiver-side), plus the int8 mean-load payload, its fp32
+    shared scale, and the exact counts.
+    """
+    if compress:
+        return (int(n) + 7) // 8 + 2, 17
+    return 8 * int(nw) + 12, 8
+
+
+def dist_bucket_range(cfg) -> tuple[int, int]:
+    """(floor, ceiling) of the sharded superstep's per-device bucket.
+
+    The ceiling is ``local_capacity``; the floor is the bucket of two
+    balance blocks (capped at the ceiling), so a device can still donate a
+    whole block."""
+    ceiling = int(cfg.local_capacity)
+    return min(cfg.bucket(2 * int(cfg.balance_block)), ceiling), ceiling
+
+
+def dist_first_bucket(cfg, rows: int) -> int:
+    """The bucket the sharded deal runs at, for at most ``rows`` triplets
+    per device."""
+    floor, ceiling = dist_bucket_range(cfg)
+    return min(max(cfg.bucket(max(int(rows), 1)), floor), ceiling)
+
+
+def dist_next_bucket(cfg, status: str, cur: int, *, need: int,
+                     peak: int) -> int:
+    """The sharded driver's bucket policy — one function for the driver
+    and the tuner's replay twin (``replay_dist``). A superstep at bucket
+    ``cur`` exited with ``status`` (a ``STATUS_NAMES`` name): on GROW the
+    busiest device's aborted round needed ``need`` rows, so grow to its
+    bucket with ``grow_headroom`` extra doublings; on RUN or SHRINK shrink
+    to the bucket of ``peak``, the busiest device's live rows, when that is
+    smaller. Floor and ceiling from ``dist_bucket_range``."""
+    floor, ceiling = dist_bucket_range(cfg)
+    if status == "GROW":
+        return min(cfg.bucket(cfg.bucket(max(int(need), 1))
+                              << max(int(cfg.grow_headroom), 0)), ceiling)
+    if status in ("RUN", "SHRINK"):
+        return min(max(cfg.bucket(max(int(peak), 1)), floor), cur)
+    return cur
+
+
 def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
                         delta: int, k_max: int, bucket: int):
     """Build the UNJITTED sharded wave superstep at the per-device
@@ -485,7 +538,7 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     — and the superstep exits GROW with that count in the history slot of
     the aborted round, for the host to re-bucket. At the ceiling the round
     runs and drops rows, which the host refuses. Above the floor
-    (``tune.cost_model.dist_bucket_range``) the superstep exits SHRINK
+    (``dist_bucket_range``) the superstep exits SHRINK
     once no device holds more than a quarter of the bucket (checked where
     a launch of ``rounds_per_launch`` rounds ends).
 
@@ -503,7 +556,6 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     cycle counts and live counts (the per-device wave profiles the tuner's
     sharded replay twin consumes; their column sums are the global ones).
     """
-    from ..tune.cost_model import dist_bucket_range
     floor, ceiling = dist_bucket_range(cfg)
     cap = int(bucket)
     can_grow = cap < ceiling
@@ -517,7 +569,6 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     compress = bool(cfg.compress_cross_host)
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
     op = E.expand_op(cfg.formulation, cfg.backend)
-    fused = bool(cfg.fused_round)
     row_axes = (host_axis, axis) if host_axis else (axis,)
     fspec = _fspec(mesh, row_axes)
     rspec = fspec.count  # P over the row tiers (per-device outputs)
@@ -546,15 +597,14 @@ def make_dist_superstep(mesh: Mesh, axis: str, g_spec, cfg: EngineConfig,
     def round_(g, f, ef, gidx, active):
         """One round on this device's rows: the op's flags, then — when
         the busiest device's extensions fit the bucket — its compaction
-        and the balance step. ``fused`` selects the one-pass gather
-        compaction (DESIGN.md §6.8). Returns (f', ef', counter increments,
-        need, fits)."""
+        and the balance step. Returns (f', ef', counter increments, need,
+        fits)."""
         flags, n_cyc, n_new = op.flags(g, f, delta)
         need = pmax(n_new.astype(jnp.int32))
 
         def run(args):
             f, ef = args
-            f2, drop = op.compact(g, f, flags, delta, cap, fused)
+            f2, drop = op.compact(g, f, flags, delta, cap)
             f2, moved_i, moved_x, lost, ef = balance(g, f2, gidx, active,
                                                      ef)
             return (f2, ef), jnp.stack([n_cyc, drop + lost, moved_i,
@@ -693,11 +743,12 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
     the |V|−3 budget runs out — one batched readback per superstep, so host
     syncs are O(iterations / superstep_rounds + bucket transitions) + O(1).
     Every superstep runs at one per-device bucket: the deal at the bucket
-    of n·C(Δ, 2) wedges per device (every triplet is a wedge), then, after each superstep, the bucket
-    ``tune.cost_model.dist_next_bucket`` picks from its exit (GROW: the
-    aborted round's busiest-device need; RUN/SHRINK: the busiest device's
-    live rows), between the floor and the ceiling ``local_capacity``; a
-    change resizes every shard in the ``rebucket`` phase. ``cache`` (a
+    of n·C(Δ, 2) wedges per device (every triplet is a wedge), then, after
+    each superstep, the bucket ``dist_next_bucket`` picks from its exit
+    (GROW: the aborted round's busiest-device need; RUN/SHRINK: the
+    busiest device's live rows), between the floor and the ceiling
+    ``local_capacity``; a change resizes every shard in the ``rebucket``
+    phase. ``cache`` (a
     ``core.plan.ProgramCache``) memoizes the jitted deal + superstep across
     requests on the same mesh/shape; ``trace`` (a ``tune.telemetry
     .WaveTrace``) records per-dispatch events incl. per-device wave peaks
@@ -743,8 +794,6 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
             trace=trace if trace.enabled else None)
 
     from .plan import DistPlan, PlanKey, ProgramCache
-    from ..tune.cost_model import (dist_first_bucket, dist_next_bucket,
-                                   dist_wire_bytes)
 
     row_axes = (host_axis, axis) if host_axis else (axis,)
     # one plan per bucket, kept for the run when no cache is shared
@@ -763,7 +812,6 @@ def enumerate_sharded(g: BitsetGraph, cfg: EngineConfig, *, cache=None,
                           delta=delta, store=False,
                           formulation=cfg.formulation, backend=cfg.backend,
                           k_max=k_max, batch=ndev, donate=bool(donate),
-                          fused=bool(cfg.fused_round),
                           extra=(tag, mesh, axis, host_axis,
                                  cfg.balance_block, cfg.balance_every,
                                  cfg.cross_balance_every,

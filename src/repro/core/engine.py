@@ -2,19 +2,13 @@
 
 The paper relaunches the expansion kernel a fixed |V|−3 times with a
 double-buffered T/T' to avoid device→host convergence checks over PCIe.
-Two engines reproduce that trade-off (DESIGN.md §6.4):
-
-* ``wave`` (default) — device-resident superstep: one jitted program runs up
-  to K expansion rounds in a ``lax.while_loop`` at a fixed capacity bucket,
-  fusing flag computation, popcount cycle counting, cycle gathering into a
-  preallocated device CycleBuffer, and prefix-sum compaction.  The host is
-  re-entered only on *bucket transitions*: frontier outgrew its bucket,
-  cycle buffer filled, wave died, or the |V|−3 round budget ran out.  Host
-  syncs drop from O(iterations) to O(bucket transitions).
-* ``host`` — legacy per-round dispatch (kept as the A/B baseline and for
-  step-debugging), with all per-round scalars batched into ONE readback per
-  round (the `count == 0` probe and the `dropped` assert ride the next
-  round's fetch instead of blocking their own).
+The wave engine keeps that trade-off device-resident (DESIGN.md §6.4): one
+jitted superstep runs up to K expansion rounds in a ``lax.while_loop`` at a
+fixed capacity bucket, fusing flag computation, popcount cycle counting,
+cycle gathering into a preallocated device CycleBuffer, and prefix-sum
+compaction. The host is re-entered only on *bucket transitions*: frontier
+outgrew its bucket, cycle buffer filled, wave died, or the |V|−3 round
+budget ran out. Host syncs are O(bucket transitions), not O(iterations).
 
 Modes:
   * store=True  — returns every chordless cycle as a vertex bitmap (the
@@ -25,7 +19,7 @@ interpreted elsewhere).
 Formulations: 'slot' (paper-faithful) or 'bitword' (TPU-native).
 
 Layering (DESIGN.md §"Service layer"): this module holds the device
-ALGORITHM (``wave_superstep``, the legacy host loop) and ``EngineConfig``;
+ALGORITHM (``wave_superstep``) and ``EngineConfig``;
 ``core.plan`` owns compilation (jit + donation + the cross-graph program
 cache + batch vmap); ``core.service`` owns the host driver loop and the
 public session API (``CycleService``). ``enumerate_chordless_cycles`` is a
@@ -43,9 +37,8 @@ import jax.numpy as jnp
 
 from .bitset_graph import BitsetGraph
 from . import expand as E
-from . import triplets as T
 from .frontier import CycleBuffer, Frontier
-from ..tune.telemetry import STATUSES, WaveTrace, disabled_trace
+from ..tune.telemetry import STATUSES, WaveTrace
 
 
 def _bucket(c: int, *, growth_bits: int = 1) -> int:
@@ -60,7 +53,6 @@ def _bucket(c: int, *, growth_bits: int = 1) -> int:
 
 FORMULATIONS = ("slot", "bitword")
 BACKENDS = ("jnp", "pallas")
-ENGINES = ("wave", "host")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,13 +70,12 @@ class EngineConfig:
     round producing more cycles than the whole buffer triggers a host-side
     buffer regrow.
 
-    Validation is EAGER: unknown ``formulation``/``backend``/``engine`` and
+    Validation is EAGER: unknown ``formulation``/``backend`` and
     cross-field mismatches raise ``ValueError`` here, at construction, with
     the allowed values listed — not deep inside tracing."""
     store: bool = True
     formulation: str = "slot"      # 'slot' | 'bitword'
     backend: str = "jnp"           # 'jnp' | 'pallas'
-    engine: str = "wave"           # 'wave' | 'host'
     growth_bits: int = 1           # bucket granularity (see _bucket)
     superstep_rounds: int = 8      # K — max device rounds per dispatch
     # (K=8 measured best warm time on CPU interpret; raise on real
@@ -93,10 +84,6 @@ class EngineConfig:
     grow_headroom: int = 1         # extra ×2 buckets granted on GROW — an
     # aborted GROW round re-runs its expand at the new bucket, so headroom
     # trades dead-row work for fewer wasted peak-size rounds
-    fused_round: bool = True       # one-pass round (DESIGN.md §6.8): jnp
-    # swaps the cap·Δ scatter compaction for the gather formulation, pallas
-    # collapses the whole guarded round into ONE kernel dispatch
-    # (two-phase scatter). Bit-identical output; tunable (TUNED_KNOBS).
     rounds_per_launch: int = 1     # R — rounds per kernel launch
     # (DESIGN.md §6.11): the superstep body advances up to R complete
     # guarded rounds per while-iteration through the persistent wave
@@ -141,9 +128,6 @@ class EngineConfig:
         if self.backend not in BACKENDS:
             raise ValueError(
                 f"unknown backend {self.backend!r}; allowed: {BACKENDS}")
-        if self.engine not in ENGINES:
-            raise ValueError(
-                f"unknown engine {self.engine!r}; allowed: {ENGINES}")
         for field in ("growth_bits", "superstep_rounds", "cycle_buffer_rows",
                       "rounds_per_launch", "local_capacity", "balance_block",
                       "balance_every", "cross_balance_every"):
@@ -216,8 +200,8 @@ STATUS_NAMES = dict(enumerate(STATUSES))
 def wave_superstep(g: BitsetGraph, f: Frontier, buf: CycleBuffer,
                    rounds_limit: jnp.ndarray, *, delta: int, store: bool,
                    formulation: str, backend: str, k_max: int,
-                   fused: bool = False, rounds_per_launch: int = 1):
-    """Run up to min(k_max, rounds_limit) fused rounds fully on device.
+                   rounds_per_launch: int = 1):
+    """Run up to min(k_max, rounds_limit) guarded rounds fully on device.
 
     UNJITTED device algorithm — compilation (jit + buffer donation + the
     cross-graph program cache + vmap over a graph batch axis) is owned by
@@ -252,7 +236,7 @@ def wave_superstep(g: BitsetGraph, f: Frontier, buf: CycleBuffer,
     def body(c):
         f, buf, r, status, th, ch, pn, pc = c
         f2, buf2, n_cyc, n_new, ok_f, ok_c = E.expand_count_compact(
-            g, f, buf, delta=delta, store=store, op=op, fused=fused)
+            g, f, buf, delta=delta, store=store, op=op)
         ok = ok_f & ok_c
         th = th.at[r].set(jnp.where(ok, n_new, 0))
         ch = ch.at[r].set(jnp.where(ok, n_cyc, 0))
@@ -272,7 +256,7 @@ def wave_superstep(g: BitsetGraph, f: Frontier, buf: CycleBuffer,
         rem = (rounds_limit - r).astype(jnp.int32)
         f2, buf2, ch_r, nh_r, done, ok_f, ok_c = E.expand_count_compact_multi(
             g, f, buf, delta=delta, store=store, rounds=R, op=op,
-            fused=fused, rlimit=rem)
+            rlimit=rem)
         tripped = ~(ok_f & ok_c)
         # histories hold APPLIED rounds only; the (k_max + R - 1) padding
         # keeps the R-wide window in bounds so the update never clamps.
@@ -306,136 +290,12 @@ def wave_superstep(g: BitsetGraph, f: Frontier, buf: CycleBuffer,
     return f, buf, r, status, th, ch, pn, pc
 
 
-# ---------------------------------------------------------------------------
-# Legacy host-driven engine (per-round dispatch, batched readbacks)
-# ---------------------------------------------------------------------------
-
-def _enumerate_host(g: BitsetGraph, cfg: EngineConfig,
-                    progress: Callable[[dict], None] | None,
-                    trace: WaveTrace | None = None) -> EnumerationResult:
-    op = E.expand_op(cfg.formulation, cfg.backend)
-    if cfg.backend == "pallas":
-        from ..kernels import ops as kops
-        trip_flags = kops.triplet_flags
-        bitword_count = kops.bitword_flags_count
-    else:
-        trip_flags = T.triplet_flags
-        bitword_count = E.bitword_flags_count
-
-    store, formulation = cfg.store, cfg.formulation
-    delta = max(g.max_degree, 1)
-    frontier, tri_masks, n_tri = T.initial_frontier(
-        g, bucket=cfg.bucket, flags_fn=trip_flags)
-
-    trace = trace if trace is not None else disabled_trace()
-    cycles: list[np.ndarray] = [tri_masks] if store else []
-    n_cycles = n_tri
-    cnt = int(frontier.count)
-    trace.sync()
-    history = [dict(step=0, T=cnt, C=n_tri)]
-    limit = cfg.max_iters if cfg.max_iters is not None else max(g.n - 3, 0)
-
-    # the previous round's `dropped` scalar rides the NEXT round's readback
-    # (it is provably 0 — out_cap is sized from the exact n_new — so nothing
-    # downstream ever waits on it).
-    prev_dropped = None
-    it = 0
-    while it < limit and cnt > 0:
-        it += 1
-        cap_in, cnt_in = frontier.capacity, cnt
-        trace.tic()
-
-        if formulation == "bitword" and not store:
-            # fast path (§Perf engine hillclimb): popcount-only cycle
-            # counting, exact output sizing, ONE readback per round.
-            ext_w, n_cyc_j, n_new_j = bitword_count(g, frontier)
-            trace.launch()
-            fetch = (n_cyc_j, n_new_j) + (
-                () if prev_dropped is None else (prev_dropped,))
-            got = jax.device_get(fetch)
-            trace.sync()
-            n_cyc, n_new = int(got[0]), int(got[1])
-            if prev_dropped is not None:
-                assert int(got[2]) == 0
-            n_cycles += n_cyc
-            frontier, prev_dropped = E.bitword_compact(
-                g, frontier, ext_w, delta, cfg.bucket(max(n_new, 1)))
-            trace.launch()
-            cnt = n_new
-            trace.dispatch(
-                kind="round", bucket=cap_in, cyc_cap=0, budget=1, rounds=1,
-                status="DONE" if n_new == 0 else "RUN", t_sizes=(n_new,),
-                c_counts=(n_cyc,), enter_count=cnt_in, exit_count=n_new,
-                t_ms=trace.toc_ms(), launches=0)
-            rec = dict(step=it, T=n_new, C=n_cycles)
-            history.append(rec)
-            if progress:
-                progress(rec)
-            continue
-
-        flags, n_cyc_j, n_new_j = op.flags(g, frontier, delta)
-        if formulation == "bitword":
-            close_w, ext_w = flags
-            cand_v = E.bitword_to_slots(ext_w, delta)
-            is_ext = cand_v >= 0
-            ccand = E.bitword_to_slots(close_w, delta)
-            cyc_src, cyc_flags = ccand, ccand >= 0
-        else:
-            cand_v, is_cyc, is_ext = flags
-            cyc_src, cyc_flags = cand_v, is_cyc
-        trace.launch()
-        fetch = (n_cyc_j, n_new_j) + (
-            () if prev_dropped is None else (prev_dropped,))
-        got = jax.device_get(fetch)
-        trace.sync()
-        n_cyc, n_new = int(got[0]), int(got[1])
-        if prev_dropped is not None:
-            assert int(got[2]) == 0
-
-        if store and n_cyc:
-            masks, _ = E.gather_cycles(frontier, cyc_src, cyc_flags,
-                                       cfg.bucket(n_cyc))
-            cycles.append(np.asarray(masks)[:n_cyc])
-            trace.launch()
-            trace.sync()
-        n_cycles += n_cyc
-
-        frontier, prev_dropped = E.compact_extensions(
-            g, frontier, cand_v, is_ext, cfg.bucket(max(n_new, 1)))
-        trace.launch()
-        cnt = n_new
-        trace.dispatch(
-            kind="round", bucket=cap_in, cyc_cap=0, budget=1, rounds=1,
-            status="DONE" if n_new == 0 else "RUN", t_sizes=(n_new,),
-            c_counts=(n_cyc,), enter_count=cnt_in, exit_count=n_new,
-            cyc_fill=n_cyc, t_ms=trace.toc_ms(), launches=0)
-        rec = dict(step=it, T=n_new, C=n_cycles)
-        history.append(rec)
-        if progress:
-            progress(rec)
-
-    if prev_dropped is not None:
-        assert int(jax.device_get(prev_dropped)) == 0
-        trace.sync()
-
-    cycle_masks = None
-    if store:
-        nw = g.adj_bits.shape[1]
-        cycle_masks = (np.concatenate(cycles, axis=0) if cycles
-                       else np.zeros((0, nw), np.uint32))
-    return EnumerationResult(
-        n_cycles=n_cycles, n_triangles=n_tri, cycle_masks=cycle_masks,
-        iterations=it, history=history, stats=trace.finalize(rounds=it),
-        trace=trace if trace.enabled else None)
-
-
 def enumerate_chordless_cycles(
     g: BitsetGraph,
     *,
     store: bool = True,
     formulation: str = "slot",
     backend: str = "jnp",
-    engine: str = "wave",
     max_iters: int | None = None,
     progress: Callable[[dict], None] | None = None,
     config: EngineConfig | None = None,
@@ -448,6 +308,6 @@ def enumerate_chordless_cycles(
     program cache). ``config`` overrides the individual keyword knobs."""
     from .service import default_service
     cfg = config if config is not None else EngineConfig(
-        store=store, formulation=formulation, backend=backend, engine=engine,
+        store=store, formulation=formulation, backend=backend,
         max_iters=max_iters)
     return default_service().enumerate(g, config=cfg, progress=progress)

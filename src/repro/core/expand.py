@@ -21,7 +21,7 @@ superstep of K rounds compiles to one program with zero host syncs.
 
 Backends implement ONE interface (DESIGN.md §6.7): ``ExpandOp`` — the
 (formulation × backend) registry every layer of the stack (wave superstep,
-legacy host engine, sharded step) programs against. Every op is
+sharded step) programs against. Every op is
 batch-transparent: it traces identically with or without a leading lane
 axis, so ``jax.vmap`` of the superstep works on every backend (the pallas
 ops route vmap onto lane-gridded kernels via ``custom_vmap``).
@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .bitset_graph import BitsetGraph, bit_test, popcount
-from .frontier import CycleBuffer, Frontier, scatter_frontier
+from .frontier import CycleBuffer, Frontier
 
 
 # ---------------------------------------------------------------------------
@@ -135,50 +135,18 @@ def compaction_dests(flat_flags: jnp.ndarray, out_cap: int,
     return dest.astype(jnp.int32), total.astype(jnp.int32)
 
 
-def _extension_rows(g: BitsetGraph, f: Frontier, cand_v: jnp.ndarray):
-    """Materialize ⟨p, v⟩ rows for every (path, slot) pair (flat layout)."""
-    cap, delta = cand_v.shape
-    nw = f.n_words
-    row = jnp.repeat(jnp.arange(cap, dtype=jnp.int32), delta)
-    v = cand_v.reshape(-1)
-    vi = jnp.clip(v, 0, None)
-    onehot_w = (jnp.uint32(1) << (vi % 32).astype(jnp.uint32))
-    wi = (vi // 32).astype(jnp.int32)
-    upd = jnp.where(jnp.arange(nw)[None, :] == wi[:, None],
-                    onehot_w[:, None], jnp.uint32(0))
-    new_path = f.path[row] | upd
-    new_blocked = f.blocked[row] | g.adj_bits[f.vlast[row]]
-    return row, v, new_path, new_blocked
-
-
-@partial(jax.jit, static_argnames=("out_cap",), donate_argnums=())
-def compact_extensions(g: BitsetGraph, f: Frontier, cand_v: jnp.ndarray,
-                       is_ext: jnp.ndarray, out_cap: int) -> tuple[Frontier, jnp.ndarray]:
-    """Scatter extended paths ⟨p, v⟩ into a fresh frontier of capacity
-    ``out_cap`` using cumsum offsets. Returns (new_frontier, n_dropped)."""
-    flat_ext = is_ext.reshape(-1)
-    dest, total = compaction_dests(flat_ext, out_cap)
-    row, v, new_path, new_blocked = _extension_rows(g, f, cand_v)
-    out = scatter_frontier(dest, new_path, new_blocked,
-                           f.v1[row], f.l2[row], v,
-                           jnp.minimum(total, out_cap), out_cap)
-    return out, jnp.maximum(total - out_cap, 0)
-
-
 # ---------------------------------------------------------------------------
-# Gather-based compaction (fused round, DESIGN.md §6.8)
+# Split-path frontier compaction (DESIGN.md §6.8)
 #
-# The scatter path above materializes every (path, slot) pair — cap·Δ rows of
-# nw words — before compacting them down to ≤cap survivors. The gather
-# formulation inverts the data flow: each OUTPUT slot locates its source row
-# by merging the sorted output slots with the sorted inclusive prefix of
-# per-row survivor counts — a histogram of the prefix over the slots and two
-# prefix scans, no binary search (O(cap + out_cap), not O(cap·Δ)) — and
-# rebuilds exactly its own row, so the round's frontier traffic drops from
-# O(cap·Δ·nw) to O(cap·nw) — the XLA realization of the two-phase-scatter
-# destination computation the fused pallas kernel performs on device.
-# Output order is bit-identical to the scatter path: survivors land in
-# row-major (row, slot) order, slots in ascending-vertex order for bitword.
+# Each OUTPUT slot locates its source row by merging the sorted output slots
+# with the sorted inclusive prefix of per-row survivor counts — a histogram
+# of the prefix over the slots and two prefix scans, no binary search
+# (O(cap + out_cap)) — and rebuilds exactly its own row, so no cap·Δ rows of
+# nw words are ever materialized: the round's frontier traffic is O(cap·nw),
+# the XLA realization of the two-phase-scatter destination computation the
+# fused pallas kernel performs on device. Survivors land in row-major
+# (row, slot) order, slots in ascending-vertex order for bitword — the order
+# the fused kernel writes.
 # ---------------------------------------------------------------------------
 
 def _source_rows(counts: jnp.ndarray, out_cap: int):
@@ -231,8 +199,8 @@ def _select_kth_bit(words: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
 
 def _gathered_frontier(g: BitsetGraph, f: Frontier, src: jnp.ndarray,
                        v: jnp.ndarray, valid: jnp.ndarray, total, out_cap):
-    """Build the compacted frontier from gathered (src, v) pairs — dead
-    output rows match ``scatter_frontier``'s zero-init exactly."""
+    """Build the compacted frontier from gathered (src, v) pairs; dead
+    output rows are zero, with ``v1 = -1``."""
     nw = f.n_words
     vi = jnp.clip(v, 0, None)
     upd = jnp.where(jnp.arange(nw)[None, :] == (vi // 32)[:, None],
@@ -267,8 +235,8 @@ def compact_extensions_gather(g: BitsetGraph, f: Frontier,
                               cand_v: jnp.ndarray, is_ext: jnp.ndarray,
                               out_cap: int):
     """Slot-formulation twin of ``bitword_compact_gather``: each output slot
-    selects the k-th flagged slot of its source row (slot order preserved —
-    bit-identical to the scatter path). Returns (new_frontier, n_dropped)."""
+    selects the k-th flagged slot of its source row (slot order preserved).
+    Returns (new_frontier, n_dropped)."""
     src, k, valid, total = _source_rows(
         is_ext.sum(axis=1, dtype=jnp.int32), out_cap)
     flags_src = is_ext[src].astype(jnp.int32)                 # (out_cap, Δ)
@@ -284,24 +252,6 @@ def count_ext_and_cycles(is_cycle: jnp.ndarray, is_ext: jnp.ndarray):
     return (is_ext.sum(dtype=jnp.int32), is_cycle.sum(dtype=jnp.int32))
 
 
-@jax.jit
-def bitword_flags_count(g: BitsetGraph, f: Frontier):
-    """Count-only round, part 1 (§Perf engine hillclimb): candidate words +
-    POPCOUNT cycle/extension counts — no slot extraction for cycles, one
-    host sync for exact output sizing."""
-    close_w, ext_w = expand_words_bitword(g, f)
-    return ext_w, popcount(close_w).sum(), popcount(ext_w).sum()
-
-
-@partial(jax.jit, static_argnames=("delta", "out_cap"))
-def bitword_compact(g: BitsetGraph, f: Frontier, ext_w: jnp.ndarray,
-                    delta: int, out_cap: int):
-    """Count-only round, part 2: extract extension slots + compact."""
-    cand_v = bitword_to_slots(ext_w, delta)
-    is_ext = cand_v >= 0
-    return compact_extensions(g, f, cand_v, is_ext, out_cap)
-
-
 def _cycle_rows(f: Frontier, cand_v: jnp.ndarray):
     """Cycle bitmaps for every (path, slot) pair: path | bit(v), flat."""
     cap, delta = cand_v.shape
@@ -312,18 +262,6 @@ def _cycle_rows(f: Frontier, cand_v: jnp.ndarray):
                     (jnp.uint32(1) << (v % 32).astype(jnp.uint32))[:, None],
                     jnp.uint32(0))
     return f.path[row] | upd
-
-
-@partial(jax.jit, static_argnames=("out_cap",))
-def gather_cycles(f: Frontier, cand_v: jnp.ndarray, is_cycle: jnp.ndarray,
-                  out_cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Materialize closed cycles as bitmaps (out_cap, nw): path | bit(v)."""
-    flat = is_cycle.reshape(-1)
-    dest, total = compaction_dests(flat, out_cap)
-    rows = _cycle_rows(f, cand_v)
-    nw = f.n_words
-    out = jnp.zeros((out_cap, nw), jnp.uint32).at[dest].set(rows, mode="drop")
-    return out, jnp.minimum(total, out_cap)
 
 
 def gather_cycles_into(f: Frontier, cand_v: jnp.ndarray,
@@ -345,8 +283,8 @@ def gather_cycles_into(f: Frontier, cand_v: jnp.ndarray,
 
 class ExpandOp:
     """One (formulation × backend) implementation of a stage-2 expansion
-    round — the single interface the whole stack (wave superstep, legacy
-    host engine, sharded ``core/distributed`` step) programs against.
+    round — the single interface the whole stack (wave superstep, sharded
+    ``core/distributed`` step) programs against.
 
     Contract: every method is BATCH-TRANSPARENT — it traces identically
     whether the operands are single-graph ((cap, nw) frontier leaves,
@@ -361,19 +299,19 @@ class ExpandOp:
       the ``repro.round.flags`` scope; ``flags`` is formulation-specific
       (slot: ``(cand_v, is_cyc, is_ext)`` per (path, slot); bitword:
       ``(close_words, ext_words)``).
-    * ``compact(g, f, flags, delta, cap, fused)`` → ``(f', n_dropped)``:
-      the round's extensions compacted into a fresh frontier of ``cap``
-      rows, under the ``repro.round.compact`` scope. ``fused`` selects the
-      one-pass gather compaction (DESIGN.md §6.8): O(cap·nw) frontier
-      traffic per round instead of the cap·Δ scatter. Bit-identical rows
-      either way; rows past ``cap`` are dropped and counted. The sharded
-      step calls it at its fixed per-device capacity.
-    * ``apply(g, f, buf, flags, delta, store, fused)`` → ``(f', buf')``:
-      gather this round's cycles into the ring (with ``store``) and
-      ``compact`` at the frontier's own capacity — the T → T' update.
+    * ``compact(g, f, flags, delta, cap)`` → ``(f', n_dropped)``: the
+      round's extensions compacted into a fresh frontier of ``cap`` rows by
+      the gather compaction (DESIGN.md §6.8, O(cap·nw) frontier traffic),
+      under the ``repro.round.compact`` scope; rows past ``cap`` are
+      dropped and counted. The sharded step calls it at its per-device
+      bucket.
+    * ``apply(g, f, buf, flags, delta, store)`` → ``(f', buf')``: gather
+      this round's cycles into the ring (with ``store``) and ``compact`` at
+      the frontier's own capacity — the T → T' update.
     * ``fused_kernel`` (pallas ops only): the whole guarded round — flags,
       counts, cycle append, compaction — collapses into ONE pallas
-      dispatch (``expand_count_compact`` routes there under ``fused``).
+      dispatch (``expand_count_compact`` routes there wherever the bucket
+      fits the kernel's VMEM budget).
     """
     formulation: str
     backend: str
@@ -386,15 +324,15 @@ class ExpandOp:
             return self._flags(g, f, delta)
 
     def compact(self, g: BitsetGraph, f: Frontier, flags, delta: int,
-                cap: int, fused: bool):
+                cap: int):
         with jax.named_scope("repro.round.compact"):
-            return self._compact(g, f, flags, delta, cap, fused)
+            return self._compact(g, f, flags, delta, cap)
 
     def _flags(self, g: BitsetGraph, f: Frontier, delta: int):
         raise NotImplementedError
 
     def _compact(self, g: BitsetGraph, f: Frontier, flags, delta: int,
-                 cap: int, fused: bool):
+                 cap: int):
         raise NotImplementedError
 
     def append_cycles(self, f: Frontier, flags, delta: int,
@@ -402,11 +340,11 @@ class ExpandOp:
         raise NotImplementedError
 
     def apply(self, g: BitsetGraph, f: Frontier, buf: CycleBuffer, flags,
-              delta: int, store: bool, fused: bool = False):
+              delta: int, store: bool):
         if store:
             with jax.named_scope("repro.round.cycles"):
                 buf = self.append_cycles(f, flags, delta, buf)
-        f2, _ = self.compact(g, f, flags, delta, f.capacity, fused)
+        f2, _ = self.compact(g, f, flags, delta, f.capacity)
         return f2, buf
 
     def fused_round(self, g: BitsetGraph, f: Frontier, buf: CycleBuffer,
@@ -429,11 +367,9 @@ class ExpandOp:
 class _SlotApply:
     """Shared slot-formulation compaction and cycle append."""
 
-    def _compact(self, g, f, flags, delta, cap, fused):
+    def _compact(self, g, f, flags, delta, cap):
         cand_v, _, is_ext = flags
-        if fused:
-            return compact_extensions_gather(g, f, cand_v, is_ext, cap)
-        return compact_extensions(g, f, cand_v, is_ext, cap)
+        return compact_extensions_gather(g, f, cand_v, is_ext, cap)
 
     def append_cycles(self, f, flags, delta, buf):
         cand_v, is_cyc, _ = flags
@@ -443,14 +379,10 @@ class _SlotApply:
 class _BitwordApply:
     """Shared bitword-formulation compaction and cycle append."""
 
-    def _compact(self, g, f, flags, delta, cap, fused):
-        ext_w = flags[1]
-        if fused:
-            # straight from the candidate words — no Δ-round slot
-            # extraction, no cap·Δ row materialization (DESIGN.md §6.8)
-            return bitword_compact_gather(g, f, ext_w, cap)
-        cand_v = bitword_to_slots(ext_w, delta)
-        return compact_extensions(g, f, cand_v, cand_v >= 0, cap)
+    def _compact(self, g, f, flags, delta, cap):
+        # straight from the candidate words — no Δ-round slot extraction,
+        # no cap·Δ row materialization (DESIGN.md §6.8)
+        return bitword_compact_gather(g, f, flags[1], cap)
 
     def append_cycles(self, f, flags, delta, buf):
         ccand = bitword_to_slots(flags[0], delta)
@@ -580,7 +512,7 @@ def fused_kernel_fits(formulation: str, g: BitsetGraph, f: Frontier,
 def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
                          delta: int, store: bool,
                          formulation: str = "slot", backend: str = "jnp",
-                         op: ExpandOp | None = None, fused: bool = False):
+                         op: ExpandOp | None = None):
     """One fused, guarded expansion round — the wave superstep's loop body.
 
     Combines an ``ExpandOp``'s flag computation and application into a
@@ -591,19 +523,17 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
     and escalates to the host (bucket transition).  ``op`` defaults to the
     registered ``expand_op(formulation, backend)``.
 
-    ``fused`` selects the one-pass round (DESIGN.md §6.8): pallas ops with
-    a fused kernel collapse the whole guarded round into ONE device
-    dispatch (two-phase scatter, guard evaluated in kernel) while the
-    bucket fits the kernel's VMEM budget (``fused_kernel_fits``), and take
-    the split path past it; the split path swaps the scatter compaction
-    for the gather formulation (one frontier pass instead of two). Output
-    is bit-identical either way.
+    Pallas ops with a fused kernel collapse the whole guarded round into
+    ONE device dispatch (two-phase scatter, guard evaluated in kernel)
+    while the bucket fits the kernel's VMEM budget (``fused_kernel_fits``,
+    DESIGN.md §6.8); every other round takes the split path: the op's
+    flags, then its gather compaction. Output is bit-identical either way.
 
     Returns (f2, buf2, n_cyc, n_new, ok_frontier, ok_cycles).
     """
     if op is None:
         op = expand_op(formulation, backend)
-    if fused and op.fused_kernel and fused_kernel_fits(
+    if op.fused_kernel and fused_kernel_fits(
             op.formulation, g, f, buf, store=store, persistent=False):
         _took("fused")
         with jax.named_scope("repro.round.fused"):
@@ -619,7 +549,7 @@ def expand_count_compact(g: BitsetGraph, f: Frontier, buf: CycleBuffer, *,
 
     f2, buf2 = jax.lax.cond(
         ok,
-        lambda _: op.apply(g, f, buf, flags, delta, store, fused),
+        lambda _: op.apply(g, f, buf, flags, delta, store),
         lambda _: (f, buf),
         None)
     return f2, buf2, n_cyc, n_new, ok_frontier, ok_cycles
@@ -629,20 +559,18 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
                                buf: CycleBuffer, *, delta: int, store: bool,
                                rounds: int, formulation: str = "slot",
                                backend: str = "jnp",
-                               op: ExpandOp | None = None,
-                               fused: bool = False, rlimit=None):
+                               op: ExpandOp | None = None, rlimit=None):
     """Up to ``rounds`` complete guarded expansion rounds as ONE traced
     unit — the persistent superstep's loop body (DESIGN.md §6.11).
 
-    On pallas ops with a fused kernel (``fused=True``) whose bucket fits
-    the VMEM budget this is the
-    persistent wave kernel: one ``pallas_call`` with a leading round axis
-    whose scratch carries the frontier between rounds, so HBM sees one
-    frontier read + one write per LAUNCH instead of per round. Every other
-    path runs the bit-identical jnp twin: a ``lax.fori_loop`` over
-    ``expand_count_compact`` (which itself resolves gather compaction /
-    the single-round kernel per op), with the round-application rules the
-    kernel applies in SMEM mirrored in carried scalars.
+    On pallas ops with a fused kernel whose bucket fits the VMEM budget
+    this is the persistent wave kernel: one ``pallas_call`` with a leading
+    round axis whose scratch carries the frontier between rounds, so HBM
+    sees one frontier read + one write per LAUNCH instead of per round.
+    Every other path runs the bit-identical jnp twin: a ``lax.fori_loop``
+    over ``expand_count_compact`` (which itself picks the single-round
+    kernel or the split path per op and bucket), with the round-application
+    rules the kernel applies in SMEM mirrored in carried scalars.
 
     ``rlimit`` (dynamic, defaults to ``rounds``) bounds how many rounds may
     be APPLIED — the superstep passes its remaining budget so a static-R
@@ -661,7 +589,7 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
     rounds = int(rounds)
     if rlimit is None:
         rlimit = jnp.int32(rounds)
-    if fused and op.fused_kernel and fused_kernel_fits(
+    if op.fused_kernel and fused_kernel_fits(
             op.formulation, g, f, buf, store=store, persistent=True):
         _took("fused")
         with jax.named_scope("repro.round.fused"):
@@ -673,7 +601,7 @@ def expand_count_compact_multi(g: BitsetGraph, f: Frontier,
     def body(r, carry):
         f, buf, ch, nh, done, alive, okf, okc = carry
         f2, buf2, n_cyc, n_new, okf_r, okc_r = expand_count_compact(
-            g, f, buf, delta=delta, store=store, op=op, fused=fused)
+            g, f, buf, delta=delta, store=store, op=op)
         alive = alive & (done < rlimit)
         okr = okf_r & okc_r
         applied = alive & okr

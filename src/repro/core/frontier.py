@@ -7,14 +7,10 @@ B_p = ∪_{i=2..t-1} Adj(v_i) (DESIGN.md §2) that turns the paper's O(t·logΔ)
 chord re-check into one word probe. We store ℓ(v₂) directly instead of v₂
 since only the label is ever used.
 
-Two kinds of capacity change exist (DESIGN.md §6.4):
-
-* ``with_capacity`` — HOST-side bucketing: pads/trims to a new power-of-two
-  bucket between jit shapes.  Only legal at superstep boundaries.
-* ``scatter_frontier`` — DEVICE-side functional update at *fixed* capacity:
-  builds the next frontier from gathered rows + cumsum destinations without
-  any host round-trip.  This is what the fused wave engine loops over inside
-  ``lax.while_loop``.
+Capacity changes (DESIGN.md §6.4) happen only between jit shapes:
+``with_capacity`` pads/trims to a new power-of-two bucket at a superstep
+boundary. Inside a superstep each round rebuilds the frontier at the same
+capacity on device (``core.expand``'s compaction or the fused kernel).
 """
 from __future__ import annotations
 
@@ -112,29 +108,6 @@ def with_capacity_batched(f: Frontier, capacity: int) -> Frontier:
         v1=f.v1[:, :capacity], l2=f.l2[:, :capacity],
         vlast=f.vlast[:, :capacity],
         count=jnp.minimum(f.count, capacity).astype(jnp.int32),
-    )
-
-
-def scatter_frontier(dest: jnp.ndarray, path_rows: jnp.ndarray,
-                     blocked_rows: jnp.ndarray, v1: jnp.ndarray,
-                     l2: jnp.ndarray, vlast: jnp.ndarray,
-                     count: jnp.ndarray, out_cap: int) -> Frontier:
-    """Build a fresh frontier of static capacity ``out_cap`` by scattering
-    row i of each field to ``dest[i]`` (entries ≥ out_cap are dropped).
-
-    Pure device op — the wave engine's in-bucket T → T' update.
-    """
-    nw = path_rows.shape[-1]
-    return Frontier(
-        path=jnp.zeros((out_cap, nw), jnp.uint32)
-            .at[dest].set(path_rows, mode="drop"),
-        blocked=jnp.zeros((out_cap, nw), jnp.uint32)
-            .at[dest].set(blocked_rows, mode="drop"),
-        v1=jnp.full((out_cap,), -1, jnp.int32).at[dest].set(v1, mode="drop"),
-        l2=jnp.zeros((out_cap,), jnp.int32).at[dest].set(l2, mode="drop"),
-        vlast=jnp.zeros((out_cap,), jnp.int32)
-            .at[dest].set(vlast, mode="drop"),
-        count=count.astype(jnp.int32),
     )
 
 

@@ -73,9 +73,6 @@ class PlanKey:
     k_max: int               # superstep round budget K
     batch: int = 0
     donate: bool = True      # buffer-donation is part of program identity
-    fused: bool = False      # one-pass fused round (DESIGN.md §6.8) — the
-    # round body's program differs, so fused and split supersteps compile
-    # (and cache) separately
     rpl: int = 1             # rounds_per_launch R (DESIGN.md §6.11): the
     # persistent multi-round body is a different traced program per R, so
     # it is part of program identity
@@ -104,8 +101,7 @@ class WavePlan:
 
         statics = dict(delta=key.delta, store=key.store,
                        formulation=key.formulation, backend=key.backend,
-                       k_max=key.k_max, fused=key.fused,
-                       rounds_per_launch=key.rpl)
+                       k_max=key.k_max, rounds_per_launch=key.rpl)
 
         def _traced(g, f, buf, rounds_limit):
             # runs once per TRACE (not per call): the retrace observer

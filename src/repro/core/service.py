@@ -19,9 +19,8 @@ graph batch, or stream — is a cheap *execute* against that cache:
 ``cfg.mesh`` non-None routes the request through the sharded wave
 superstep in ``core/distributed.py`` — the same ProgramCache warms its
 deal + superstep programs (``PlanKey(kind='dist')``) and the same tuner
-resolves its knobs; ``cfg.engine == 'host'`` routes to the legacy
-per-round A/B engine. ``enumerate_chordless_cycles`` is a thin wrapper
-over the module-level ``default_service()``.
+resolves its knobs. ``enumerate_chordless_cycles`` is a thin wrapper over
+the module-level ``default_service()``.
 
 ``CycleService(auto_tune=True)`` additionally resolves every request's
 config through ``repro.tune`` (DESIGN.md §6.6): first visit of a workload
@@ -43,7 +42,7 @@ import jax.numpy as jnp
 from .bitset_graph import BitsetGraph
 from . import triplets as T
 from .engine import (STATUS_NAMES, EngineConfig, EnumerationResult, _DONE,
-                     _DRAIN, _GROW, _RUN, _SHRINK, _enumerate_host)
+                     _DRAIN, _GROW, _RUN, _SHRINK)
 from .frontier import (empty_cycle_buffer, empty_frontier, with_capacity,
                        with_capacity_batched)
 from .plan import (PlanKey, ProgramCache, RecyclePlan, WavePlan,
@@ -167,17 +166,12 @@ class CycleService:
         fed to the tuner afterwards. Mesh-sharded configs resolve like
         single-device ones, against the sharded knob set
         (``superstep_rounds`` × ``local_capacity`` × ``balance_every``,
-        keyed by device count — ``tune.DIST_TUNED_KNOBS``). Two kinds of
-        request pass through untouched: ``explicit`` per-request configs
-        (the caller pinned the knobs — e.g. a memory-bounding
-        ``cycle_buffer_rows`` — and a stored entry keyed only by workload
-        class must not override them) and ``engine='host'`` requests (the
-        cost model's replay twins the WAVE drivers, so its ranking is
-        meaningless for the per-round host loop — tuning it untried could
-        slow it down).
+        keyed by device count — ``tune.DIST_TUNED_KNOBS``). ``explicit``
+        per-request configs pass through untouched: the caller pinned the
+        knobs (e.g. a memory-bounding ``cycle_buffer_rows``), and a stored
+        entry keyed only by workload class must not override them.
         """
-        if (self._tuner is None or explicit
-                or (cfg.mesh is None and cfg.engine != "wave")):
+        if self._tuner is None or explicit:
             return cfg, None, False
         key = self._tuner.key_for(n, m, delta, cfg, batch=batch)
         tuned = self._tuner.lookup(key, cfg)
@@ -245,8 +239,8 @@ class CycleService:
                       delta=delta, store=cfg.store,
                       formulation=cfg.formulation, backend=cfg.backend,
                       k_max=cfg.superstep_rounds, batch=batch,
-                      donate=cfg.donate, fused=cfg.fused_round,
-                      rpl=cfg.rounds_per_launch, extra=(g_n, g_m))
+                      donate=cfg.donate, rpl=cfg.rounds_per_launch,
+                      extra=(g_n, g_m))
         return self._cache.get_or_build(key, lambda: WavePlan(key))
 
     def _recycle_plan(self, g_n: int, g_m: int, cap: int, cyc_cap: int,
@@ -260,7 +254,7 @@ class CycleService:
                       delta=delta, store=cfg.store,
                       formulation=cfg.formulation, backend=cfg.backend,
                       k_max=0, batch=batch, donate=cfg.donate,
-                      fused=cfg.fused_round, extra=(g_n, g_m))
+                      extra=(g_n, g_m))
         return self._cache.get_or_build(key, lambda: RecyclePlan(key))
 
     def plan(self, g: BitsetGraph, *, config: EngineConfig | None = None
@@ -272,14 +266,11 @@ class CycleService:
         immediately) so trace + compile happen NOW, not on the first
         request. Later buckets of the wave compile lazily as reached."""
         cfg = config if config is not None else self.cfg
-        if cfg.mesh is not None or cfg.engine != "wave":
-            # neither path executes a wave superstep: the sharded step is
-            # built (and cached) on first enumerate; the host engine has
-            # no single compiled program to plan.
+        if cfg.mesh is not None:
+            # the sharded step is built (and cached) on first enumerate
             raise ValueError(
                 "plan() supports the single-device wave path only "
-                "(mesh=None, engine='wave'); the sharded step compiles on "
-                "first enumerate, the host engine has no plan")
+                "(mesh=None); the sharded step compiles on first enumerate")
         nw = g.adj_bits.shape[1]
         delta = max(g.max_degree, 1)
         frontier, _, _ = T.initial_frontier_device(
@@ -310,15 +301,12 @@ class CycleService:
                 g.n, g.m, max(g.max_degree, 1), cfg,
                 explicit=config is not None)
             trace = self._new_trace(observe)
-            wave = cfg.mesh is None and cfg.engine != "host"
             if cfg.mesh is not None:
                 from .distributed import enumerate_sharded
                 res = enumerate_sharded(g, cfg, cache=self._cache,
                                         trace=trace, progress=progress,
                                         metrics=self.metrics,
                                         spans=self.spans, rid=rid)
-            elif not wave:
-                res = _enumerate_host(g, cfg, progress, trace=trace)
             else:
                 gen = self._wave_events(g, cfg, progress, trace, rid=rid)
                 chunks: list[np.ndarray] = []
@@ -337,8 +325,7 @@ class CycleService:
             self._after_run(g, cfg, tkey, observe, trace, res)
         # the wave and sharded drivers' host phases already recorded their
         # dispatches
-        self._request_spans(rid, t_req, trace,
-                            dispatches=not wave and cfg.mesh is None)
+        self._request_spans(rid, t_req, trace, dispatches=False)
         return res
 
     def stream(self, g: BitsetGraph, *,
@@ -364,9 +351,6 @@ class CycleService:
         if not cfg.store:
             raise ValueError("stream() requires store=True (count-only "
                              "results have no masks to stream)")
-        if cfg.engine != "wave":
-            raise ValueError("stream() requires engine='wave' (the host "
-                             "engine has no device-resident cycle buffer)")
         self._m["requests"].inc()
         self._m["graphs"].inc()
         self._m["streams"].inc()
@@ -531,8 +515,8 @@ class CycleService:
         Batch is a first-class axis on EVERY backend (DESIGN.md §6.7): the
         pallas kernels run on a lane grid under the same vmapped plan, so
         there is no per-graph fallback; stage 1 seeds all lanes device-side
-        in one dispatch (``T.initial_frontier_batched``). Only the legacy
-        host engine (the per-round A/B baseline) loops per graph."""
+        in one dispatch (``T.initial_frontier_batched``). A batch of one
+        graph runs as a plain ``enumerate``."""
         cfg = config if config is not None else self.cfg
         if cfg.mesh is not None:
             raise NotImplementedError(
@@ -545,7 +529,7 @@ class CycleService:
         graphs = list(graphs)
         if not graphs:
             return []
-        if len(graphs) == 1 or cfg.engine == "host":
+        if len(graphs) == 1:
             return [self.enumerate(g, config=cfg) for g in graphs]
 
         self._m["requests"].inc()
@@ -814,6 +798,6 @@ def default_service() -> CycleService:
 
 
 def reset_default_service() -> None:
-    """Drop the shared session (tests / benchmarks that need a cold path)."""
+    """Drop the shared session (for tests that need a cold path)."""
     global _DEFAULT
     _DEFAULT = None

@@ -8,8 +8,8 @@ paper's atomic append into C / T(G) becomes deterministic stream compaction.
 
 Two compaction paths (DESIGN.md §2, §6.7):
 
-* ``initial_frontier``        — legacy host nonzero (kept as the A/B
-                                baseline the host engine drives).
+* ``initial_frontier``        — host nonzero: the plain reference seed
+                                the device paths are tested against.
 * ``initial_frontier_device`` — device-side: the triplet-flags →
                                 cumsum-scatter deal PR 4 built for the
                                 sharded path (``core/distributed``),

@@ -232,7 +232,7 @@ def _frontier_writer(refs, lead, path, v1, l2):
 
 
 def _clear_rows(refs, lead, rows, tp: int, nw: int):
-    """Dead-row init of a frontier region (``scatter_frontier``'s zeros)."""
+    """Dead-row init of a frontier region: zeros, with ``v1 = -1``."""
     opath, oblocked, ov1, ol2, ovlast = refs
     opath[lead, rows, :] = jnp.zeros((tp, nw), jnp.uint32)
     oblocked[lead, rows, :] = jnp.zeros((tp, nw), jnp.uint32)

@@ -147,18 +147,9 @@ def bitword_fused_counts(g: BitsetGraph, f: Frontier):
     """Fused mask algebra + per-row popcounts in ONE kernel pass
     (DESIGN.md §6.4). Returns (close_words, ext_words, n_cyc, n_new).
     The scalar reductions ride the same traced unit as the kernel when the
-    caller jits (the wave superstep and ``bitword_flags_count`` both do)."""
+    caller jits (the wave and sharded supersteps do)."""
     close, ext, ncyc, next_ = _bitword_rows(g, f)
     return close, ext, ncyc.sum(), next_.sum()
-
-
-@jax.jit
-def bitword_flags_count(g: BitsetGraph, f: Frontier):
-    """Drop-in for core.expand.bitword_flags_count, but the popcounts ride
-    the expansion kernel instead of a second HBM pass. Jitted so the scalar
-    .sum() reductions fuse into the same dispatch (legacy host engine)."""
-    _, ext, n_cyc, n_new = bitword_fused_counts(g, f)
-    return ext, n_cyc, n_new
 
 
 # ---------------------------------------------------------------------------

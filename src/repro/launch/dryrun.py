@@ -11,7 +11,7 @@ production meshes and extract the roofline terms.
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-0.5b \
         --shape train_4k --rules '{"seq": ["model"]}'
 
-Results append to benchmarks/results/dryrun_<mesh>.json (one row per cell:
+Results append to dryrun_results/dryrun_<mesh>.json (one row per cell:
 memory_analysis, cost_analysis, collective bytes, roofline terms).
 """
 import argparse
@@ -229,7 +229,7 @@ def main():
                     help="store serve params in bf16 (halves weight-gather "
                          "traffic at decode)")
     ap.add_argument("--tag", default="")
-    ap.add_argument("--out", default="benchmarks/results")
+    ap.add_argument("--out", default="dryrun_results")
     args = ap.parse_args()
 
     rules = None

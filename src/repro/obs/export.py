@@ -27,8 +27,8 @@ at ``ts`` µs sits at ``origin_unix_ns + 1e3·ts - profile_start_time`` ns
 of the trace.
 
 ``validate_perfetto`` is the schema gate (required keys, per-track
-monotonic timestamps, span nesting) that ``benchmarks/run.py --check``
-fails on, so the export can't silently rot.
+monotonic timestamps, span nesting) the tests hold every export to, so
+the export can't silently rot.
 
 ``FlightRecorder`` is the always-on anomaly net: a bounded ring of recent
 TraceEvents (attached to ``WaveTrace`` as an observer, so it sees events
@@ -49,7 +49,7 @@ _PROCESS_NAMES = {PID_LANES: "lanes", PID_REQUESTS: "requests",
 
 # dispatch kinds that advance frontiers on lane tracks vs boundary kinds
 # that live on the engine track
-_LANE_KINDS = ("superstep", "batch", "round", "dist")
+_LANE_KINDS = ("superstep", "batch", "dist")
 _ENGINE_KINDS = ("seed", "recycle", "deal")
 
 TRACE_SCHEMA = "repro.obs/perfetto/v1"

@@ -84,11 +84,11 @@ class ContinuousScheduler:
         self.service = service
         self._explicit_cfg = config is not None
         self.cfg_base = config if config is not None else service.cfg
-        if self.cfg_base.mesh is not None or self.cfg_base.engine != "wave":
+        if self.cfg_base.mesh is not None:
             raise ValueError(
                 "lane recycling requires the single-device wave path "
-                "(mesh=None, engine='wave'): the pool IS one batched wave "
-                "program's lane axis")
+                "(mesh=None): the pool IS one batched wave program's lane "
+                "axis")
         self.slots = slots
         self.pool: LanePool | None = None
         self.stats = dict(

@@ -1,7 +1,7 @@
 """Traffic generators + latency summaries for sustained-serving scenarios.
 
-The sustained-traffic benchmark (DESIGN.md §6.9, ``benchmarks/serve_bench``)
-needs two ingredients the wave engine's one-shot benchmarks never model:
+Sustained-serving scenarios (DESIGN.md §6.9) need two ingredients the wave
+engine's one-shot runs never model:
 
 * an OPEN-LOOP arrival process — requests arrive on their own clock
   (Poisson at a fixed QPS), not when the previous one finishes, so queue

@@ -21,8 +21,7 @@ sharded twin's feasibility guard keeps capacity candidates that could drop
 rows out of the running.
 
 The base config is always one of the candidates, so with measured trials
-the tuner can never pick a knob set that measured WORSE than the default —
-the invariant ``benchmarks/engine_bench.py::tune_smoke`` asserts.
+the tuner can never pick a knob set that measured WORSE than the default.
 """
 from __future__ import annotations
 
@@ -32,13 +31,9 @@ import itertools
 from .cost_model import CostModel, DistProfile, WaveProfile
 from .store import TuneKey, TuneStore, _p2, shape_class
 
-# the shape-dependent, equivalence-preserving knobs the tuner may touch.
-# fused_round is equivalence-preserving by construction (the one-pass round
-# is bit-identical to the split round, tested in tests/test_fused_round.py)
-# but not always faster: tiny buckets can favor the split path's simpler
-# programs, so it is a searched axis, not a constant.
+# the shape-dependent, equivalence-preserving knobs the tuner may touch
 TUNED_KNOBS = ("superstep_rounds", "growth_bits", "grow_headroom",
-               "cycle_buffer_rows", "fused_round", "rounds_per_launch")
+               "cycle_buffer_rows", "rounds_per_launch")
 # the mesh-routed (sharded) knob set: round budget per superstep, frontier
 # rows per device, and the diffusion-balance cadence. local_capacity is
 # equivalence-preserving only while nothing overflows — the replay twin's
@@ -81,7 +76,6 @@ class TuneSpace:
     growth_bits: tuple = (1, 2)
     grow_headroom: tuple = (0, 1, 2)
     cycle_buffer_rows: tuple = (1024, 4096, 16384)
-    fused_round: tuple = (True, False)
     # sharded axes
     local_capacity: tuple = (1 << 12, 1 << 14, 1 << 16)
     balance_every: tuple = (1, 2, 4)
@@ -106,7 +100,6 @@ class TuneSpace:
             axes = dict(superstep_rounds=self.superstep_rounds,
                         growth_bits=self.growth_bits,
                         grow_headroom=self.grow_headroom,
-                        fused_round=self.fused_round,
                         rounds_per_launch=self.rounds_per_launch)
             if base_cfg.store:
                 axes["cycle_buffer_rows"] = self.cycle_buffer_rows
@@ -179,7 +172,7 @@ class AutoTuner:
             if mesh is not None else 0
         return TuneKey(shape=shape_class(n, m, delta), store=cfg.store,
                        formulation=cfg.formulation, backend=cfg.backend,
-                       engine="dist" if ndev else cfg.engine,
+                       engine="dist" if ndev else "wave",
                        device_kind=self.device_kind, ndev=ndev,
                        batch=_p2(batch) if batch else 0, nhost=nhost)
 
@@ -217,8 +210,10 @@ class AutoTuner:
     def apply(knobs: dict, cfg):
         """Overlay tuned knobs on a base config (only TUNED_KNOBS /
         DIST_TUNED_KNOBS; every correctness-relevant field of ``cfg`` is
-        preserved verbatim). A stored ``local_capacity`` below THIS base
-        config's ``balance_block`` is dropped rather than applied —
+        preserved verbatim, and knobs that no longer exist, such as
+        ``fused_round`` in older stored entries, are ignored). A stored
+        ``local_capacity`` below THIS base config's ``balance_block`` is
+        dropped rather than applied —
         ``TuneKey`` does not carry ``balance_block``, so an entry tuned
         under a smaller block must not make a warm lookup raise (or
         shrink) on a base config with a bigger one."""

@@ -20,8 +20,9 @@ host-side computation:
                     plus the observed per-device peaks and balance cadence
                     of a ``core.distributed`` run. The dispatch/sync/round
                     chop is exact (the busiest device's per-round rows,
-                    recorded in the trace, drive the shared bucket policy
-                    ``dist_next_bucket``); per-device placement under a
+                    recorded in the trace, drive the driver's own bucket
+                    policy ``core.distributed.dist_next_bucket``);
+                    per-device placement under a
                     DIFFERENT ``balance_every`` / ``local_capacity`` is not
                     replayable without re-running the diffusion, so the
                     twin carries a conservative *feasibility guard*: a
@@ -217,10 +218,6 @@ def replay(profile: WaveProfile, cfg, *, recycle: bool = False
     limit = profile.limit
     t, c = profile.t_sizes, profile.c_counts
     nw = max(profile.nw, 1)
-    # one frontier pass per attempted round when the round is fused
-    # (DESIGN.md §6.8: flags + compaction share a single sweep); the split
-    # round reads the frontier once to flag and once more to scatter
-    passes = 1 if getattr(cfg, "fused_round", True) else 2
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
     cnt = profile.n0
     cap = cfg.bucket(max(cnt, 1))
@@ -251,8 +248,8 @@ def replay(profile: WaveProfile, cfg, *, recycle: bool = False
             n_new, n_cyc = t[it + r], c[it + r]
             ok_f = n_new <= cap
             ok_c = (fill + n_cyc <= cyc_cap) if cfg.store else True
-            row_work += passes * cap * nw
-            waste += passes * max(cap - max(cnt, 1), 0) * nw
+            row_work += cap * nw
+            waste += max(cap - max(cnt, 1), 0) * nw
             if not (ok_f and ok_c):
                 status = _DRAIN if ok_f else _GROW
                 pn, pc = n_new, n_cyc
@@ -276,8 +273,8 @@ def replay(profile: WaveProfile, cfg, *, recycle: bool = False
         n_launches = -(-att // rpl)
         launches += n_launches
         idle = n_launches * rpl - att
-        row_work += idle * passes * cap * nw
-        waste += idle * passes * cap * nw
+        row_work += idle * cap * nw
+        waste += idle * cap * nw
         dispatches += 1
         syncs += 1
         by_cause[status] = by_cause.get(status, 0) + 1
@@ -363,7 +360,6 @@ def _replay_batch(profile: WaveProfile, cfg, *,
     B = profile.lanes
     t, c = profile.lane_t, profile.lane_c
     nw = max(profile.nw, 1)
-    passes = 1 if getattr(cfg, "fused_round", True) else 2
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
     limits = []
     for ln in profile.lane_n:
@@ -434,19 +430,19 @@ def _replay_batch(profile: WaveProfile, cfg, *,
         n_launches = -(-max_att // rpl)
         launches += n_launches
         idle = n_launches * rpl - max_att
-        row_work += idle * passes * B * cap * nw
-        waste += idle * passes * B * cap * nw
+        row_work += idle * B * cap * nw
+        waste += idle * B * cap * nw
         for j in range(max_att):
             lanes_j = ([i for i in range(B) if j < attempts[i]]
                        if recycle else list(range(B)))
-            row_work += passes * len(lanes_j) * cap * nw
+            row_work += len(lanes_j) * cap * nw
             for i in lanes_j:
                 enter = enters[i] if j == 0 else (
                     t[i][its[i] - rs[i] + j - 1]
                     if its[i] - rs[i] + j - 1 < len(t[i]) and j <= attempts[i]
                     else 0)
                 live = enter if j < attempts[i] else 0
-                waste += passes * max(cap - max(live, 1), 0) * nw
+                waste += max(cap - max(live, 1), 0) * nw
 
         drain_lanes = [i for i in range(B) if statuses[i] == _DRAIN]
         grow_lanes = [i for i in range(B) if statuses[i] == _GROW]
@@ -511,7 +507,6 @@ def replay_sched(profile: WaveProfile, cfg, *, slots: int) -> ReplaySummary:
     R = profile.lanes
     B = max(int(slots), 1)
     nw = max(profile.nw, 1)
-    passes = 1 if getattr(cfg, "fused_round", True) else 2
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
     t_all, c_all, n0_all = profile.lane_t, profile.lane_c, profile.lane_n0
     limits_all = []
@@ -597,17 +592,17 @@ def replay_sched(profile: WaveProfile, cfg, *, slots: int) -> ReplaySummary:
             n_launches = -(-max_att // rpl)
             launches += n_launches
             idle = n_launches * rpl - max_att
-            row_work += idle * passes * len(occ) * cap * nw
-            waste += idle * passes * len(occ) * cap * nw
+            row_work += idle * len(occ) * cap * nw
+            waste += idle * len(occ) * cap * nw
             for j in range(max_att):
                 lanes_j = [i for i in occ if j < attempts[i]]
-                row_work += passes * len(lanes_j) * cap * nw
+                row_work += len(lanes_j) * cap * nw
                 for i in lanes_j:
                     ridx = lane_req[i]
                     enter = enters[i] if j == 0 else (
                         t_all[ridx][its[i] - rs[i] + j - 1]
                         if its[i] - rs[i] + j - 1 < len(t_all[ridx]) else 0)
-                    waste += passes * max(cap - max(enter, 1), 0) * nw
+                    waste += max(cap - max(enter, 1), 0) * nw
             drain_lanes = [i for i in occ if statuses[i] == _DRAIN]
             grow_lanes = [i for i in occ if statuses[i] == _GROW]
             if drain_lanes:
@@ -653,59 +648,6 @@ def replay_sched(profile: WaveProfile, cfg, *, slots: int) -> ReplaySummary:
 # ---------------------------------------------------------------------------
 # Sharded twin (core/distributed.py's superstep driver)
 # ---------------------------------------------------------------------------
-
-def dist_wire_bytes(n: int, nw: int, compress: bool) -> tuple[int, int]:
-    """Modeled wire size of one balance hop: (bytes per donated row,
-    per-round stat overhead per device).
-
-    The SAME formula the sharded driver charges into its per-tier metrics
-    and trace events — replay and reality share one accounting. Exact rows
-    ship path + blocked (nw uint32 words each) + three int32 ids, plus the
-    int32 count and the reverse-permuted neighbor count. The compressed
-    cross-host wire ships the bit-packed path (⌈n/8⌉ bytes) + two
-    ``ef_quantize``d int8 endpoint ids per row (``blocked``/``l2`` are
-    reconstructed receiver-side), plus the int8 mean-load payload, its fp32
-    shared scale, and the exact counts.
-    """
-    if compress:
-        return (int(n) + 7) // 8 + 2, 17
-    return 8 * int(nw) + 12, 8
-
-
-def dist_bucket_range(cfg) -> tuple[int, int]:
-    """(floor, ceiling) of the sharded superstep's per-device bucket.
-
-    The ceiling is ``local_capacity``; the floor is the bucket of two
-    balance blocks (capped at the ceiling), so a device can still donate a
-    whole block."""
-    ceiling = int(cfg.local_capacity)
-    return min(cfg.bucket(2 * int(cfg.balance_block)), ceiling), ceiling
-
-
-def dist_first_bucket(cfg, rows: int) -> int:
-    """The bucket the sharded deal runs at, for at most ``rows`` triplets
-    per device."""
-    floor, ceiling = dist_bucket_range(cfg)
-    return min(max(cfg.bucket(max(int(rows), 1)), floor), ceiling)
-
-
-def dist_next_bucket(cfg, status: str, cur: int, *, need: int,
-                     peak: int) -> int:
-    """The sharded driver's bucket policy — one function for the driver
-    and its replay twin. A superstep at bucket ``cur`` exited with
-    ``status``: on GROW the busiest device's aborted round needed ``need``
-    rows, so grow to its bucket with ``grow_headroom`` extra doublings; on
-    RUN or SHRINK shrink to the bucket of ``peak``, the busiest device's
-    live rows, when that is smaller. Floor and ceiling from
-    ``dist_bucket_range``."""
-    floor, ceiling = dist_bucket_range(cfg)
-    if status == _GROW:
-        return min(cfg.bucket(cfg.bucket(max(int(need), 1))
-                              << max(int(cfg.grow_headroom), 0)), ceiling)
-    if status in (_RUN, _SHRINK):
-        return min(max(cfg.bucket(max(int(peak), 1)), floor), cur)
-    return cur
-
 
 @dataclasses.dataclass(frozen=True)
 class DistProfile:
@@ -814,6 +756,8 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
     cross-host hop at its own bandwidth: the balance-cadence ↔
     interconnect-bandwidth trade the tuner searches.
     """
+    from ..core.distributed import (dist_bucket_range, dist_first_bucket,
+                                    dist_next_bucket, dist_wire_bytes)
     limit = profile.limit
     t = profile.t_sizes
     nw = max(profile.nw, 1)
@@ -849,7 +793,6 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
                     or cross_every <= profile.base_cross_balance_every))
     feasible = cap >= n0_dev and (base_ok or cap >= 2 * est_peak)
 
-    passes = 1 if getattr(cfg, "fused_round", True) else 2
     rpl = max(int(getattr(cfg, "rounds_per_launch", 1)), 1)
     # per-device bucketing: the busiest device's rows per round (the
     # profiled run's, else the global sizes — all rows on one device)
@@ -876,8 +819,8 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
         r = att = 0
         while status == _RUN and r < k and cnt > 0 and it + r < n_rounds:
             att += 1
-            row_work += passes * b * ndev * nw
-            waste += passes * max(b * ndev - max(cnt, 1), 0) * nw
+            row_work += b * ndev * nw
+            waste += max(b * ndev - max(cnt, 1), 0) * nw
             if b < ceiling and dev_new[it + r] > b:
                 status = _GROW                # aborted: nothing applied
                 break
@@ -900,8 +843,8 @@ def replay_dist(profile: DistProfile, cfg) -> ReplaySummary:
         n_launches = -(-att // rpl)
         launches += n_launches
         idle = n_launches * rpl - att
-        row_work += idle * passes * b * ndev * nw
-        waste += idle * passes * b * ndev * nw
+        row_work += idle * b * ndev * nw
+        waste += idle * b * ndev * nw
         if status != _GROW and cnt == 0:
             status = _DONE
         by_cause[status] = by_cause.get(status, 0) + 1

@@ -78,7 +78,7 @@ class TuneKey:
     store: bool           # store vs count-only mode
     formulation: str
     backend: str
-    engine: str           # 'wave' | 'host' | 'dist' (mesh-routed)
+    engine: str           # 'wave' | 'dist' (mesh-routed) | 'sched'
     device_kind: str      # jax platform: 'cpu' | 'gpu' | 'tpu'
     ndev: int = 0         # TOTAL device count H·D (0: unsharded)
     batch: int = 0        # batch-size class (pow2 bucket of B; 0: unbatched)

@@ -6,7 +6,7 @@ was thrown away after each run. This module turns it into a structured,
 recordable stream:
 
 * ``TraceEvent``  — one host↔device interaction (a wave superstep dispatch,
-                    a legacy host-engine round, or a batched superstep),
+                    a batched superstep, or a sharded superstep),
                     carrying the full wave shape of that dispatch: bucket
                     capacity, per-round frontier sizes and cycle counts,
                     exit status (by CAUSE: GROW / SHRINK / DRAIN / DONE /
@@ -103,11 +103,11 @@ class TraceEvent:
     moved_cross: int = 0       # rows shipped over the cross-host tier
     comm_bytes_intra: int = 0  # modeled wire bytes of intra-host balance
     #                            hops inside this dispatch (block-sized
-    #                            sends × ``cost_model.dist_wire_bytes``)
+    #                            sends × ``distributed.dist_wire_bytes``)
     comm_bytes_cross: int = 0  # modeled wire bytes of the cross-host hops
     #                            (compressed when the run compresses them —
-    #                            the quantity the tier-aware cost model and
-    #                            the BENCH_multihost_smoke 4× gate consume)
+    #                            the quantity the tier-aware cost model
+    #                            consumes)
     # --- lane-recycling dispatches ('recycle' + scheduler 'batch'/'seed'
     # events) only — DESIGN.md §6.9 ------------------------------------
     lanes: int = 0             # pool size B of the recyclable batch
@@ -165,8 +165,8 @@ class WaveTrace:
 
     Counters always accumulate; ``events`` fills only when ``enabled``.
     ``finalize(rounds)`` renders the legacy stats dict (the exact shape
-    ``EnumerationResult.stats`` has carried since PR 1) so existing
-    consumers — benchmarks, tests, BENCH_*.json baselines — see no change.
+    ``EnumerationResult.stats`` has always carried), so its consumers —
+    the ``bench/`` harness and the tests — see one stable shape.
     """
 
     __slots__ = ("enabled", "events", "n_dispatches", "n_host_syncs",
@@ -223,12 +223,6 @@ class WaveTrace:
     def d2h(self, n: int = 1) -> None:
         """Count ``n`` arrays copied device-to-host by one read."""
         self.n_d2h_arrays += n
-
-    def launch(self, n: int = 1) -> None:
-        """Count device-program launches that are part of the CURRENT
-        dispatch event (the legacy host engine issues several per round;
-        pass ``launches=0`` to ``dispatch`` when counting this way)."""
-        self.n_dispatches += n
 
     def drain(self) -> None:
         self.n_drains += 1

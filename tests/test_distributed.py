@@ -225,11 +225,11 @@ print('OK', R, s['n_dispatches'], s1['n_dispatches'])
 # the fixed-capacity run: the bucket policy's floor raised to the ceiling,
 # so the deal and every superstep run at ``local_capacity`` and no
 # superstep exits GROW or SHRINK. The driver and the superstep builder
-# look the policy up in ``tune.cost_model`` when they run, so patching the
+# look the policy up in ``core.distributed`` when they run, so patching the
 # module reaches both.
 _FIXED = """
-import repro.tune.cost_model as cm
-cm.dist_bucket_range = lambda cfg: (int(cfg.local_capacity),) * 2
+import repro.core.distributed as D
+D.dist_bucket_range = lambda cfg: (int(cfg.local_capacity),) * 2
 """
 
 
@@ -249,11 +249,11 @@ from repro.core import (CycleService, EngineConfig, build_graph,
                         sequential_chordless_cycles)
 from repro.core.graphs import grid_graph, random_gnp
 from repro.tune import DistProfile, replay_dist
-import repro.tune.cost_model as cm
+import repro.core.distributed as D
 
 mesh = Mesh(np.array(jax.devices())[:4], ('data',))
 cap = 1 << 13
-policy = cm.dist_bucket_range
+policy = D.dist_bucket_range
 for n, edges in (grid_graph(5, 6), random_gnp(30, 0.2, 11)):
     g = build_graph(n, edges)
     ref, _ = sequential_chordless_cycles(n, edges)
@@ -264,7 +264,7 @@ for n, edges in (grid_graph(5, 6), random_gnp(30, 0.2, 11)):
         if arm == 'fixed':
 %s
         res = CycleService(cfg, trace=True).enumerate(g)
-        cm.dist_bucket_range = policy
+        D.dist_bucket_range = policy
         s = res.stats
         assert res.n_cycles == ref, (arm, n, res.n_cycles, ref)
         assert s['dropped'] == 0 and s['lost'] == 0, (arm, s)
@@ -398,12 +398,12 @@ import jax, numpy as np
 from jax.sharding import Mesh
 from repro.core import CycleService, EngineConfig, build_graph
 from repro.core.graphs import grid_graph
-import repro.tune.cost_model as cm
+import repro.core.distributed as D
 
 mesh = Mesh(np.array(jax.devices())[:4], ('data',))
 cfg = EngineConfig(store=False, mesh=mesh, local_capacity=1 << 13,
                    balance_block=64)
-cm.dist_next_bucket = lambda cfg, status, cur, **kw: cur
+D.dist_next_bucket = lambda cfg, status, cur, **kw: cur
 try:
     CycleService(cfg).enumerate(build_graph(*grid_graph(5, 8)))
     raise SystemExit('expected the RuntimeError')

@@ -2,23 +2,23 @@
 
 The acceptance surface of the two-phase-scatter fusion:
 
-* the fused round — jnp gather AND the pallas kernel — is bit-identical to
-  the split round it replaces: every frontier leaf, the cycle-ring masks,
-  the raw n_cyc/n_new totals, and BOTH guard flags, round by round,
-  including guard-tripped (overflowing) rounds where the round must not be
-  applied;
+* the round each op runs — the pallas kernel where the bucket fits its
+  VMEM budget, the split round (flags, then gather compaction) elsewhere —
+  is bit-identical to the split round of the same formulation on the jnp
+  op: every frontier leaf, the cycle-ring masks, the raw n_cyc/n_new
+  totals, and BOTH guard flags, round by round, including guard-tripped
+  (overflowing) rounds where the round must not be applied;
 * the same identity holds through the batched lanes path (custom_vmap →
-  lane-gridded kernel) and end-to-end through ``CycleService`` across
-  slot/bitword × jnp/pallas, in ``cycle_masks`` and |T| histories;
-* mesh-routed enumeration with the fused local step matches the reference
-  count on 1/2/4-device meshes;
+  lane-gridded kernel) and end-to-end through ``CycleService`` between
+  the pallas and jnp backends, in ``cycle_masks`` and |T| histories;
+* mesh-routed enumeration matches the reference count on 1/2/4-device
+  meshes;
 * the traced fused-round program is ONE ``pallas_call`` with zero
   scatter/cumsum/sort passes outside it (the split program demonstrably
   leaks them) — asserted on the jaxpr, plus the trace-time build counters;
-* the replay twin charges a fused round exactly ONE frontier pass per
-  attempted round (the split round two), all other counters unchanged;
-* the tuner searches ``fused_round`` as a knob and legacy stored entries /
-  key strings without it still parse and apply.
+* every round a run attempts is counted on exactly one path;
+* stored tuner entries from before the knob was removed — carrying a
+  ``fused_round`` value or an ``engine='host'`` key — still load and apply.
 """
 import os
 import subprocess
@@ -54,7 +54,7 @@ def _leaves(f):
 
 
 # ---------------------------------------------------------------------------
-# Round-level bit-identity: fused (gather + kernel) == split, per round
+# Round-level bit-identity: each op's round == the jnp split round
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("formulation", ["slot", "bitword"])
@@ -70,9 +70,9 @@ def test_fused_round_bit_identical(formulation, backend, store):
     f, buf, fp, bp = f0, buf0, f0, buf0
     for rnd in range(6):
         f, buf, nc, nn, okf, okc = E.expand_count_compact(
-            g, f, buf, delta=delta, store=store, op=op_ref, fused=False)
+            g, f, buf, delta=delta, store=store, op=op_ref)
         fp, bp, ncp, nnp, okfp, okcp = E.expand_count_compact(
-            g, fp, bp, delta=delta, store=store, op=op_fus, fused=True)
+            g, fp, bp, delta=delta, store=store, op=op_fus)
         assert int(nc) == int(ncp) and int(nn) == int(nnp), (rnd, nn, nnp)
         assert bool(okf) == bool(okfp) and bool(okc) == bool(okcp), rnd
         for name, leaf in _leaves(f):
@@ -100,9 +100,9 @@ def test_fused_round_guard_trip_not_applied(formulation):
     tripped = False
     for rnd in range(4):
         f, buf, nc, nn, okf, _ = E.expand_count_compact(
-            g, f, buf, delta=delta, store=True, op=op_ref, fused=False)
+            g, f, buf, delta=delta, store=True, op=op_ref)
         fp, bp, _, _, okfp, _ = E.expand_count_compact(
-            g, fp, bp, delta=delta, store=True, op=op_pal, fused=True)
+            g, fp, bp, delta=delta, store=True, op=op_pal)
         assert bool(okf) == bool(okfp), rnd
         tripped = tripped or not bool(okf)
         assert np.array_equal(np.asarray(f.path), np.asarray(fp.path)), rnd
@@ -114,7 +114,7 @@ def test_fused_round_guard_trip_not_applied(formulation):
 @pytest.mark.parametrize("formulation", ["slot", "bitword"])
 def test_fused_round_batched_lanes_bit_identical(formulation):
     """vmapped fused round (custom_vmap → lane-gridded kernel) == vmapped
-    split round, per lane, on a mixed-size batch."""
+    jnp split round, per lane, on a mixed-size batch."""
     specs = [grid_graph(3, 4), grid_graph(4, 4)]
     gs = [build_graph(n, e) for n, e in specs]
     gb = batch_graphs(gs)
@@ -125,9 +125,9 @@ def test_fused_round_batched_lanes_bit_identical(formulation):
     op_ref = E.expand_op(formulation, "jnp")
     op_pal = E.expand_op(formulation, "pallas")
     step_ref = jax.vmap(lambda gg, ff, uu: E.expand_count_compact(
-        gg, ff, uu, delta=delta, store=True, op=op_ref, fused=False))
+        gg, ff, uu, delta=delta, store=True, op=op_ref))
     step_pal = jax.vmap(lambda gg, ff, uu: E.expand_count_compact(
-        gg, ff, uu, delta=delta, store=True, op=op_pal, fused=True))
+        gg, ff, uu, delta=delta, store=True, op=op_pal))
     f, buf, fp, bp = fb, bb, fb, bb
     for rnd in range(5):
         f, buf, nc, nn, *_ = step_ref(gb, f, buf)
@@ -141,35 +141,42 @@ def test_fused_round_batched_lanes_bit_identical(formulation):
 
 
 # ---------------------------------------------------------------------------
-# End-to-end: CycleService fused == split in masks + histories
+# End-to-end: CycleService pallas (fused) == jnp (split) in masks + histories
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("formulation", ["slot", "bitword"])
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_service_fused_matches_split_end_to_end(formulation, backend):
+    """``backend`` against the jnp backend (every round split): the pallas
+    case runs its fused kernel, the jnp case pins the split round's
+    determinism across two services."""
     for n, edges in [grid_graph(4, 4), random_gnp(14, 0.35, 7)]:
         g = build_graph(n, edges)
         ref, _ = sequential_chordless_cycles(n, edges)
         res = {}
-        for fused in (True, False):
+        for be in (backend, "jnp"):
             svc = CycleService(EngineConfig(
-                store=True, formulation=formulation, backend=backend,
-                fused_round=fused))
-            res[fused] = svc.enumerate(g)
-        assert res[True].n_cycles == res[False].n_cycles == ref
-        assert res[True].history == res[False].history
-        assert np.array_equal(res[True].cycle_masks, res[False].cycle_masks)
+                store=True, formulation=formulation, backend=be))
+            res[be] = svc.enumerate(g)
+        got, split = res[backend], res["jnp"]
+        if backend == "pallas":
+            assert got.stats["fused_rounds"] > 0
+        assert split.stats["fused_rounds"] == 0
+        assert got.n_cycles == split.n_cycles == ref
+        assert got.history == split.history
+        assert np.array_equal(got.cycle_masks, split.cycle_masks)
 
 
 def test_service_fused_batched_matches_split():
     specs = [grid_graph(3, 4), grid_graph(4, 5), random_gnp(12, 0.3, 3)]
     gs = [build_graph(n, e) for n, e in specs]
     out = {}
-    for fused in (True, False):
+    for backend in ("pallas", "jnp"):
         svc = CycleService(EngineConfig(store=True, formulation="bitword",
-                                        backend="pallas", fused_round=fused))
-        out[fused] = svc.enumerate_batch(gs)
-    for a, b, (n, edges) in zip(out[True], out[False], specs):
+                                        backend=backend))
+        out[backend] = svc.enumerate_batch(gs)
+    assert out["pallas"][0].stats["fused_rounds"] > 0
+    for a, b, (n, edges) in zip(out["pallas"], out["jnp"], specs):
         ref, _ = sequential_chordless_cycles(n, edges)
         assert a.n_cycles == b.n_cycles == ref
         assert a.history == b.history
@@ -177,8 +184,9 @@ def test_service_fused_batched_matches_split():
 
 
 def test_mesh_fused_matches_reference_1_2_4_devices():
-    """Sharded local step with gather compaction == reference counts on
-    1/2/4-device meshes (subprocess: forces multiple host devices)."""
+    """Sharded local step (flags, then gather compaction) == reference
+    counts on 1/2/4-device meshes, on both backends (subprocess: forces
+    multiple host devices)."""
     code = """
 import jax, numpy as np
 from jax.sharding import Mesh
@@ -191,11 +199,11 @@ for n, edges in [grid_graph(4, 6), random_gnp(24, 0.3, 5)]:
     ref, _ = sequential_chordless_cycles(n, edges)
     for ndev in (1, 2, 4):
         mesh = Mesh(np.array(jax.devices())[:ndev].reshape(ndev,), ('data',))
-        for fused in (True, False):
+        for backend in ('jnp', 'pallas'):
             cfg = EngineConfig(store=False, mesh=mesh, local_capacity=1<<13,
-                               balance_block=64, fused_round=fused)
+                               balance_block=64, backend=backend)
             res = CycleService(cfg).enumerate(g)
-            assert res.n_cycles == ref, (ndev, fused, res.n_cycles, ref)
+            assert res.n_cycles == ref, (ndev, backend, res.n_cycles, ref)
             assert res.stats['dropped'] == 0 and res.stats['lost'] == 0
 print('OK')
 """
@@ -223,15 +231,17 @@ def test_fused_round_is_one_kernel_dispatch(formulation, store):
 
     def fused_body(g, f, buf):
         return E.expand_count_compact(g, f, buf, delta=delta, store=store,
-                                      op=op, fused=True)
+                                      op=op)
 
     counts = assert_fused_round_program(fused_body, g, f, buf)
     assert counts.get("pallas_call", 0) == 1
 
     # the contrast: the split round leaks compaction passes into XLA
+    split_op = E.expand_op(formulation, "jnp")
+
     def split_body(g, f, buf):
         return E.expand_count_compact(g, f, buf, delta=delta, store=store,
-                                      op=op, fused=False)
+                                      op=split_op)
 
     leak = compaction_prims_outside_kernel(
         primitive_counts(jax.make_jaxpr(split_body)(g, f, buf)))
@@ -247,71 +257,90 @@ def test_fused_kernel_build_counters_increment():
     op = E.expand_op("bitword", "pallas")
     before = dict(kops.FUSED_KERNEL_BUILDS)
     jax.make_jaxpr(lambda g, f, buf: E.expand_count_compact(
-        g, f, buf, delta=delta, store=False, op=op, fused=True))(g, f, buf)
+        g, f, buf, delta=delta, store=False, op=op))(g, f, buf)
     assert kops.FUSED_KERNEL_BUILDS["single"] > before["single"]
 
 
+
+
 # ---------------------------------------------------------------------------
-# Replay twin: fused charges ONE frontier pass per round, split two
+# Round-path accounting: every attempted round is counted on one path
 # ---------------------------------------------------------------------------
 
-def test_replay_fused_charges_one_pass_per_round():
-    from repro.tune import WaveProfile, replay
+@pytest.mark.parametrize("formulation", ["slot", "bitword"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_round_path_counters_cover_every_round(formulation, backend):
+    """``fused_rounds + split_rounds`` is every round the run applied plus
+    the one each GROW or DRAIN exit aborted (and re-ran after the host
+    resized); the jnp backend has no fused kernel to take."""
     g = _graph(4, 5)
-    res = CycleService(EngineConfig(store=False)).enumerate(g)
-    prof = WaveProfile.from_history(res.history, n=g.n,
-                                    nw=g.adj_bits.shape[1])
-    fused = replay(prof, EngineConfig(store=False, fused_round=True))
-    split = replay(prof, EngineConfig(store=False, fused_round=False))
-    # exactly 2x the row traffic, nothing else moves
-    assert split.row_work == 2 * fused.row_work > 0
-    assert split.padded_waste == 2 * fused.padded_waste
-    assert split.n_dispatches == fused.n_dispatches
-    assert split.n_host_syncs == fused.n_host_syncs
-    assert split.n_programs == fused.n_programs
-
-
-def test_replay_batch_fused_charges_one_pass_per_round():
-    from repro.tune import WaveProfile, replay
-    specs = [grid_graph(3, 4), grid_graph(4, 4)]
-    gs = [build_graph(n, e) for n, e in specs]
-    svc = CycleService(EngineConfig(store=False, backend="pallas"))
-    batch = svc.enumerate_batch(gs)
-    nmax = max(g.n for g in gs)
-    prof = WaveProfile.from_batch(
-        [r.history for r in batch], lane_n=tuple(g.n for g in gs),
-        n=nmax, nw=max(g.adj_bits.shape[1] for g in gs))
-    fused = replay(prof, EngineConfig(store=False, fused_round=True))
-    split = replay(prof, EngineConfig(store=False, fused_round=False))
-    assert split.row_work == 2 * fused.row_work > 0
-    assert split.n_dispatches == fused.n_dispatches
+    res = CycleService(EngineConfig(formulation=formulation,
+                                    backend=backend)).enumerate(g)
+    st = res.stats
+    aborted = sum(st["exit_causes"].get(s, 0) for s in ("GROW", "DRAIN"))
+    assert aborted > 0          # the default buckets do grow on Grid_4x5
+    assert st["rounds"] == res.iterations == len(res.history) - 1 > 0
+    assert st["fused_rounds"] + st["split_rounds"] == st["rounds"] + aborted
+    if backend == "jnp":
+        assert st["fused_rounds"] == 0
+    else:
+        assert st["fused_rounds"] > 0
 
 
 # ---------------------------------------------------------------------------
-# Tuner surface: fused_round is a searched knob; legacy entries still work
+# Stored tuner entries from before the knob went
 # ---------------------------------------------------------------------------
-
-def test_tuner_searches_fused_round_axis():
-    from repro.tune import TUNED_KNOBS
-    from repro.tune.autotune import TuneSpace
-    assert "fused_round" in TUNED_KNOBS
-    space = TuneSpace()
-    assert set(space.fused_round) == {True, False}
-    sets = space.knob_sets(EngineConfig())
-    assert any(k.get("fused_round") is False for k in sets)
-    assert any(k.get("fused_round") is True for k in sets)
-
 
 def test_legacy_tune_entries_parse_and_apply():
-    """Pre-fusion stored entries carry neither a fused_round knob nor any
-    new key token: the key string round-trips and applying the legacy knob
-    dict preserves the base config's fused_round."""
+    """Key strings written before the key gained tokens round-trip, and a
+    stored knob dict applies onto the base config."""
     from repro.tune import AutoTuner, TuneKey
     legacy = "n32-m64-d8|count|bitword|pallas|wave|cpu"
     key = TuneKey.from_str(legacy)
     assert key.as_str() == legacy
-    cfg = EngineConfig(fused_round=True)
-    tuned = AutoTuner.apply({"superstep_rounds": 8}, cfg)
-    assert tuned.fused_round is True and tuned.superstep_rounds == 8
-    tuned2 = AutoTuner.apply({"fused_round": False}, cfg)
-    assert tuned2.fused_round is False
+    tuned = AutoTuner.apply({"superstep_rounds": 8}, EngineConfig())
+    assert tuned.superstep_rounds == 8
+
+
+@pytest.mark.parametrize("legacy", [True, False])
+def test_legacy_store_with_fused_round_and_host_entries_loads(tmp_path,
+                                                             legacy):
+    """A store file in today's format whose entries carry the removed
+    ``fused_round`` knob, plus an entry keyed ``engine='host'``: it loads,
+    the wave entry applies without the knob, and the host entry matches no
+    request."""
+    import dataclasses
+    import json
+    from repro.tune import SCHEMA_VERSION, AutoTuner, TuneKey, TuneStore
+    g = _graph(4, 4)
+    cfg = EngineConfig(store=False, formulation="bitword")
+    key = AutoTuner(device_kind=jax.devices()[0].platform).key_for(
+        g.n, g.m, g.max_degree, cfg)
+    host = dataclasses.replace(key, engine="host")
+    path = tmp_path / "tune.json"
+    path.write_text(json.dumps(dict(version=SCHEMA_VERSION, entries={
+        key.as_str(): dict(knobs={"superstep_rounds": 4,
+                                  "fused_round": legacy},
+                           meta={}, hits=0),
+        host.as_str(): dict(knobs={"superstep_rounds": 2,
+                                   "fused_round": legacy},
+                            meta={}, hits=0)})))
+    store = TuneStore(path=str(path))
+    assert len(store) == 2 and store.stale_drops == 0
+    assert TuneKey.from_str(host.as_str()) == host
+
+    svc = CycleService(cfg, tune_store=store)
+    res = svc.enumerate(g)
+    ref, _ = sequential_chordless_cycles(*grid_graph(4, 4))
+    assert res.n_cycles == ref
+    st = svc.stats
+    assert st["tuned_requests"] == 1 and st["tune"]["searches"] == 0
+    # the stored round budget is the one the compiled supersteps run
+    assert {k.k_max for k in svc._cache._plans if k.kind == "wave"} == {4}
+    entries = json.loads(path.read_text())["entries"]
+    assert store._entries[host.as_str()]["hits"] == 0
+    assert host.as_str() in entries
+    for knobs in (store.get(key), store.get(host)):
+        tuned = AutoTuner.apply(knobs, cfg)
+        assert not hasattr(tuned, "fused_round")
+        assert tuned.formulation == "bitword" and tuned.store is False

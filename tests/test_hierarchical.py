@@ -93,11 +93,11 @@ from jax.sharding import Mesh
 from repro.core import (CycleService, EngineConfig, build_graph,
                         sequential_chordless_cycles)
 from repro.core.graphs import grid_graph, random_gnp
-import repro.tune.cost_model as cm
+import repro.core.distributed as D
 
 mesh = Mesh(np.array(jax.devices())[:4].reshape(2, 2), ('host', 'data'))
 cap = 1 << 13
-policy = cm.dist_bucket_range
+policy = D.dist_bucket_range
 for n, edges in (grid_graph(4, 6), random_gnp(30, 0.2, 11)):
     g = build_graph(n, edges)
     ref, _ = sequential_chordless_cycles(n, edges)
@@ -109,9 +109,9 @@ for n, edges in (grid_graph(4, 6), random_gnp(30, 0.2, 11)):
         out = {}
         for arm in ('bucketed', 'fixed'):
             if arm == 'fixed':
-                cm.dist_bucket_range = lambda cfg: (cap, cap)
+                D.dist_bucket_range = lambda cfg: (cap, cap)
             res = CycleService(cfg).enumerate(g)
-            cm.dist_bucket_range = policy
+            D.dist_bucket_range = policy
             s = res.stats
             assert res.n_cycles == ref, (arm, compress, res.n_cycles, ref)
             assert s['dropped'] == 0 and s['lost'] == 0, (arm, s)
@@ -153,8 +153,8 @@ for compress in (False, True):
     s = res.stats
     assert s['comm_bytes_intra'] > 0 and s['comm_bytes_cross'] > 0, s
     bytes_cross[compress] = s['comm_bytes_cross']
-# >=2x at n=30 (5-byte packed rows); the >=4x gate lives in
-# benchmarks/dist_enum.py where the graph is sized (n<=16) for it
+# >=2x at n=30 (5-byte packed rows); the packed row is n/8 + 2 bytes,
+# so the saving grows as n shrinks
 assert bytes_cross[True] * 2 <= bytes_cross[False], bytes_cross
 
 mb = svc.metrics.counter('dist_comm_bytes')

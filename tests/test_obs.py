@@ -606,7 +606,7 @@ def _plan(cap, *, backend="pallas", rpl=1, store=False, nw=2, n=60,
     from repro.core.plan import PlanKey, WavePlan
     key = PlanKey(kind="wave", bucket=cap, nw=nw, cyc_rows=cyc_cap,
                   delta=4, store=store, formulation="bitword",
-                  backend=backend, k_max=4, fused=True, rpl=rpl,
+                  backend=backend, k_max=4, rpl=rpl,
                   extra=(n, m))
     plan = WavePlan(key)
     lowered = plan.lower(*_abstract_plan_args(cap, nw, n, m, cyc_cap))

@@ -57,7 +57,7 @@ def _compose_single(g, f, buf, *, delta, store, rounds, rlimit, op):
         if not alive or done >= rlimit:
             continue
         f2, buf2, n_cyc, n_new, okf_r, okc_r = E.expand_count_compact(
-            g, f, buf, delta=delta, store=store, op=op, fused=False)
+            g, f, buf, delta=delta, store=store, op=op)
         nh[r], ch[r] = int(n_new), int(n_cyc)
         if not bool(okf_r & okc_r):
             alive, okf, okc = False, bool(okf_r), bool(okc_r)
@@ -87,8 +87,7 @@ def test_multi_round_bit_identical(formulation, backend, store, bucket,
     f_r, buf_r, ch_r, nh_r, done_r, okf_r, okc_r = ref
     out = E.expand_count_compact_multi(
         g, f0, buf0, delta=delta, store=store, rounds=R,
-        op=E.expand_op(formulation, backend), fused=True,
-        rlimit=jnp.int32(rlimit))
+        op=E.expand_op(formulation, backend), rlimit=jnp.int32(rlimit))
     f_p, buf_p, ch_p, nh_p, done_p, okf_p, okc_p = out
     assert int(done_p) == done_r
     assert list(np.asarray(nh_p)) == nh_r
@@ -192,8 +191,7 @@ def test_superstep_dispatch_contract_ceil_k_over_r(rpl, expect):
     def superstep(g, f, buf):
         for _ in range(-(-K // rpl)):
             f, buf, *_ = E.expand_count_compact_multi(
-                g, f, buf, delta=delta, store=True, rounds=rpl, op=op,
-                fused=True)
+                g, f, buf, delta=delta, store=True, rounds=rpl, op=op)
         return f, buf
 
     counts = assert_superstep_dispatches(superstep, g, f, buf, budget=K,
@@ -212,8 +210,7 @@ def test_superstep_dispatch_contract_fails_loudly():
 
     def one_launch(g, f, buf):
         return E.expand_count_compact_multi(
-            g, f, buf, delta=delta, store=False, rounds=4, op=op,
-            fused=True)
+            g, f, buf, delta=delta, store=False, rounds=4, op=op)
 
     with pytest.raises(AssertionError, match="pallas"):
         assert_superstep_dispatches(one_launch, g, f, buf, budget=4,
@@ -229,8 +226,7 @@ def test_persistent_kernel_build_counters_increment():
     op = E.expand_op("bitword", "pallas")
     before = dict(kops.FUSED_KERNEL_BUILDS)
     jax.make_jaxpr(lambda g, f, buf: E.expand_count_compact_multi(
-        g, f, buf, delta=delta, store=False, rounds=4, op=op,
-        fused=True))(g, f, buf)
+        g, f, buf, delta=delta, store=False, rounds=4, op=op))(g, f, buf)
     assert (kops.FUSED_KERNEL_BUILDS["persistent_single"]
             > before["persistent_single"])
 

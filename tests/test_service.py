@@ -29,8 +29,6 @@ def test_config_unknown_values_raise_eagerly():
         EngineConfig(formulation="bitplane")
     with pytest.raises(ValueError, match="jnp.*pallas"):
         EngineConfig(backend="cuda")
-    with pytest.raises(ValueError, match="wave.*host"):
-        EngineConfig(engine="gpu")
     with pytest.raises(ValueError, match="superstep_rounds"):
         EngineConfig(superstep_rounds=0)
     with pytest.raises(ValueError, match="grow_headroom"):
@@ -55,8 +53,8 @@ def test_config_mesh_mismatches_raise_eagerly():
 
 def test_compat_wrapper_validates_before_tracing():
     g = build_graph(*grid_graph(3, 3))
-    with pytest.raises(ValueError, match="engine"):
-        enumerate_chordless_cycles(g, engine="warp")
+    with pytest.raises(ValueError, match="formulation"):
+        enumerate_chordless_cycles(g, formulation="warp")
 
 
 # ---------------------------------------------------------------------------
@@ -152,8 +150,6 @@ def test_plan_rejects_non_wave_configs():
     from jax.sharding import Mesh
     g = build_graph(*grid_graph(3, 3))
     svc = CycleService()
-    with pytest.raises(ValueError, match="wave"):
-        svc.plan(g, config=EngineConfig(engine="host"))
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     with pytest.raises(ValueError, match="wave"):
         svc.plan(g, config=EngineConfig(store=False, mesh=mesh))
@@ -316,12 +312,3 @@ def test_per_call_config_override_shares_cache():
     a = svc.enumerate(g)
     b = svc.enumerate(g, config=EngineConfig(store=False))
     assert a.n_cycles == b.n_cycles and b.cycle_masks is None
-
-
-def test_engine_host_routes_through_service():
-    g = build_graph(*grid_graph(3, 4))
-    svc = CycleService(EngineConfig(store=True, engine="host"))
-    cnt_ref, sets_ref = _ref_sets(*grid_graph(3, 4))
-    res = svc.enumerate(g)
-    assert res.n_cycles == cnt_ref
-    assert set(res.cycles_as_sets(12)) == sets_ref

@@ -134,3 +134,59 @@ def test_split_compaction_has_no_while_loop(split_operands, formulation):
     # a binary search is a fori_loop: `scan` in the jaxpr, `while` once lowered
     assert not {"while", "scan"} & set(counts), counts
     assert "stablehlo.while" not in jax.jit(body).lower(*args).as_text()
+
+
+# ---------------------------------------------------------------------------
+# The Pallas ops' split round, just past the fused kernels' VMEM budget
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def grown_frontier(split_operands):
+    """Grid_6x10's frontier after nine rounds (7,951 live rows) at
+    SPLIT_BUCKET, the first bucket above the 16,384 rows the fused kernels
+    hold at nw = 2. Three more rounds reach 13,130, 20,693, then 33,692
+    rows, which the bucket cannot hold."""
+    g, f = split_operands
+    op = E.expand_op("bitword", "jnp")
+    step = jax.jit(lambda f, buf: E.expand_count_compact(
+        g, f, buf, delta=int(g.max_degree), store=False, op=op))
+    buf = empty_cycle_buffer(1, g.adj_bits.shape[1])
+    for _ in range(9):
+        f, buf, *_ = step(f, buf)
+    assert int(f.count) == 7951
+    return g, f
+
+
+@pytest.mark.parametrize("store", [True, False])
+@pytest.mark.parametrize("formulation", ["bitword", "slot"])
+def test_pallas_split_round_matches_jnp_past_vmem_budget(grown_frontier,
+                                                         formulation, store):
+    """The split round of the Pallas op (its flag kernel, then the gather
+    compaction and cycle append) == the jnp op's split round, leaf for leaf,
+    through two applied rounds and the guard trip of the third."""
+    g, f0 = grown_frontier
+    delta = int(g.max_degree)
+    buf0 = empty_cycle_buffer(1 << 14, g.adj_bits.shape[1])
+    state = {}
+    for backend in ("pallas", "jnp"):
+        op = E.expand_op(formulation, backend)
+        with E.record_round_paths() as paths:
+            step = jax.jit(lambda f, buf: E.expand_count_compact(
+                g, f, buf, delta=delta, store=store, op=op))
+            f, buf, rounds = f0, buf0, []
+            for _ in range(3):
+                f, buf, n_cyc, n_new, ok_f, ok_c = step(f, buf)
+                rounds.append((int(n_cyc), int(n_new), bool(ok_f),
+                               bool(ok_c)))
+        assert paths == ["split"], (backend, paths)
+        state[backend] = (f, buf, rounds)
+    (fp, bp, rp), (fj, bj, rj) = state["pallas"], state["jnp"]
+    assert rp == rj
+    assert [r[1] for r in rj] == [13130, 20693, 33692]
+    assert [r[2] for r in rj] == [True, True, False]
+    for name in ("path", "blocked", "v1", "l2", "vlast", "count"):
+        assert np.array_equal(np.asarray(getattr(fp, name)),
+                              np.asarray(getattr(fj, name))), name
+    assert int(fj.count) == 20693
+    assert int(bp.count) == int(bj.count)
+    assert np.array_equal(np.asarray(bp.masks), np.asarray(bj.masks))

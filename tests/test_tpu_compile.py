@@ -179,11 +179,11 @@ def _round_kernels(cap: int, *, persistent: bool) -> list[str]:
     if persistent:
         fn = lambda g, f, buf: E.expand_count_compact_multi(
             g, f, buf, delta=DELTA, store=False, rounds=4,
-            formulation="bitword", backend="pallas", fused=True)
+            formulation="bitword", backend="pallas")
     else:
         fn = lambda g, f, buf: E.expand_count_compact(
             g, f, buf, delta=DELTA, store=False, formulation="bitword",
-            backend="pallas", fused=True)
+            backend="pallas")
     shapes = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), (g, f, buf))
     names = []

@@ -71,16 +71,6 @@ def test_untraced_service_attaches_no_trace():
     assert res.stats["n_dispatches"] > 0      # counters still maintained
 
 
-def test_host_engine_emits_round_events():
-    svc = CycleService(EngineConfig(store=True, engine="host"), trace=True)
-    res = svc.enumerate(build_graph(*grid_graph(3, 4)))
-    assert res.trace is not None
-    assert all(e.kind == "round" for e in res.trace.events)
-    assert len(res.trace.events) == res.iterations
-    # legacy launch accounting: several device programs per round
-    assert res.stats["n_dispatches"] > res.iterations
-
-
 # ---------------------------------------------------------------------------
 # WaveProfile: extraction + roundtrip
 # ---------------------------------------------------------------------------
@@ -387,7 +377,7 @@ def test_tuner_preserves_correctness_fields_and_persists():
     tuner = AutoTuner(device_kind="cpu")
     key = tuner.key_for(g.n, g.m, g.max_degree, cfg)
     tuned = tuner.tune(prof, cfg, key=key)
-    for field in ("store", "formulation", "backend", "engine", "max_iters",
+    for field in ("store", "formulation", "backend", "max_iters",
                   "donate", "mesh"):
         assert getattr(tuned, field) == getattr(cfg, field)
     assert tuner.lookup(key, cfg) == tuned       # stored → warm path
@@ -421,35 +411,22 @@ def test_shape_class_buckets_similar_graphs_together():
 
 @settings(max_examples=6, deadline=None)
 @given(formulation=st.sampled_from(["slot", "bitword"]),
-       engine=st.sampled_from(["wave", "host"]),
        seed=st.integers(0, 4))
-def test_tuned_config_bit_identical_masks(formulation, engine, seed):
+def test_tuned_config_bit_identical_masks(formulation, seed):
     """Acceptance: any tuner-emitted EngineConfig yields bit-identical
-    cycle_masks to the default config (slot/bitword × wave/host). The
-    service auto-tunes wave requests itself; the host engine (which the
-    service deliberately leaves untuned — the cost model replays the wave
-    driver) is exercised through the AutoTuner directly."""
+    cycle_masks to the default config (slot/bitword), through the
+    service's own auto-tuning."""
     n, edges = random_gnp(11, 0.35, seed)
     g = build_graph(n, edges)
-    cfg = EngineConfig(store=True, formulation=formulation, engine=engine)
+    cfg = EngineConfig(store=True, formulation=formulation)
     ref = CycleService(cfg).enumerate(g)
 
-    if engine == "wave":
-        svc = CycleService(cfg, auto_tune=True)
-        first = svc.enumerate(g)    # observes: runs base cfg, then tunes
-        tuned = svc.enumerate(g)    # executes the tuner-emitted config
-        assert svc.stats["tune"]["searches"] == 1
-        assert svc.stats["tuned_requests"] == 1
-        results = (first, tuned)
-    else:
-        prof = WaveProfile.from_history(ref.history, n=g.n,
-                                        nw=g.adj_bits.shape[1])
-        tuner = AutoTuner(device_kind="cpu")
-        tuned_cfg = tuner.tune(
-            prof, cfg, key=tuner.key_for(g.n, g.m, g.max_degree, cfg))
-        assert tuned_cfg.engine == "host"
-        results = (CycleService(tuned_cfg).enumerate(g),)
-    for res in results:
+    svc = CycleService(cfg, auto_tune=True)
+    first = svc.enumerate(g)    # observes: runs base cfg, then tunes
+    tuned = svc.enumerate(g)    # executes the tuner-emitted config
+    assert svc.stats["tune"]["searches"] == 1
+    assert svc.stats["tuned_requests"] == 1
+    for res in (first, tuned):
         assert res.n_cycles == ref.n_cycles
         assert res.n_triangles == ref.n_triangles
         assert np.array_equal(res.cycle_masks, ref.cycle_masks)
@@ -522,19 +499,6 @@ def test_explicit_per_request_config_bypasses_tuner():
     s = svc.stats
     assert s["tune"]["searches"] == 1            # no second search either
     assert s["tuned_requests"] == 0
-
-
-def test_host_engine_requests_pass_through_untuned():
-    """The service must not model-tune the host engine: the cost model's
-    replay twins the WAVE driver, so its ranking doesn't transfer."""
-    g = build_graph(*grid_graph(4, 4))
-    svc = CycleService(EngineConfig(store=False, formulation="bitword",
-                                    engine="host"), auto_tune=True)
-    a, b = svc.enumerate(g), svc.enumerate(g)
-    assert a.n_cycles == b.n_cycles
-    ts = svc.stats["tune"]
-    assert ts["searches"] == 0 and ts["observations"] == 0
-    assert svc.stats["tuned_requests"] == 0
 
 
 def test_tune_store_alone_implies_auto_tune():
